@@ -12,6 +12,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     failures = 0
     from benchmarks import bench_kernels, bench_paper_tables, roofline
     sections = [("paper_tables", bench_paper_tables.run),

@@ -79,11 +79,14 @@ def main(args):
     small = dataclasses.replace(cnn.CNN_ZOO["alexnet"], image_size=16)
     sparams = cnn.init(jax.random.PRNGKey(0), small)
     xs = jax.random.normal(jax.random.PRNGKey(8), (2, 16, 16, 3))
+    # the CPU interprets the kernel, which runs sooner eagerly than traced
+    # under jit; on a TPU the kernel compiles, so jit the forward there
+    jit = jax.default_backend() != "cpu"
     lg = CNNServingEngine(small, sparams,
-                          CNNServingConfig(impl="pallas", jit=False)).logits(xs)
+                          CNNServingConfig(impl="pallas", jit=jit)).logits(xs)
     if args.devices == 1:
         lp = CNNServingEngine(small, sparams, CNNServingConfig(
-            impl="planes", jit=False)).logits(xs)
+            impl="planes", jit=jit)).logits(xs)
         exact = bool(np.array_equal(np.asarray(lg), np.asarray(lp)))
         print(f"\nalexnet-16 fully through the Pallas SAC kernel: "
               f"bit-exact vs planes oracle = {exact}")
@@ -100,7 +103,7 @@ def main(args):
         # kernel launch under shard_map — per forced host device.
         assert jax.device_count() >= args.devices, jax.device_count()
         sh = CNNServingEngine(small, sparams, CNNServingConfig(
-            impl="pallas", jit=False, shards=args.devices))
+            impl="pallas", jit=jit, shards=args.devices))
         ls = sh.logits(xs)
         exact = bool(np.array_equal(np.asarray(ls), np.asarray(lg)))
         print(f"\nsharded over {args.devices} devices: bit-exact vs "

@@ -49,7 +49,7 @@ def main():
         out = sac_matmul(a, kw, impl=impl)
         err = float(jnp.max(jnp.abs(out - dense)))
         print(f"sac_matmul[{impl:6s}] max err vs dense: {err:.2e}")
-    out = sac_matmul_pallas(a, kw, bm=8)           # Pallas kernel (interpret)
+    out = sac_matmul_pallas(a, kw, bm=8)           # Pallas kernel
     err = float(jnp.max(jnp.abs(out - sac_matmul_ref(a, kw))))
     print(f"sac_matmul[pallas] max err vs oracle: {err:.2e}")
     print(f"kneaded HBM bytes vs bf16: "

@@ -46,24 +46,34 @@ def sac_matmul_planes(a: jax.Array, kw: KneadedWeight) -> jax.Array:
     sequence as the compacted kernel, and the parity tests assert bit-exact
     *equality*, not closeness.  (``repro.core.schedule.replay_schedule`` is
     the item-by-item sparse replay; the property tests pin all three paths
-    equal.)  Output = scale * sum_b 2^b S_b — the single rear adder tree.
+    equal.)  Output = scale * sum_b 2^b S_b — the single rear adder tree,
+    summed in plane order as the kernel's epilogue does.
+
+    Each partial dot has the kernel's tile shape, [M, ks] x [ks, n_block]:
+    XLA CPU blocks a wider dot differently, which moves f32 rounding by an
+    ulp, so a dense-N replay is not bitwise comparable.
     """
     mag = bitplanes.unpack_bits(kw.planes, axis=1)                 # [B-1, K, N]
     sign = 1 - 2 * bitplanes.unpack_bits(kw.signs, axis=0).astype(jnp.int8)
     a32 = a.astype(jnp.float32)
     nk = kw.k // kw.ks
+    bn = kw.n_block
     planes = [(mag[b].astype(jnp.int8) * sign).astype(jnp.float32)
               for b in range(kw.bits - 1)]
-    segments = [jnp.zeros((a32.shape[0], kw.n), jnp.float32)
-                for _ in range(kw.bits - 1)]
-    for t in range(nk):                      # K tiles ascending (grid order)
-        sl = slice(t * kw.ks, (t + 1) * kw.ks)
-        for b in range(kw.bits - 1):         # planes within the K tile
-            segments[b] = segments[b] + a32[:, sl] @ planes[b][sl]
-    seg = jnp.stack(segments)                                      # [B-1, M, N]
-    weights = (2.0 ** jnp.arange(kw.bits - 1)).reshape(-1, 1, 1)
-    out = jnp.sum(seg * weights, axis=0)                           # rear adder
-    return out * kw.scale                                          # scale once
+    cols = []
+    for j in range(kw.n // bn):              # output tiles
+        nsl = slice(j * bn, (j + 1) * bn)
+        segments = [jnp.zeros((a32.shape[0], bn), jnp.float32)
+                    for _ in range(kw.bits - 1)]
+        for t in range(nk):                  # K tiles ascending (grid order)
+            sl = slice(t * kw.ks, (t + 1) * kw.ks)
+            for b in range(kw.bits - 1):     # planes within the K tile
+                segments[b] = segments[b] + a32[:, sl] @ planes[b][sl, nsl]
+        out = segments[0]                    # rear adder
+        for b in range(1, kw.bits - 1):
+            out = out + segments[b] * float(2 ** b)
+        cols.append(out)
+    return jnp.concatenate(cols, axis=1) * kw.scale                # scale once
 
 
 def sac_matmul_int(a: jax.Array, q: jax.Array, scale: jax.Array) -> jax.Array:

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import exact_dot_precision
+
 
 def _kernel(a_ref, q_ref, scale_ref, out_ref, acc_ref, *, nk: int, packed4: bool):
     k_idx = pl.program_id(2)
@@ -37,6 +39,7 @@ def _kernel(a_ref, q_ref, scale_ref, out_ref, acc_ref, *, nk: int, packed4: bool
     w = q.astype(jnp.float32)
     acc_ref[...] += jax.lax.dot_general(
         a, w, dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=exact_dot_precision(a_ref.dtype),
         preferred_element_type=jnp.float32,
     )
 

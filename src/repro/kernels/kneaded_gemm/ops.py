@@ -6,12 +6,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import interpret_mode
 from repro.kernels.kneaded_gemm.kernel import kneaded_gemm_pallas_call
 from repro.kernels.kneaded_gemm.ref import pack_int4
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(
@@ -30,11 +27,8 @@ def kneaded_gemm(
     bm: int = 256,
     bn: int = 256,
     bk: int = 512,
-    interpret: bool | None = None,
 ) -> jax.Array:
     """Integer-kneaded GEMM with deferred scale; pads M to the tile size."""
-    if interpret is None:
-        interpret = not _on_tpu()
     m, k = a.shape
     n = q.shape[-1]
     bm_eff = min(bm, max(8, m))
@@ -45,7 +39,7 @@ def kneaded_gemm(
         a = jnp.pad(a, ((0, pad), (0, 0)))
     out = _run(a, q, scale.reshape(1, -1).astype(jnp.float32),
                packed4=packed4, bm=bm_eff, bn=bn_eff, bk=bk_eff,
-               interpret=interpret)
+               interpret=interpret_mode())
     return out[:m] if pad else out
 
 

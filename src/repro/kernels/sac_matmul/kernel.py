@@ -84,15 +84,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.schedule import KneadedSchedule
+from repro.kernels.backend import exact_dot_precision
 
 WORD = 32
 
 
 def _unpack_words(words: jax.Array, bk: int) -> jax.Array:
-    """[bk//32, bn] uint32 -> [bk, bn] uint32 {0,1} (little-endian per word)."""
+    """[bk//32, bn] uint32 -> [bk, bn] int32 {0,1} (little-endian per word).
+
+    Unpacks in int32: Mosaic has no uint32 -> f32 cast, and an arithmetic
+    right shift leaves bit 0 equal to the selected bit all the same."""
     nw, bn = words.shape
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (nw, WORD, bn), 1)
-    bits = (words[:, None, :] >> shifts) & jnp.uint32(1)
+    words = jax.lax.bitcast_convert_type(words, jnp.int32)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (nw, WORD, bn), 1)
+    bits = (words[:, None, :] >> shifts) & 1
     return bits.reshape(nw * WORD, bn)
 
 
@@ -141,15 +146,18 @@ def sac_matmul_kernel(
         seg_ref[b] += jax.lax.dot_general(
             a, plane * signf_ref[...],
             dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=exact_dot_precision(a_ref.dtype),
             preferred_element_type=jnp.float32,
         )
 
     @pl.when(w == num_work - 1)
     def _rear_adder_tree():
-        # Single shift-and-add over segments + single dequant scale (SAC).
-        weights = (2.0 ** jnp.arange(bits - 1, dtype=jnp.float32)).reshape(
-            bits - 1, 1, 1)
-        acc = jnp.sum(seg_ref[...] * weights, axis=0)
+        # Single shift-and-add over segments + single dequant scale (SAC),
+        # summed in plane order with Python-float weights (exact powers of
+        # two) — the order ``core.sac.sac_matmul_planes`` replays.
+        acc = seg_ref[0]
+        for b in range(1, bits - 1):
+            acc = acc + seg_ref[b] * float(2 ** b)
         out_ref[...] = acc * scale_ref[...]
 
 

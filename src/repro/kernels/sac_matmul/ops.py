@@ -1,11 +1,11 @@
 """Jitted public wrappers for the SAC bit-plane Pallas kernel.
 
 ``sac_matmul_pallas``: the raw [M, K] x kneaded [K, N] op — padding/tiling
-policy and backend dispatch (compiled Pallas on TPU, ``interpret=True``
-elsewhere; this container is CPU-only and interpret mode executes the kernel
-body faithfully for validation).  Accepts activations sized to either the
-stored (tile-aligned) or the logical reduction dim and zero-pads internally —
-padded rows meet all-zero weight rows that the schedule never dispatches.
+policy and backend dispatch (compiled Pallas on TPU, interpret mode on CPU,
+an error anywhere else — ``repro.kernels.backend``).  Accepts activations
+sized to either the stored (tile-aligned) or the logical reduction dim and
+zero-pads internally — padded rows meet all-zero weight rows that the
+schedule never dispatches.
 
 ``sac_conv2d``: the batched convolution entry point — im2col + schedule-
 compacted SAC matmul behind **one** ``pallas_call``: the kernel grid's M
@@ -41,17 +41,13 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import activation_occupancy
 from repro.core.kneading import KneadedWeight, ShardedKneadedWeight
 from repro.core.schedule import KneadedSchedule
+from repro.kernels.backend import interpret_mode
 from repro.kernels.sac_matmul.kernel import sac_matmul_pallas_call
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(
@@ -70,7 +66,6 @@ def sac_matmul_pallas(
     kw: KneadedWeight,
     *,
     bm: int = 256,
-    interpret: bool | None = None,
     skip_activations: bool = False,
 ) -> jax.Array:
     """[M, K] @ kneaded [K, N] -> [M, N] f32 via the Pallas SAC kernel.
@@ -103,8 +98,6 @@ def sac_matmul_pallas(
             f"{kw.planes.shape} — scan/index the leading stack axes down to "
             f"one slice first (expert banks: models.blocks."
             f"_dispatch_compute_kneaded, docs/DESIGN.md §13)")
-    if interpret is None:
-        interpret = not _on_tpu()
     a, m, bm_eff = _pad_activations(a, kw, bm)
     if skip_activations:
         presence = activation_occupancy.ktile_presence(a, kw.ks)
@@ -117,7 +110,7 @@ def sac_matmul_pallas(
     out = _run(
         a, kw.planes, kw.signs, kw.scale, kw.schedule, mask,
         bits=kw.bits, ks=kw.ks, n_block=kw.n_block, bm=bm_eff,
-        interpret=interpret,
+        interpret=interpret_mode(),
     )
     return out[:m]
 
@@ -168,7 +161,6 @@ def sac_matmul_pallas_sharded(
     axis: str = "model",
     *,
     bm: int = 256,
-    interpret: bool | None = None,
     skip_activations: bool = False,
 ) -> jax.Array:
     """[M, K] @ N-sharded kneaded [K, N] -> [M, N] f32, one kernel per shard.
@@ -208,8 +200,7 @@ def sac_matmul_pallas_sharded(
     partition's ``tile_slot`` gather epilogue is untouched: masking changes
     which items a tile executes, never which shard/slot the tile lives in.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = interpret_mode()
     a, m, bm_eff = _pad_activations(a, skw, bm)
     # per-slot survival masks, one row of shards: [S, T, num_work]
     base = jax.lax.broadcasted_iota(
@@ -242,9 +233,9 @@ def sac_matmul_pallas_sharded(
         out = jnp.concatenate(outs, axis=1)
     else:
         sharded = (P(axis),) * 7
-        out = shard_map(
+        out = jax.shard_map(
             one_shard, mesh=mesh, in_specs=(P(),) + sharded,
-            out_specs=P(None, axis), check_rep=False,
+            out_specs=P(None, axis), check_vma=False,
         )(a, skw.planes, skw.signs, skw.scale, skw.counts,
           skw.plane_ids, skw.ktile_ids, mask)
     if skw.partition == "balanced":
@@ -260,10 +251,15 @@ def im2col(x: jax.Array, k: int, stride: int) -> jax.Array:
     The single source of truth for the conv lowering — the float path in
     ``models/cnn.py`` imports this same function, so float and kneaded
     convolutions see identical patch layouts by construction.
+
+    The patches are a convolution with a one-hot kernel, which a TPU runs
+    on the MXU: at default precision that rounds f32 activations to bf16,
+    so the copy asks for full precision to stay exact.
     """
     return jax.lax.conv_general_dilated_patches(
         x, (k, k), (stride, stride), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST)
 
 
 def sac_conv2d(
@@ -277,7 +273,6 @@ def sac_conv2d(
     bm: int = 256,
     mesh=None,
     axis: str = "model",
-    interpret: bool | None = None,
 ) -> jax.Array:
     """2-D convolution as im2col + SAC matmul against a kneaded filter.
 
@@ -309,14 +304,13 @@ def sac_conv2d(
         if impl != "pallas":
             raise ValueError("sharded kneaded weights execute through the "
                              f"Pallas kernel only, got impl={impl!r}")
-        out = sac_matmul_pallas_sharded(a, kw, mesh, axis, bm=bm,
-                                        interpret=interpret)
+        out = sac_matmul_pallas_sharded(a, kw, mesh, axis, bm=bm)
         out = out[:, :kw.logical_n]
     elif impl != "pallas":
         from repro.core.sac import sac_matmul
         out = sac_matmul(a.astype(jnp.float32), kw, impl=impl)
     else:
-        out = sac_matmul_pallas(a, kw, bm=bm, interpret=interpret)
+        out = sac_matmul_pallas(a, kw, bm=bm)
         out = out[:, :kw.logical_n]
     out = out.reshape(lead + (kw.logical_n,)).astype(jnp.float32)
     if bias is not None:

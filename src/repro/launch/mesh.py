@@ -12,18 +12,26 @@ over DCN) and optionally pipeline stages (runtime.pipeline).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto(n: int):
+    """Auto axis types: the sharding rules constrain with
+    ``with_sharding_constraint``, which ``jax.make_mesh``'s default
+    (Explicit) axes refuse."""
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, _auto(len(axes)))
 
 
 def make_host_mesh():
     """Whatever devices exist right now, as a 1-D 'data' mesh (examples)."""
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return jax.make_mesh((n,), ("data",), _auto(1))
 
 
 def make_model_mesh(num_devices: int | None = None):

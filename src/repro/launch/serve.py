@@ -4,6 +4,8 @@
 trains nothing: initializes (or restores) params, kneads them to the
 requested precision, and serves a batch of synthetic prompts — the
 end-to-end demonstration of the paper's technique as a serving feature.
+``--smoke`` (the default) serves the arch's reduced CPU-size config;
+``--full`` serves its published config.
 ``--impl pallas`` serves through the fully-kneaded bit-plane path (the SAC
 kernel's decode-GEMV fast path, docs/DESIGN.md §7); the default "quant"
 keeps the integer-matmul form selected by ``--quant``.  ``--shards N``
@@ -27,6 +29,10 @@ import time
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
+    ap.add_argument("--smoke", action="store_true", default=True,
+                    help="serve the arch's reduced config (default)")
+    ap.add_argument("--full", dest="smoke", action="store_false",
+                    help="serve the arch's published config")
     ap.add_argument("--quant", type=int, default=0, choices=[0, 8, 4])
     ap.add_argument("--impl", default="quant",
                     choices=["quant", "float", "int", "planes", "pallas"],
@@ -93,13 +99,16 @@ def main():
 
     import jax
 
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.checkpoint import checkpointer as ckpt
     from repro.configs.registry import get_config
     from repro.inference.engine import (ServingConfig, ServingEngine,
                                         serving_bytes)
     from repro.models.lm import LanguageModel
 
-    cfg = get_config(args.arch, smoke=True)
+    cfg = get_config(args.arch, smoke=args.smoke)
     model = LanguageModel(cfg)
     params = model.init(jax.random.PRNGKey(0))
     if args.ckpt_dir:

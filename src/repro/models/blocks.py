@@ -457,7 +457,6 @@ def _moe_kneaded(h2, e2, g2, kwi, kwo, *, cfg: ModelConfig, mesh,
     if not ep:
         return _dispatch_compute_kneaded(h2, e2, g2, kwi, kwo, cfg=cfg,
                                          e_offset=0, cap=cap, dtype=dtype)
-    from jax.experimental.shard_map import shard_map
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     e_loc = e // mesh.shape["expert"]
 
@@ -475,13 +474,13 @@ def _moe_kneaded(h2, e2, g2, kwi, kwo, *, cfg: ModelConfig, mesh,
     # every bank array carries the (local) expert axis leading -> a uniform
     # P("expert") pytree spec shards dim 0 and replicates the rest
     bank_spec = jax.tree.map(lambda _: P("expert"), kwi)
-    return shard_map(
+    return jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(batch_axes, None), P(batch_axes, None),
                   P(batch_axes, None), bank_spec,
                   jax.tree.map(lambda _: P("expert"), kwo)),
         out_specs=P(batch_axes, None),
-        check_rep=False,
+        check_vma=False,
     )(h2, e2, g2, kwi, kwo)
 
 
@@ -529,7 +528,6 @@ def moe_apply(p, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
                                e_offset=0, cap=cap, dtype=dtype,
                                wi_packed4=wi_p4, wo_packed4=wo_p4)
     else:
-        from jax.experimental.shard_map import shard_map
         batch_axes = tuple(a for a in ("pod", "data")
                            if a in mesh.axis_names)
         n_batch_shards = int(np.prod([mesh.shape[a] for a in batch_axes])) or 1
@@ -553,13 +551,13 @@ def moe_apply(p, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
                 wi_packed4=wi_p4, wo_packed4=wo_p4)
             return jax.lax.psum(y, "model")
 
-        y2 = shard_map(
+        y2 = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(batch_axes, None), P(batch_axes, None),
                       P(batch_axes, None), P("model", None, None),
                       escale_spec, P("model", None, None), escale_spec),
             out_specs=P(batch_axes, None),
-            check_rep=False,
+            check_vma=False,
         )(h2, e2, g2, wi_q, wi_s_arg, wo_q, wo_s_arg)
     y = y2.reshape(b, s, d)
     if cfg.dense_residual:

@@ -20,7 +20,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 PyTree = Any
@@ -84,5 +83,5 @@ def pipeline_apply(
 
     in_specs = (jax.tree.map(lambda _: P(stage_axis), stacked_params),
                 P())
-    return shard_map(stage_fn, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                     check_rep=False)(stacked_params, x)
+    return jax.shard_map(stage_fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=P(), check_vma=False)(stacked_params, x)

@@ -77,7 +77,7 @@ def test_param_spec_rules():
 
 
 def test_param_spec_moe_and_embed():
-    mesh = jax.sharding.AbstractMesh((("data", 2), ("model", 2)))
+    mesh = jax.sharding.AbstractMesh((2, 2), ("data", "model"))
     assert sharding.param_spec("layers/moe/wi", (4, 128, 512, 1024), mesh) \
         == P(None, "model", ("data",), None)
     assert sharding.param_spec("embed", (1024, 512), mesh) \
@@ -93,7 +93,7 @@ def test_param_spec_moe_and_embed():
 
 
 def test_cache_sharding_seq_over_model():
-    mesh = jax.sharding.AbstractMesh((("data", 2), ("model", 2)))
+    mesh = jax.sharding.AbstractMesh((2, 2), ("data", "model"))
     cache = {"k": jax.ShapeDtypeStruct((8, 4, 8192, 2, 16), jnp.bfloat16),
              "k_scale": jax.ShapeDtypeStruct((8, 4, 8192, 2), jnp.float32),
              "ssm": jax.ShapeDtypeStruct((8, 4, 5, 7), jnp.float32)}
@@ -123,7 +123,8 @@ _SUBPROCESS_MOE = textwrap.dedent("""
              "labels": jax.random.randint(key, (4, 32), 0, cfg.vocab_size)}
     loss_1dev = float(model.loss(params, batch))         # no mesh: local MoE
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         (jax.sharding.AxisType.Auto,) * 2)
     from repro.runtime import sharding as shd
     pshard = shd.tree_shardings(jax.eval_shape(lambda: params), mesh)
     params_s = jax.tree.map(lambda x, s: jax.device_put(x, s), params, pshard)
@@ -192,7 +193,6 @@ def test_compressed_psum_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, json
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.optim.grad_comp import compressed_psum
 
@@ -201,8 +201,8 @@ def test_compressed_psum_subprocess():
 
         def f(xl):
             return compressed_psum(xl[0], "data")
-        out = shard_map(f, mesh=mesh, in_specs=P("data", None),
-                        out_specs=P(), check_rep=False)(x)
+        out = jax.shard_map(f, mesh=mesh, in_specs=P("data", None),
+                            out_specs=P(), check_vma=False)(x)
         ref = jnp.sum(x, 0)
         rel = float(jnp.max(jnp.abs(out - ref)) / jnp.max(jnp.abs(ref)))
         print(json.dumps({"rel": rel}))

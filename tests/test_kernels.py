@@ -157,3 +157,17 @@ def test_kernel_bytes_reduction():
     assert kw8.packed_bytes() < 0.75 * dense
     assert kw16.packed_bytes() < 1.5 * dense
     assert kw8.packed_bytes() < kw16.packed_bytes()
+
+
+@pytest.mark.parametrize("backend,want", [("cpu", True), ("tpu", False),
+                                          ("gpu", None)])
+def test_interpret_mode_by_backend(monkeypatch, backend, want):
+    """Compiled on TPU, interpreted on CPU, refused anywhere else — a
+    serving run never interprets the kernel on an accelerator."""
+    from repro.kernels import backend as kb
+    monkeypatch.setattr(kb.jax, "default_backend", lambda: backend)
+    if want is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            kb.interpret_mode()
+    else:
+        assert kb.interpret_mode() is want

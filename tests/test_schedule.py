@@ -270,7 +270,7 @@ def test_balanced_sharded_bit_exact_random(seed, shards):
     unsharded, bitwise (the gather restores original column order and each
     tile's f32 accumulation sequence is untouched)."""
     rng = np.random.default_rng(seed)
-    w = np.asarray(_sparse_w(seed, 512, 512, sparsity=0.5))
+    w = np.array(_sparse_w(seed, 512, 512, sparsity=0.5))   # writable copy
     # zero random whole N-blocks so tiles carry genuinely unequal work
     for j in range(4):
         if rng.random() < 0.5:

@@ -91,3 +91,27 @@ def test_example_loss_descends():
     log = tr.run()
     steps = sorted(log)
     assert log[steps[-1]]["loss"] < log[steps[0]]["loss"] - 0.3
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(monkeypatch, tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; without
+    it the cache goes to one fixed directory in the checkout."""
+    from pathlib import Path
+
+    from repro.runtime import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert compile_cache.enable_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = compile_cache.CHECKOUT_CACHE_DIR
+            assert want.parent == Path(__file__).resolve().parents[1]
+            assert compile_cache.enable_compile_cache() == str(want)
+            assert jax.config.jax_compilation_cache_dir == str(want)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
