@@ -1,9 +1,8 @@
 """Benchmark entry point: ``PYTHONPATH=src python -m benchmarks.run``.
 
 One section per paper table/figure (cycle-accurate cost model on real
-quantized weights), the Pallas kernel metrics, and the roofline aggregation
-over whatever dry-run artifacts exist.  Output format: name,us_per_call,
-derived (CSV).
+quantized weights) and the Pallas kernel metrics.  Output format:
+name,us_per_call,derived (CSV).
 """
 from __future__ import annotations
 
@@ -15,10 +14,9 @@ def main() -> None:
     from repro.runtime.compile_cache import enable_compile_cache
     enable_compile_cache()
     failures = 0
-    from benchmarks import bench_kernels, bench_paper_tables, roofline
+    from benchmarks import bench_kernels, bench_paper_tables
     sections = [("paper_tables", bench_paper_tables.run),
-                ("kernels", bench_kernels.run),
-                ("roofline", roofline.run)]
+                ("kernels", bench_kernels.run)]
     print("name,us_per_call,derived")
     for name, fn in sections:
         try:

@@ -49,6 +49,7 @@ from repro.inference.frontend import (RequestFrontEnd, RequestHandle,
                                       validate_buckets)
 from repro.inference.resilience import ServingFaultPolicy
 from repro.models import cnn
+from repro.runtime.spans import span
 
 PyTree = Any
 
@@ -171,7 +172,6 @@ class CNNServingEngine(RequestFrontEnd):
         dimension and are sliced off), so the jitted forward sees one shape
         per bucket — no per-request-count retraces.
         """
-        from repro.inference import frontend as fe
         buckets = self.scfg.buckets
         cap = buckets[-1]
         results: Dict[int, jax.Array] = {}
@@ -181,43 +181,56 @@ class CNNServingEngine(RequestFrontEnd):
             bucket = next(bk for bk in buckets if bk >= b)
             start = time.perf_counter()
             start_tick = self.ticks
-            xb = jnp.stack([r.payload for r in chunk])
-            if bucket > b:
-                xb = jnp.pad(xb, ((0, bucket - b),) + ((0, 0),) * 3)
+            with span("serve.batch", n=b, bucket=bucket):
+                xb = jnp.stack([r.payload for r in chunk])
+                if bucket > b:
+                    xb = jnp.pad(xb, ((0, bucket - b),) + ((0, 0),) * 3)
             self.ticks += 1                     # one jitted forward launch
-            out = jax.block_until_ready(self.logits(xb))[:b]
-            done = time.perf_counter()
-            pol = self.scfg.fault_policy
-            bad_rows = set()
-            if pol is not None and pol.nan_guard:
-                import numpy as np
-                finite = np.isfinite(np.asarray(out).astype(np.float32))
-                bad_rows = {i for i in range(b) if not finite[i].all()}
-            for i, req in enumerate(chunk):
-                if i in bad_rows:
-                    req.state = fe.FAILED
-                    req.error = "non-finite logits"
-                    req.finish_t = done
-                    req.finish_tick = self.ticks
-                    self._fault_event("nan_quarantined", id=req.id)
-                    self._fault_event("failed_requests", id=req.id,
-                                      reason=req.error)
-                    continue
-                req.state = fe.DONE
-                req.result = out[i]
-                req.admit_t, req.finish_t = start, done
-                req.admit_tick, req.finish_tick = start_tick, self.ticks
-                results[req.id] = req.result
-                self._log_request(
-                    id=req.id,
-                    latency_ms=(done - req.submit_t) * 1e3,
-                    queue_wait_ms=(start - req.submit_t) * 1e3,
-                    decode_ms=(done - start) * 1e3,
-                    latency_ticks=self.ticks - req.submit_tick,
-                    bucket=bucket,
-                    batch_fill=b / bucket,
-                )
+            with span("serve.forward"):
+                out = self.logits(xb)
+            with span("serve.forward_sync"):
+                out = jax.block_until_ready(out)
+            with span("serve.finish"):
+                self._finish(chunk, out[:b], bucket, start, start_tick,
+                             results)
         return results
+
+    def _finish(self, chunk, out: jax.Array, bucket: int, start: float,
+                start_tick: int, results: Dict[int, jax.Array]) -> None:
+        """Per-request bookkeeping of one served chunk."""
+        from repro.inference import frontend as fe
+        b = len(chunk)
+        done = time.perf_counter()
+        pol = self.scfg.fault_policy
+        bad_rows = set()
+        if pol is not None and pol.nan_guard:
+            import numpy as np
+            finite = np.isfinite(np.asarray(out).astype(np.float32))
+            bad_rows = {i for i in range(b) if not finite[i].all()}
+        for i, req in enumerate(chunk):
+            if i in bad_rows:
+                req.state = fe.FAILED
+                req.error = "non-finite logits"
+                req.finish_t = done
+                req.finish_tick = self.ticks
+                self._fault_event("nan_quarantined", id=req.id)
+                self._fault_event("failed_requests", id=req.id,
+                                  reason=req.error)
+                continue
+            req.state = fe.DONE
+            req.result = out[i]
+            req.admit_t, req.finish_t = start, done
+            req.admit_tick, req.finish_tick = start_tick, self.ticks
+            results[req.id] = req.result
+            self._log_request(
+                id=req.id,
+                latency_ms=(done - req.submit_t) * 1e3,
+                queue_wait_ms=(start - req.submit_t) * 1e3,
+                decode_ms=(done - start) * 1e3,
+                latency_ticks=self.ticks - req.submit_tick,
+                bucket=bucket,
+                batch_fill=b / bucket,
+            )
 
     # ------------------------------------------------------------- reporting
 

@@ -70,7 +70,9 @@ class Request:
 
     ``payload`` is engine-specific (a 1-D token prompt for the LM engine,
     an [H, W, C] image for the CNN engine).  Wall-clock stamps
-    (``submit_t``/``admit_t``/``finish_t``) feed ``latency_stats``;
+    (``submit_t``/``admit_t``/``first_token_t``/``finish_t``; the first
+    token's only under the continuous scheduler, once that token is on the
+    host) feed ``latency_stats``;
     the ``*_tick`` twins are stamped from the engine's deterministic
     virtual-launch clock so benches can compare schedulers bit-for-bit.
     """
@@ -86,6 +88,7 @@ class Request:
     slot: Optional[int] = None
     submit_t: float = 0.0
     admit_t: float = 0.0
+    first_token_t: float = 0.0
     finish_t: float = 0.0
     submit_tick: int = 0
     admit_tick: int = 0
@@ -135,6 +138,27 @@ class RequestHandle(int):
     @property
     def deadline(self) -> Optional[float]:
         return self._req.deadline
+
+    @property
+    def submit_t(self) -> float:
+        """``time.perf_counter()`` at submit."""
+        return self._req.submit_t
+
+    @property
+    def admit_t(self) -> float:
+        """``time.perf_counter()`` at admission (0.0 while queued)."""
+        return self._req.admit_t
+
+    @property
+    def first_token_t(self) -> float:
+        """``time.perf_counter()`` once the first token reached the host
+        (continuous scheduler; 0.0 before that, and on other paths)."""
+        return self._req.first_token_t
+
+    @property
+    def finish_t(self) -> float:
+        """``time.perf_counter()`` at completion (0.0 until then)."""
+        return self._req.finish_t
 
     @property
     def retries(self) -> int:
@@ -271,10 +295,11 @@ class RequestFrontEnd:
 
         Beyond total latency, the summary breaks out **queue wait**
         (submit -> start of execution) vs **decode time** (execution
-        start -> completion) at p50/p95 each, so the batch and continuous
-        schedulers are comparable from the CLI: batch mode hides its
-        wave barrier in queue wait, continuous in slightly longer decode
-        (shared slots).
+        start -> completion) at p50/p95 each, and **time to first token**
+        (submit -> first token on the host) where the continuous scheduler
+        stamped it, so the batch and continuous schedulers are comparable
+        from the CLI: batch mode hides its wave barrier in queue wait,
+        continuous in slightly longer decode (shared slots).
         """
         lat = np.array([r["latency_ms"] for r in self._request_log])
         if lat.size == 0:
@@ -295,7 +320,7 @@ class RequestFrontEnd:
         if fill:
             out["mean_batch_fill"] = float(np.mean(fill))
         for key, label in (("queue_wait_ms", "queue_wait"),
-                           ("decode_ms", "decode")):
+                           ("decode_ms", "decode"), ("ttft_ms", "ttft")):
             vals = np.array([r[key] for r in self._request_log if key in r])
             if vals.size:
                 out[f"{label}_p50_ms"] = float(np.percentile(vals, 50))

@@ -60,6 +60,7 @@ from repro.inference import frontend as fe
 from repro.inference.kv_pool import KVBlockPool
 from repro.inference.resilience import StepTimeout
 from repro.runtime import fault_tolerance as ft
+from repro.runtime.spans import span
 
 PyTree = Any
 
@@ -244,11 +245,16 @@ class ContinuousScheduler:
         group = self._admission_group()
         if not group:
             return
+        plen = group[0].prompt_len
+        bucket = next(b for b in self.eng.scfg.buckets if b >= len(group))
+        with span("serve.admit", n=len(group), bucket=bucket, plen=plen):
+            self._admit_group(group, plen, bucket)
+
+    def _admit_group(self, group: List[fe.Request], plen: int,
+                     bucket: int) -> None:
         ids = {r.id for r in group}
         self.eng._pending = [r for r in self.eng._pending
                              if r.id not in ids]
-        plen = group[0].prompt_len
-        bucket = next(b for b in self.eng.scfg.buckets if b >= len(group))
         now = time.perf_counter()
         for r in group:
             r.slot = self._free_slots()[0]
@@ -256,52 +262,59 @@ class ContinuousScheduler:
             self.slots[r.slot] = r
             r.state = fe.RUNNING
             r.admit_t, r.admit_tick = now, self.eng.ticks
-        toks = jnp.stack([r.payload for r in group])
-        if bucket > len(group):
-            toks = jnp.pad(toks, ((0, bucket - len(group)), (0, 0)))
-        # attempt counter advances BEFORE the launch (fault included), so
-        # a retried step moves past a one-shot injected fault index
-        attempt = self._prefill_calls
-        self._prefill_calls += 1
-        if self.policy is not None and self.policy.injector is not None:
-            # after slot/pool assignment, so recovery sees the group live
-            self.policy.injector.maybe_fail_prefill(attempt)
-        with self.eng._mesh_ctx():
-            logits, pre_cache = self.eng._prefill(self.eng.params,
-                                                  {"tokens": toks})
-        self.eng.ticks += 1
-        logits, bad_rows = self._guard_logits(logits, group)
-        tok0 = np.asarray(self.eng._select(logits, self._next_key()))
-        # grow the live cache geometry BEFORE inserting the new rows
-        if self._cache is None:
-            extent = self.pool.extent()
-            batch = next(b for b in self.slot_buckets
-                         if b >= max(r.slot for r in group) + 1)
-            spec = self.eng.model.cache_spec(batch=batch, max_len=extent)
-            self._cache = {k: jnp.zeros(v.shape, v.dtype)
-                           for k, v in spec.items()}
-            for key, pad in _SEQ_PAD.items():
-                if key in self._cache and pad != 0.0:
-                    self._cache[key] = jnp.full(
-                        self._cache[key].shape, pad,
-                        self._cache[key].dtype)
-            self._batch, self._extent = batch, extent
-        else:
-            self._resize_cache()
-        for i, r in enumerate(group):
-            if i in bad_rows:
-                self.eng._fault_event("nan_quarantined", id=r.id,
-                                      at="prefill")
-                self._requeue_or_fail(r, "non-finite logits at prefill")
-                continue
-            r.out.append(int(tok0[i]))
-            if len(r.out) >= r.num_tokens:
-                self._retire(r)       # single-token request: done at prefill
+        with span("serve.prefill"):
+            toks = jnp.stack([r.payload for r in group])
+            if bucket > len(group):
+                toks = jnp.pad(toks, ((0, bucket - len(group)), (0, 0)))
+            # attempt counter advances BEFORE the launch (fault included),
+            # so a retried step moves past a one-shot injected fault index
+            attempt = self._prefill_calls
+            self._prefill_calls += 1
+            if self.policy is not None and self.policy.injector is not None:
+                # after slot/pool assignment, so recovery sees the group live
+                self.policy.injector.maybe_fail_prefill(attempt)
+            with self.eng._mesh_ctx():
+                logits, pre_cache = self.eng._prefill(self.eng.params,
+                                                      {"tokens": toks})
+            self.eng.ticks += 1
+            logits, bad_rows = self._guard_logits(logits, group)
+            tok0 = self.eng._select(logits, self._next_key())
+        with span("serve.prefill_sync"):
+            tok0 = np.asarray(tok0)
+        first_t = time.perf_counter()
+        with span("serve.cache_write"):
+            # grow the live cache geometry BEFORE inserting the new rows
+            if self._cache is None:
+                extent = self.pool.extent()
+                batch = next(b for b in self.slot_buckets
+                             if b >= max(r.slot for r in group) + 1)
+                spec = self.eng.model.cache_spec(batch=batch, max_len=extent)
+                self._cache = {k: jnp.zeros(v.shape, v.dtype)
+                               for k, v in spec.items()}
+                for key, pad in _SEQ_PAD.items():
+                    if key in self._cache and pad != 0.0:
+                        self._cache[key] = jnp.full(
+                            self._cache[key].shape, pad,
+                            self._cache[key].dtype)
+                self._batch, self._extent = batch, extent
             else:
-                row = {k: jnp.take(v, jnp.array([i]), axis=self._axes[k][0])
-                       for k, v in pre_cache.items()}
-                self._write_slot(r.slot, row, plen)
-        self._resize_cache()          # a same-step retirement may shrink
+                self._resize_cache()
+            for i, r in enumerate(group):
+                if i in bad_rows:
+                    self.eng._fault_event("nan_quarantined", id=r.id,
+                                          at="prefill")
+                    self._requeue_or_fail(r, "non-finite logits at prefill")
+                    continue
+                r.out.append(int(tok0[i]))
+                r.first_token_t = first_t
+                if len(r.out) >= r.num_tokens:
+                    self._retire(r)   # single-token request: done at prefill
+                else:
+                    row = {k: jnp.take(v, jnp.array([i]),
+                                       axis=self._axes[k][0])
+                           for k, v in pre_cache.items()}
+                    self._write_slot(r.slot, row, plen)
+            self._resize_cache()      # a same-step retirement may shrink
 
     def _retire(self, req: fe.Request) -> None:
         self.slots[req.slot] = None
@@ -316,6 +329,7 @@ class ContinuousScheduler:
             id=req.id,
             latency_ms=(req.finish_t - req.submit_t) * 1e3,
             queue_wait_ms=(req.admit_t - req.submit_t) * 1e3,
+            ttft_ms=(req.first_token_t - req.submit_t) * 1e3,
             decode_ms=(req.finish_t - req.admit_t) * 1e3,
             latency_ticks=req.finish_tick - req.submit_tick,
             queue_wait_ticks=req.admit_tick - req.submit_tick,
@@ -335,52 +349,64 @@ class ContinuousScheduler:
         if pol is not None and pol.injector is not None:
             pol.injector.maybe_fail_decode(attempt)
         b = self._batch
-        tok = np.zeros((b, 1), np.int32)
-        pos = np.zeros((b,), np.int32)
-        for slot, r in live:
-            tok[slot, 0] = r.out[-1]
-            pos[slot] = r.prompt_len + len(r.out) - 1
-        if self._timer is not None:
-            self._timer.start()
-        with self.eng._mesh_ctx():
-            logits, self._cache = self.eng._decode(
-                self.eng.params, jnp.asarray(tok), jnp.asarray(pos),
-                self._cache)
-        self.eng.ticks += 1
-        if self._timer is not None:
-            # the launch is async; time to logits-ready, which the token
-            # select below forces anyway
+        with span("serve.decode", live=len(live), rows=b,
+                  extent=self._extent):
+            tok = np.zeros((b, 1), np.int32)
+            pos = np.zeros((b,), np.int32)
+            for slot, r in live:
+                tok[slot, 0] = r.out[-1]
+                pos[slot] = r.prompt_len + len(r.out) - 1
+            if self._timer is not None:
+                self._timer.start()
+            with self.eng._mesh_ctx():
+                logits, self._cache = self.eng._decode(
+                    self.eng.params, jnp.asarray(tok), jnp.asarray(pos),
+                    self._cache)
+            self.eng.ticks += 1
+            if self._timer is not None:
+                self._watch(logits, attempt)
+            rows: List[Optional[fe.Request]] = [None] * b
+            for slot, r in live:
+                rows[slot] = r
+            logits, bad_rows = self._guard_logits(logits, rows)
+            nxt = self.eng._select(logits, self._next_key())
+        with span("serve.token_sync"):
+            nxt = np.asarray(nxt)
+        with span("serve.retire"):
+            for slot, r in live:
+                if slot in bad_rows:
+                    # row-independence makes surviving rows' cache writes
+                    # valid; only this request's state is junk
+                    self.eng._fault_event("nan_quarantined", id=r.id,
+                                          at="decode")
+                    self._requeue_or_fail(r, "non-finite logits at decode")
+                    continue
+                r.out.append(int(nxt[slot]))
+                if len(r.out) >= r.num_tokens:
+                    self._retire(r)
+            self._resize_cache()
+
+    def _watch(self, logits, attempt: int) -> None:
+        """Watchdog (fault policy): time the decode launch to logits-ready
+        and flag stragglers; raise on a timeout that counts as a fault."""
+        pol = self.policy
+        # the launch is async; time to logits-ready, which the token
+        # select forces anyway
+        with span("serve.watchdog_sync"):
             jax.block_until_ready(logits)
-            flagged = len(self._timer.straggler_steps)
-            dt = self._timer.stop(attempt)
-            if len(self._timer.straggler_steps) > flagged:
-                self.eng._fault_counters["straggler_steps"] += 1
-            if pol.step_timeout_s and dt > pol.step_timeout_s:
-                self.eng._fault_event("watchdog_timeouts",
-                                      step=attempt, dt_s=dt)
-                if pol.timeout_is_fault:
-                    # before any token lands: recovery replays the whole
-                    # step, so no request observes a half-applied step
-                    raise StepTimeout(
-                        f"decode launch {attempt} took "
-                        f"{dt:.3f}s > step_timeout_s={pol.step_timeout_s}")
-        rows: List[Optional[fe.Request]] = [None] * b
-        for slot, r in live:
-            rows[slot] = r
-        logits, bad_rows = self._guard_logits(logits, rows)
-        nxt = np.asarray(self.eng._select(logits, self._next_key()))
-        for slot, r in live:
-            if slot in bad_rows:
-                # row-independence makes surviving rows' cache writes
-                # valid; only this request's state is junk
-                self.eng._fault_event("nan_quarantined", id=r.id,
-                                      at="decode")
-                self._requeue_or_fail(r, "non-finite logits at decode")
-                continue
-            r.out.append(int(nxt[slot]))
-            if len(r.out) >= r.num_tokens:
-                self._retire(r)
-        self._resize_cache()
+        flagged = len(self._timer.straggler_steps)
+        dt = self._timer.stop(attempt)
+        if len(self._timer.straggler_steps) > flagged:
+            self.eng._fault_counters["straggler_steps"] += 1
+        if pol.step_timeout_s and dt > pol.step_timeout_s:
+            self.eng._fault_event("watchdog_timeouts",
+                                  step=attempt, dt_s=dt)
+            if pol.timeout_is_fault:
+                # before any token lands: recovery replays the whole
+                # step, so no request observes a half-applied step
+                raise StepTimeout(
+                    f"decode launch {attempt} took "
+                    f"{dt:.3f}s > step_timeout_s={pol.step_timeout_s}")
 
     # --------------------------------------- fault handling (§10; policy)
 
@@ -402,7 +428,8 @@ class ContinuousScheduler:
                   and inj.poison_request(r.id)]
         if not pol.nan_guard and not poison:
             return logits, set()
-        host = np.asarray(logits).copy()
+        with span("serve.guard_sync"):
+            host = np.asarray(logits).copy()
         for i in poison:
             host[i] = np.nan
         bad_rows: set = set()
@@ -516,21 +543,22 @@ class ContinuousScheduler:
         then FAILED) and rebuilds the slot table — the loop itself never
         dies to a step fault.
         """
-        if self.policy is None:
-            self._expire()
-            self._admit()
-            self._decode_once()
-        else:
-            try:
+        with span("serve.step"):
+            if self.policy is None:
                 self._expire()
-                self._lose_slots()
                 self._admit()
                 self._decode_once()
-                self._fault_streak = 0     # clean step: demotion de-arms
-            except Exception as exc:  # noqa: BLE001 — any step fault
-                self._recover(exc)         # enters bounded recovery
-            self._step_idx += 1
-            self._maybe_wait_backoff()
+            else:
+                try:
+                    self._expire()
+                    self._lose_slots()
+                    self._admit()
+                    self._decode_once()
+                    self._fault_streak = 0   # clean step: demotion de-arms
+                except Exception as exc:  # noqa: BLE001 — any step fault
+                    self._recover(exc)       # enters bounded recovery
+                self._step_idx += 1
+                self._maybe_wait_backoff()
         return bool(self.eng._pending or any(r is not None
                                              for r in self.slots))
 

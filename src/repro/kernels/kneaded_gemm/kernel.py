@@ -79,4 +79,5 @@ def kneaded_gemm_pallas_call(
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
+        name="kneaded_gemm",
     )(a, q, scale)

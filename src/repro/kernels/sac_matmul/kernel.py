@@ -230,5 +230,6 @@ def sac_matmul_pallas_call(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
+        name="sac_matmul",
     )(mask.astype(jnp.int32), schedule.plane_ids, schedule.ktile_ids,
       a, planes, signs, scale)

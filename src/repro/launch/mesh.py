@@ -74,7 +74,7 @@ def make_serving_mesh(model_shards: int = 1, *, expert_shards: int = 1):
     return jax.sharding.Mesh(arr, ("expert", "model"))
 
 
-# v5e hardware constants used by the roofline analysis (benchmarks/roofline).
+# v5e hardware constants used by the dry-run cost terms (launch/dryrun.py).
 PEAK_FLOPS_BF16 = 197e12        # per chip
 HBM_BW = 819e9                  # bytes/s per chip
 ICI_BW = 50e9                   # bytes/s per link

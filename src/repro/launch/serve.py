@@ -205,6 +205,9 @@ def main():
                   f"{stats['queue_wait_p95_ms']:.1f} ms | decode p50/p95: "
                   f"{stats['decode_p50_ms']:.1f}/"
                   f"{stats['decode_p95_ms']:.1f} ms")
+        if "ttft_p50_ms" in stats:
+            print(f"  time to first token p50/p95: "
+                  f"{stats['ttft_p50_ms']:.1f}/{stats['ttft_p95_ms']:.1f} ms")
     if "routed_tokens" in stats:
         print(f"routing: {stats['routed_tokens']} tokens routed over "
               f"{stats['routing_steps']} steps, "

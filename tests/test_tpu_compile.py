@@ -107,3 +107,42 @@ def test_kneaded_gemm_int8_compiles(one_chip):
         a, q, s, bm=256, bn=256, bk=512, interpret=False)
     ).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_kernels_keep_their_names_inside_jitted_wrappers(one_chip):
+    """The profiler names a kernel's ops after the HLO instruction, which
+    takes the ``pallas_call`` name even when the kernel runs through the
+    jitted wrappers of ``ops.py`` inside a jitted step."""
+    from repro.kernels.kneaded_gemm import ops as gemm_ops
+    from repro.kernels.sac_matmul import ops as sac_ops
+
+    m, k, n = 8, 1024, 1024
+    nk, n_tiles = k // KS, n // N_BLOCK
+    num_work = (BITS - 1) * nk
+
+    def sac_step(a, planes, signs, scale, counts, pids, kids, mask):
+        sched = KneadedSchedule(counts=counts, plane_ids=pids, ktile_ids=kids,
+                                num_work=num_work,
+                                total_work=num_work * n_tiles,
+                                nk=nk, n_tiles=n_tiles)
+        return jnp.tanh(sac_ops._run(a, planes, signs, scale, sched, mask,
+                                     bits=BITS, ks=KS, n_block=N_BLOCK, bm=8,
+                                     interpret=False))
+
+    sched_shape = (n_tiles, num_work)
+    sac = jax.jit(sac_step).lower(
+        _sds((m, k), jnp.bfloat16, one_chip),
+        _sds((BITS - 1, k // WORD, n), jnp.uint32, one_chip),
+        _sds((k // WORD, n), jnp.uint32, one_chip),
+        _sds((1, n), jnp.float32, one_chip),
+        _sds((n_tiles,), jnp.int32, one_chip),
+        *[_sds(sched_shape, jnp.int32, one_chip)] * 3).compile().as_text()
+    gemm = jax.jit(lambda a, q, s: jnp.tanh(gemm_ops._run(
+        a, q, s, packed4=False, bm=8, bn=256, bk=512, interpret=False))
+    ).lower(_sds((m, k), jnp.bfloat16, one_chip),
+            _sds((k, n), jnp.int8, one_chip),
+            _sds((1, n), jnp.float32, one_chip)).compile().as_text()
+    for text, name in ((sac, "sac_matmul"), (gemm, "kneaded_gemm")):
+        calls = [ln.split(" = ")[0].strip() for ln in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in ln]
+        assert calls and all(c.startswith(f"%{name}") for c in calls), calls
