@@ -84,6 +84,24 @@ def sp_gather(x: jax.Array, cfg: ModelConfig) -> jax.Array:
     return pspec.constrain(x, *(["batch"] + [None] * (x.ndim - 1)))
 
 
+def _model_degree() -> int:
+    """Devices the logical "model" axis spans under the installed mesh
+    (1 without one)."""
+    mesh = pspec.current_mesh()
+    if mesh is None:
+        return 1
+    axes = [a for a in pspec.current_rules().get("model", ())
+            if a in mesh.axis_names]
+    return int(np.prod([mesh.shape[a] for a in axes])) if axes else 1
+
+
+def cache_seq_sharded() -> bool:
+    """Whether a decode cache's sequence axis may be split over devices:
+    the decode runs under a mesh whose "model" axis spans more than one
+    device (``sharding.cache_spec_sharding`` shards long caches there)."""
+    return _model_degree() > 1
+
+
 def _attn_shard_mode(cfg: ModelConfig):
     """How to shard attention tensors over the "model" axes.
 
@@ -92,12 +110,7 @@ def _attn_shard_mode(cfg: ModelConfig):
     ~8x less traffic than the replicated-head fallback GSPMD chooses on its
     own, which all-gathers q/k/v inside every flash-attention step).
     """
-    mesh = pspec.current_mesh()
-    if mesh is None:
-        return None
-    axes = [a for a in pspec.current_rules().get("model", ())
-            if a in mesh.axis_names]
-    n = int(np.prod([mesh.shape[a] for a in axes])) if axes else 1
+    n = _model_degree()
     if n <= 1:
         return None
     if cfg.num_kv_heads % n == 0:
@@ -148,51 +161,56 @@ def attn_apply(
     pos: Optional[jax.Array] = None,             # decode position [B]
     return_kv: bool = False,
 ):
-    """Pre-norm attention block.  Returns (y, new_cache_or_kv_or_None)."""
+    """Pre-norm attention block.  Returns (y, new_cache_or_kv_or_None).
+
+    With ``cache`` (a decode step), ``return_kv`` leaves the cache read-only
+    and returns the new token's entries as the cache stores them, for the
+    caller to write at ``pos``; without it the layer's cache slice comes
+    back written."""
     dtype = jnp.dtype(cfg.dtype)
     h = sp_gather(layers.apply_norm(p["ln"], x, cfg.norm), cfg)
     use_rope = cfg.positional == "rope"
 
     if cache is not None:                         # ---- decode step
-        quant_kv = len(cache) == 4                # (k8, v8, k_scale, v_scale)
-        if quant_kv:
-            k_cache, v_cache, k_sc, v_sc = cache
-        else:
-            k_cache, v_cache = cache
         q, k_new, v_new = _qkv(p, h, h, cfg, dtype)
         if use_rope:
             posb = pos[:, None]
             q = layers.apply_rope(q, posb, cfg.rope_theta)
             k_new = layers.apply_rope(k_new, posb, cfg.rope_theta)
-        # Cache write as a masked elementwise select, NOT dynamic_update_
-        # slice: the cache seq axis is "model"-sharded at scale, and DUS on
-        # a sharded axis forces an involuntary full rematerialization (SPMD
-        # gathers the whole cache).  The where() lowers to a fully local
-        # masked write on every shard.
-        write = (jnp.arange(k_cache.shape[1])[None, :, None, None]
-                 == pos[:, None, None, None])
-        if quant_kv:
+        if len(cache) == 4:                       # (k8, v8, k_scale, v_scale)
             # knead the cache like the weights: int8 codes + per-(pos, head)
-            # scale; write codes and scales under the same mask
-            (k8, ks_new), (v8, vs_new) = (layers.quantize_kv(k_new),
-                                          layers.quantize_kv(v_new))
-            k_cache = jnp.where(write, k8, k_cache)
-            v_cache = jnp.where(write, v8, v_cache)
-            k_sc = jnp.where(write[..., 0], ks_new, k_sc)
-            v_sc = jnp.where(write[..., 0], vs_new, v_sc)
-            k_read = k_cache.astype(jnp.float32) * k_sc[..., None]
-            v_read = v_cache.astype(jnp.float32) * v_sc[..., None]
+            # scale, read back dequantised
+            (k8, ks), (v8, vs) = (layers.quantize_kv(k_new),
+                                  layers.quantize_kv(v_new))
+            new = (k8, v8, ks, vs)
+            read = lambda c: (c[0].astype(jnp.float32) * c[2][..., None],
+                              c[1].astype(jnp.float32) * c[3][..., None])
         else:
-            k_cache = jnp.where(write, k_new.astype(k_cache.dtype), k_cache)
-            v_cache = jnp.where(write, v_new.astype(v_cache.dtype), v_cache)
-            k_read, v_read = k_cache, v_cache
-        out = layers.decode_attention(q, k_read, v_read, pos,
-                                      window=cfg.window)
+            new = (k_new.astype(cache[0].dtype), v_new.astype(cache[1].dtype))
+            read = lambda c: (c[0], c[1])
+        if return_kv:
+            # The caller writes ``new`` in place after the layer scan
+            # (LanguageModel.decode_step): attend to the old cache plus the
+            # new token, so no layer slice is rewritten.
+            out = layers.decode_attention_append(q, *read(cache), *read(new),
+                                                 pos, window=cfg.window)
+            cache = new
+        else:
+            # A cache whose seq axis a mesh may shard (cache_seq_sharded):
+            # write by masked select, NOT dynamic_update_slice, which on a
+            # sharded axis makes SPMD gather the whole cache.  The where()
+            # is a local masked write on every shard, at the price of
+            # rewriting the whole layer slice.
+            write = jnp.arange(cache[0].shape[1])[None, :] == pos[:, None]
+            cache = tuple(
+                jnp.where(write.reshape(write.shape + (1,) * (c.ndim - 2)),
+                          n, c)
+                for c, n in zip(cache, new))
+            out = layers.decode_attention(q, *read(cache), pos,
+                                          window=cfg.window)
         y = matmul_any(out.reshape(out.shape[0], 1, -1), p["wo"], dtype,
                        impl=cfg.impl, skip_activations=cfg.activation_skip)
-        if quant_kv:
-            return x + y, (k_cache, v_cache, k_sc, v_sc)
-        return x + y, (k_cache, v_cache)
+        return x + y, cache
 
     if kv_const is not None:                      # ---- cross-attn w/ cached KV
         k, v = kv_const

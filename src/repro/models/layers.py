@@ -322,6 +322,33 @@ def decode_attention(q, k_cache, v_cache, pos: jax.Array,
     return out.astype(q.dtype)
 
 
+def decode_attention_append(q, k_cache, v_cache, k_new, v_new,
+                            pos: jax.Array, window: int = 0) -> jax.Array:
+    """:func:`decode_attention` on a cache that does not hold the new token.
+
+    q [B,1,KV,G,hd] attends to the cache's positions ``< pos`` and to the
+    new token's own ``k_new``/``v_new`` [B,1,KV,hd] as one more term of the
+    same softmax: the keys of ``decode_attention`` on a cache written at
+    ``pos``, with the cache left read-only.  Pass the new K/V as the cache
+    will store them (rounded to its dtype, or dequantised).
+    """
+    smax = k_cache.shape[1]
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    s = _scores(q, k_cache, scale)                            # [B,KV,G,1,Smax]
+    kpos = jnp.arange(smax)[None, :]
+    valid = kpos < pos[:, None]
+    if window:
+        valid &= kpos > pos[:, None] - window
+    s = jnp.where(valid[:, None, None, None, :], s, NEG_INF)
+    s = jnp.concatenate([s, _scores(q, k_new, scale)], axis=-1)
+    p = jax.nn.softmax(s, axis=-1)
+    out = (jnp.einsum("bkgqs,bskh->bqkgh", p[..., :smax],
+                      v_cache.astype(jnp.float32))
+           + jnp.einsum("bkgqs,bskh->bqkgh", p[..., smax:],
+                        v_new.astype(jnp.float32)))
+    return out.astype(q.dtype)
+
+
 # ---------------------------------------------------------------------------
 # Flash attention (pair-list, custom_vjp): exact causal FLOPs, O(S) memory.
 # The forward is the `exact` path above; the custom backward recomputes

@@ -1,5 +1,7 @@
 """Per-arch smoke tests (reduced configs, one fwd/train step on CPU) +
 attention/SSM equivalence properties + decode==full-forward consistency."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -78,6 +80,67 @@ def test_arch_decode_matches_full_forward(arch):
                           - full[:, -1].astype(jnp.float32)))
     scale = jnp.max(jnp.abs(full[:, -1].astype(jnp.float32))) + 1e-6
     assert float(err / scale) < 0.05    # bf16 accumulation tolerance
+
+
+@pytest.mark.parametrize("kv_bits", [0, 8], ids=["bf16", "int8"])
+def test_decode_writes_only_each_rows_new_entry(kv_bits):
+    """One decode step over rows at mixed positions (0, max_len - 1, and
+    free rows the scheduler pads with token 0 at position 0) on a cache
+    holding junk from each row's position on: live rows' logits equal the
+    full forward at their position, each row's entry at pos[row] becomes
+    its new K/V, and every other cache entry is bit-unchanged."""
+    cfg = dataclasses.replace(get_config("smollm-360m", smoke=True),
+                              kv_cache_bits=kv_bits)
+    model = LanguageModel(cfg)
+    params = model.init(jax.random.PRNGKey(2))
+    b, max_len = 8, 16
+    toks = jax.random.randint(jax.random.PRNGKey(3), (b, max_len), 0,
+                              cfg.vocab_size)
+    full = model.logits(params, {"tokens": toks})
+    _, filled = model.prefill(params, {"tokens": toks})
+    pos = jnp.array([0, 1, 5, max_len - 1, 9, 0, 0, 3], jnp.int32)
+    live = np.array([1, 1, 1, 1, 1, 0, 0, 1], bool)
+    tok = jnp.where(live[:, None],
+                    jnp.take_along_axis(toks, pos[:, None], axis=1), 0)
+
+    kpos = np.arange(max_len)
+    at = kpos[None, :] == np.asarray(pos)[:, None]                # [B, S]
+    cache = {}
+    for i, (name, c) in enumerate(filled.items()):
+        later = (kpos[None, :] >= np.asarray(pos)[:, None]).reshape(
+            (1, b, max_len) + (1,) * (c.ndim - 3))
+        junk = jax.random.normal(jax.random.PRNGKey(10 + i), c.shape)
+        if c.dtype == jnp.int8:
+            junk = jnp.clip(junk * 50, -127, 127)
+        cache[name] = jnp.where(later, junk.astype(c.dtype), c)
+    before = {name: np.asarray(c) for name, c in cache.items()}
+
+    logits, out = jax.jit(model.decode_step, donate_argnums=(3,))(
+        params, tok, pos, cache)
+    assert set(out) == set(before)
+    for name, c in out.items():
+        c = np.asarray(c)
+        mask = at.reshape((1, b, max_len) + (1,) * (c.ndim - 3))
+        np.testing.assert_array_equal(np.where(mask, 0, c),
+                                      np.where(mask, 0, before[name]))
+
+    rows = np.flatnonzero(live)
+    ref = full[rows, pos[rows]].astype(jnp.float32)
+    err = jnp.max(jnp.abs(logits[rows].astype(jnp.float32) - ref))
+    assert float(err / jnp.max(jnp.abs(ref))) < (0.05 if kv_bits == 0
+                                                 else 0.1)
+    entry = lambda c: np.asarray(c)[:, rows, np.asarray(pos)[rows]]
+    if kv_bits == 0:
+        for name in ("k", "v"):
+            np.testing.assert_array_equal(entry(out[name]),
+                                          entry(filled[name]))
+    else:
+        for name in ("k", "v"):
+            deq = lambda c: (entry(c[name]).astype(np.float32)
+                             * entry(c[name + "_scale"])[..., None])
+            want = deq(filled)
+            np.testing.assert_allclose(deq(out), want,
+                                       atol=0.02 * np.abs(want).max())
 
 
 # ----------------------------------------------------------- attention eqv

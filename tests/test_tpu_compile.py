@@ -11,16 +11,20 @@ The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
 and test workers that each import this file must collect the same tests.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.registry import get_config
 from repro.core.schedule import KneadedSchedule
 from repro.kernels.kneaded_gemm.kernel import kneaded_gemm_pallas_call
 from repro.kernels.sac_matmul.kernel import WORD, sac_matmul_pallas_call
+from repro.models.lm import LanguageModel
 
 BITS = 8
 KS = 256          # ServingConfig.knead_ks / CNNServingConfig.ks
@@ -146,3 +150,53 @@ def test_kernels_keep_their_names_inside_jitted_wrappers(one_chip):
         calls = [ln.split(" = ")[0].strip() for ln in text.splitlines()
                  if 'custom_call_target="tpu_custom_call"' in ln]
         assert calls and all(c.startswith(f"%{name}") for c in calls), calls
+
+
+def _top_level_ops(hlo_text):
+    """(opcode, output type) of every instruction outside the fused
+    computations of a compiled HLO module: the ops the device runs one by
+    one, each reading and writing its operands and output in HBM."""
+    fused = set(re.findall(r"\bfusion\(.*?calls=(%[\w.\-]+)", hlo_text))
+    ops, comp = [], None
+    for line in hlo_text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            comp = line.split()[1] if line.startswith("ENTRY") else \
+                line.split()[0]
+            continue
+        m = re.match(r"\s+(?:ROOT )?%[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(",
+                     line)
+        if m and comp not in fused:
+            kind = re.search(r"kind=(k\w+)", line)
+            ops.append((m.group(2) + (f":{kind.group(1)}" if kind else ""),
+                        m.group(1)))
+    return ops
+
+
+def test_lm_decode_step_writes_the_kv_cache_in_place(one_chip):
+    """smollm-360m's dense decode step at its serving widths (2 layers,
+    64 rows, a 1536-position bf16 cache, donated) writes only the new
+    token's K/V: no whole-layer or whole-cache temporary, no op that
+    rewrites the cache, and no Mosaic kernel of its own.  Weights are
+    bf16: f32 ones add a 94 MB bf16 copy of the embedding table, which
+    has nothing to do with the cache, to the temporaries."""
+    cfg = dataclasses.replace(get_config("smollm-360m"), num_layers=2)
+    model = LanguageModel(cfg)
+    b, max_len = 64, 1536
+    params = jax.tree.map(
+        lambda x: _sds(x.shape, jnp.bfloat16, one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    sds = lambda x: _sds(x.shape, x.dtype, one_chip)
+    cache = jax.tree.map(sds, model.cache_spec(b, max_len))
+    compiled = jax.jit(model.decode_step, donate_argnums=(3,)).lower(
+        params, _sds((b, 1), jnp.int32, one_chip),
+        _sds((b,), jnp.int32, one_chip), cache).compile()
+    layer_k_bytes = b * max_len * cfg.num_kv_heads * cfg.hd * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_k_bytes
+    text = compiled.as_text()
+    shapes = (f"[{b},{max_len},{cfg.num_kv_heads},{cfg.hd}]",
+              f"[{cfg.num_layers},{b},{max_len},{cfg.num_kv_heads},{cfg.hd}]")
+    rewrites = [(op, out) for op, out in _top_level_ops(text)
+                if op in ("select", "copy", "scatter", "fusion:kLoop")
+                and any(s in out for s in shapes)]
+    assert not rewrites, rewrites
+    assert "tpu_custom_call" not in text
