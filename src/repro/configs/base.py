@@ -28,6 +28,7 @@ class ModelConfig:
     head_dim: int = 0                 # 0 -> d_model // num_heads
     activation: str = "swiglu"        # swiglu | gelu | relu2
     norm: str = "rmsnorm"             # rmsnorm | layernorm
+    norm_eps: float = 1e-6
     positional: str = "rope"          # rope | learned | none
     rope_theta: float = 10_000.0
     tie_embeddings: bool = False
@@ -44,8 +45,22 @@ class ModelConfig:
     ssm_head_dim: int = 64
     ssm_expand: int = 2
     conv_width: int = 4
+    conv_bias: bool = False           # mamba2: bias on the depthwise conv
+    ssm_chunk: int = 128              # mamba2 prefill: SSD chunk length
     attn_every: int = 0               # zamba2: shared attn block every N blocks
+    # granite-4.0-h / jamba: one attention layer with its own weights per
+    # ``attn_period`` layers, at index ``attn_offset`` of the period; every
+    # other layer is mamba2, and an MLP follows every mixer
+    attn_period: int = 0
+    attn_offset: int = 0
     slstm_every: int = 0              # xlstm: one sLSTM per this many blocks
+    # muP multipliers (granite): embeddings x embed_scale, every residual
+    # branch x residual_scale, attention softmax scale attn_scale (0 ->
+    # 1/sqrt(head_dim)), logits x logits_scale; the defaults change nothing
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    attn_scale: float = 0.0
+    logits_scale: float = 1.0
     # enc-dec
     encoder_layers: int = 0
     encoder_seq: int = 0              # whisper: 1500 precomputed frames (stub)
@@ -100,6 +115,13 @@ class ModelConfig:
     microbatch: int = 0               # 0 -> no gradient accumulation
 
     def __post_init__(self) -> None:
+        if self.attn_period and (
+                self.num_layers % self.attn_period
+                or not 0 <= self.attn_offset < self.attn_period):
+            raise ValueError(
+                f"{self.name!r}: num_layers={self.num_layers} must be whole "
+                f"periods of attn_period={self.attn_period}, with "
+                f"attn_offset={self.attn_offset} inside the period")
         if self.sac_impl is not None:
             warnings.warn(
                 "ModelConfig.sac_impl is deprecated; use impl=",
@@ -149,6 +171,12 @@ class ModelConfig:
             di = self.ssm_expand * d
             per_layer = d * 2 * di + di * d + 2 * di * self.ssm_state
         n = self.num_layers * per_layer + v * d * (1 if self.tie_embeddings else 2)
+        if self.family == "hybrid" and self.attn_period:
+            di, ns = self.ssm_expand * d, self.ssm_state
+            mamba = d * (2 * di + 2 * ns + di // self.ssm_head_dim) + di * d
+            n_attn = self.num_layers // self.attn_period
+            n += (n_attn * (attn + mlp) + (self.num_layers - n_attn)
+                  * (mamba + mlp) - self.num_layers * per_layer)
         if self.encoder_layers:
             n += self.encoder_layers * (attn + (mlp if f else 0))
         if self.cross_attn_every:

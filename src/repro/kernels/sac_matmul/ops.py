@@ -49,6 +49,13 @@ from repro.core.schedule import KneadedSchedule
 from repro.kernels.backend import interpret_mode
 from repro.kernels.sac_matmul.kernel import sac_matmul_pallas_call
 
+# Most rows in one M block of a matmul launch.  A grid step's cost is
+# mostly fixed (unpack one plane tile, pipeline the next), whatever its M,
+# so a prefill-sized launch walks its work list once per 1024 rows rather
+# than once per 256 (segment scratch at 1024 rows: 7 x 1024 x 128 f32 =
+# 3.7 MB of VMEM).  ``sac_conv2d`` keeps 256-row blocks.
+MATMUL_BM = 1024
+
 
 @functools.partial(
     jax.jit, static_argnames=("bits", "ks", "n_block", "bm", "interpret"))
@@ -65,7 +72,7 @@ def sac_matmul_pallas(
     a: jax.Array,
     kw: KneadedWeight,
     *,
-    bm: int = 256,
+    bm: int = MATMUL_BM,
     skip_activations: bool = False,
 ) -> jax.Array:
     """[M, K] @ kneaded [K, N] -> [M, N] f32 via the Pallas SAC kernel.
@@ -115,7 +122,7 @@ def sac_matmul_pallas(
     return out[:m]
 
 
-def m_block(m: int, bm: int = 256) -> int:
+def m_block(m: int, bm: int = MATMUL_BM) -> int:
     """Effective M block for an M-row launch — the decode/GEMV fast path:
     M rounded up to the 8-row f32 sublane floor, capped at ``bm``.  Shared
     with the planes oracle (``core.sac``), which replays the kernel at the
@@ -135,8 +142,8 @@ def _pad_activations(a: jax.Array, kw, bm: int):
     The M-block shrinks to fit tiny batches — the decode/GEMV fast path:
     ``bm_eff = min(bm, M rounded up to the 8-row f32 sublane floor)``, so an
     M=1 decode step pads one row to 8 and runs a single M-step instead of
-    padding to the full 256-row streaming block (31/32 of every A-tile DMA
-    and MXU pass would be padding).  Prefill and conv calls (M >= bm) keep
+    padding to a full streaming block (31/32 of every A-tile DMA and MXU
+    pass would be padding).  Prefill and conv calls (M >= bm) keep
     the full streamed grid.  ``bm_eff`` is always a multiple of 8, so
     mid-size M (e.g. 12) pads to an aligned single block rather than
     running a misaligned one.
@@ -160,7 +167,7 @@ def sac_matmul_pallas_sharded(
     mesh=None,
     axis: str = "model",
     *,
-    bm: int = 256,
+    bm: int = MATMUL_BM,
     skip_activations: bool = False,
 ) -> jax.Array:
     """[M, K] @ N-sharded kneaded [K, N] -> [M, N] f32, one kernel per shard.

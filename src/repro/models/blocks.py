@@ -138,8 +138,13 @@ def _qkv(p, x, kv_src, cfg: ModelConfig, dtype):
     v = matmul_any(kv_src, p["wv"], dtype, impl=impl,
                    skip_activations=skip).reshape(b, -1, nkv, hd)
     if cfg.qk_norm:
-        q = layers.rms_head_norm(q, p["qnorm"])
-        k = layers.rms_head_norm(k, p["knorm"])
+        q = layers.rms_head_norm(q, p["qnorm"], cfg.norm_eps)
+        k = layers.rms_head_norm(k, p["knorm"], cfg.norm_eps)
+    if cfg.attn_scale:
+        # every attention kernel scales scores by 1/sqrt(hd); a model's own
+        # softmax scale is folded into q (exact when the ratio is a power
+        # of two, as granite's 1/64 over 1/8 is)
+        q = q * (cfg.attn_scale * float(np.sqrt(hd)))
     mode = _attn_shard_mode(cfg)
     if mode == "kv":
         q = pspec.constrain(q, "batch", None, "model", None, None)
@@ -168,7 +173,7 @@ def attn_apply(
     caller to write at ``pos``; without it the layer's cache slice comes
     back written."""
     dtype = jnp.dtype(cfg.dtype)
-    h = sp_gather(layers.apply_norm(p["ln"], x, cfg.norm), cfg)
+    h = sp_gather(layers.apply_norm(p["ln"], x, cfg.norm, cfg.norm_eps), cfg)
     use_rope = cfg.positional == "rope"
 
     if cache is not None:                         # ---- decode step
@@ -210,7 +215,7 @@ def attn_apply(
                                           window=cfg.window)
         y = matmul_any(out.reshape(out.shape[0], 1, -1), p["wo"], dtype,
                        impl=cfg.impl, skip_activations=cfg.activation_skip)
-        return x + y, cache
+        return layers.add_residual(x, y, cfg.residual_scale), cache
 
     if kv_const is not None:                      # ---- cross-attn w/ cached KV
         k, v = kv_const
@@ -224,7 +229,7 @@ def attn_apply(
     else:
         src = kv_src if kv_src is not None else h
         if kv_src is not None:
-            src = layers.apply_norm(p["ln_kv"], src, cfg.norm) \
+            src = layers.apply_norm(p["ln_kv"], src, cfg.norm, cfg.norm_eps) \
                 if "ln_kv" in p else src
         q, k, v = _qkv(p, h, src, cfg, dtype)
         if use_rope and kv_src is None:
@@ -239,7 +244,7 @@ def attn_apply(
     b, s = out.shape[:2]
     y = matmul_any(out.reshape(b, s, -1), p["wo"], dtype, impl=cfg.impl,
                    skip_activations=cfg.activation_skip)
-    y = res_constrain(x + y, cfg)
+    y = res_constrain(layers.add_residual(x, y, cfg.residual_scale), cfg)
     if return_kv:
         return y, (k, v)
     return y, None
@@ -286,10 +291,10 @@ def _ffn(h, p, activation: str, dtype, impl: str = "int",
 
 def mlp_apply(p, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     dtype = jnp.dtype(cfg.dtype)
-    h = sp_gather(layers.apply_norm(p["ln"], x, cfg.norm), cfg)
+    h = sp_gather(layers.apply_norm(p["ln"], x, cfg.norm, cfg.norm_eps), cfg)
     y = _ffn(h, p, cfg.activation, dtype, impl=cfg.impl,
              skip=cfg.activation_skip)
-    return res_constrain(x + y, cfg)
+    return res_constrain(layers.add_residual(x, y, cfg.residual_scale), cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -509,7 +514,7 @@ def moe_apply(p, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
     # NB: no sp_gather here — the MoE shard_map's in_specs reshard the
     # tokens themselves; an explicit full-seq gather first was measured
     # 2.4x worse on arctic (EXPERIMENTS.md §Perf B5).
-    h = layers.apply_norm(p["ln"], x, cfg.norm)
+    h = layers.apply_norm(p["ln"], x, cfg.norm, cfg.norm_eps)
     logits = matmul_any(h, p["router"], jnp.float32)     # [B, S, E] replicated
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     gates, eids = jax.lax.top_k(probs, cfg.top_k)
@@ -532,7 +537,8 @@ def moe_apply(p, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
                           n_tokens=b * s, dtype=dtype)
         y = y2.reshape(b, s, d)
         if cfg.dense_residual:
-            dense_h = layers.apply_norm(p["dense"]["ln"], x, cfg.norm)
+            dense_h = layers.apply_norm(p["dense"]["ln"], x, cfg.norm,
+                                        cfg.norm_eps)
             y = y + _ffn(dense_h, p["dense"], cfg.activation, dtype,
                          impl=cfg.impl, skip=cfg.activation_skip)
         return res_constrain(x + y.astype(x.dtype), cfg), aux
@@ -579,7 +585,8 @@ def moe_apply(p, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
         )(h2, e2, g2, wi_q, wi_s_arg, wo_q, wo_s_arg)
     y = y2.reshape(b, s, d)
     if cfg.dense_residual:
-        dense_h = layers.apply_norm(p["dense"]["ln"], x, cfg.norm)
+        dense_h = layers.apply_norm(p["dense"]["ln"], x, cfg.norm,
+                                    cfg.norm_eps)
         y = y + _ffn(dense_h, p["dense"], cfg.activation, dtype,
                      impl=cfg.impl, skip=cfg.activation_skip)
     out = res_constrain(x + y.astype(x.dtype), cfg)

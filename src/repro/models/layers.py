@@ -113,6 +113,12 @@ def apply_norm(p, x: jax.Array, kind: str, eps: float = 1e-6) -> jax.Array:
     return (xf * p["scale"] + p["bias"]).astype(x.dtype)
 
 
+def add_residual(x: jax.Array, y: jax.Array, scale: float = 1.0) -> jax.Array:
+    """``x + scale * y``: a residual branch under a muP residual multiplier
+    (granite's 0.22); at 1.0 the branch adds as it is."""
+    return x + (y if scale == 1.0 else y * scale)
+
+
 def rms_head_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     """Per-head RMSNorm over the head dim (qwen3 qk-norm)."""
     xf = x.astype(jnp.float32)

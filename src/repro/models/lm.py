@@ -6,7 +6,10 @@
   vlm     — scan over groups of (gated cross-attn + k self layers)
                                                            (llama-3.2-vision)
   hybrid  — scan over mamba2 blocks, shared attn block every N
-                                                           (zamba2)
+                                                           (zamba2);
+            with ``attn_period``: scan over periods of (mamba2 + mlp)
+            layers around one (attn + mlp) layer with its own weights
+                                                           (granite-4.0-h)
   ssm     — scan over groups of (k mLSTM + 1 sLSTM)       (xlstm)
   encdec  — encoder self-attn stack + decoder w/ cross-attn
                                                            (whisper; conv
@@ -42,6 +45,21 @@ def _stack_init(init_fn, key, n):
 
 def _maybe_remat(fn, cfg: ModelConfig):
     return jax.checkpoint(fn, prevent_cse=False) if cfg.remat else fn
+
+
+def _at(store, *idx):
+    """``store[idx]`` for leading indices ``idx``, as one dynamic slice."""
+    k = len(idx)
+    sl = jax.lax.dynamic_slice(store, idx + (0,) * (store.ndim - k),
+                               (1,) * k + store.shape[k:])
+    return sl.reshape(store.shape[k:])
+
+
+def _layer(stack, *idx):
+    """One layer's params out of a stacked layer pytree (kneaded leaves
+    too), each leaf one dynamic slice: scanning a slice of the stack
+    instead would copy every layer of it."""
+    return jax.tree.map(lambda a: _at(a, *idx), stack)
 
 
 def _write_rows(stores, news, pos):
@@ -102,6 +120,21 @@ class LanguageModel:
                     "selfs": _stack_init(self_init, k3, inner),
                 }
             params["groups"] = _stack_init(group_init, keys[2], g)
+        elif fam == "hybrid" and cfg.attn_period:
+            def layer_init(mixer_init):
+                def init(k):
+                    k1, k2 = jax.random.split(k)
+                    return {"mixer": mixer_init(k1, cfg),
+                            "mlp": blocks.mlp_init(k2, cfg)}
+                return init
+
+            def group_init(k):
+                k1, k2 = jax.random.split(k)
+                return {"mamba": _stack_init(layer_init(ssm.mamba2_init), k1,
+                                             cfg.attn_period - 1),
+                        "attn": layer_init(blocks.attn_init)(k2)}
+            params["groups"] = _stack_init(
+                group_init, keys[2], cfg.num_layers // cfg.attn_period)
         elif fam == "hybrid":
             params["layers"] = _stack_init(
                 lambda k: ssm.mamba2_init(k, cfg), keys[2], cfg.num_layers)
@@ -145,6 +178,8 @@ class LanguageModel:
     def _embed(self, params, tokens):
         cfg = self.cfg
         h = jnp.take(params["embed"], tokens, axis=0)
+        if cfg.embed_scale != 1.0:
+            h = h * cfg.embed_scale
         h = h.astype(jnp.dtype(cfg.dtype))
         from repro.models.blocks import res_constrain
         return res_constrain(h, cfg)
@@ -163,7 +198,8 @@ class LanguageModel:
 
         body = _maybe_remat(body, cfg)
         h, _ = jax.lax.scan(body, h, params["encoder"]["layers"])
-        return layers.apply_norm(params["encoder"]["final_norm"], h, cfg.norm)
+        return layers.apply_norm(params["encoder"]["final_norm"], h, cfg.norm,
+                                 cfg.norm_eps)
 
     def _trunk(self, params, h, positions, *, collect_cache: bool,
                cross_src: Optional[jax.Array] = None):
@@ -218,6 +254,44 @@ class LanguageModel:
                 cache = {"cross_k": ckvs[0], "cross_v": ckvs[1],
                          "k": kvss[0], "v": kvss[1]}
             return h, aux, cache
+
+        if fam == "hybrid" and cfg.attn_period:
+            off, m = cfg.attn_offset, cfg.attn_period - 1
+            stack = params["groups"]["mamba"]
+
+            def mambas(h, g, lo, hi):
+                def body(h, i):
+                    p_l = _layer(stack, g, i)
+                    h, st = ssm.mamba2_apply(p_l["mixer"], h, cfg)
+                    h = blocks.mlp_apply(p_l["mlp"], h, cfg)
+                    return h, (st if collect_cache else None)
+                if lo == hi:
+                    return h, None
+                return jax.lax.scan(body, h, jnp.arange(lo, hi))
+
+            def group_body(h, xs):
+                p_a, g = xs
+                h, pre = mambas(h, g, 0, off)
+                h, kv = blocks.attn_apply(p_a["mixer"], h, cfg,
+                                          positions=positions,
+                                          return_kv=collect_cache)
+                h = blocks.mlp_apply(p_a["mlp"], h, cfg)
+                h, post = mambas(h, g, off, m)
+                if not collect_cache:
+                    return h, None
+                states = jax.tree.map(
+                    lambda *xs: jnp.concatenate(xs, axis=0),
+                    *[st for st in (pre, post) if st is not None])
+                return h, (kv, states)
+            group_body = _maybe_remat(group_body, cfg)
+            h, ys = jax.lax.scan(
+                group_body, h, (params["groups"]["attn"],
+                                jnp.arange(cfg.num_layers // cfg.attn_period)))
+            cache = None
+            if collect_cache:
+                (k, v), (conv, state) = ys
+                cache = {"k": k, "v": v, "conv": conv, "ssm": state}
+            return h, aux0, cache
 
         if fam == "hybrid":
             n_apps = int(np.ceil(cfg.num_layers / cfg.attn_every))
@@ -331,19 +405,23 @@ class LanguageModel:
         h, aux, cache = self._trunk(params, h, positions,
                                     collect_cache=collect_cache,
                                     cross_src=cross_src)
-        h = layers.apply_norm(params["final_norm"], h, cfg.norm)
+        h = layers.apply_norm(params["final_norm"], h, cfg.norm, cfg.norm_eps)
         return h, aux, cache
 
     def _unembed_w(self, params):
         return (params["embed"].T if self.cfg.tie_embeddings
                 else params["unembed"])
 
+    def _head(self, params, h) -> jax.Array:
+        cfg = self.cfg
+        logits = layers.matmul_any(h, self._unembed_w(params),
+                                   jnp.dtype(cfg.dtype), impl=cfg.impl,
+                                   skip_activations=cfg.activation_skip)
+        return logits if cfg.logits_scale == 1.0 else logits * cfg.logits_scale
+
     def logits(self, params, batch) -> jax.Array:
         h, _, _ = self._hidden(params, batch)
-        return layers.matmul_any(h, self._unembed_w(params),
-                                 jnp.dtype(self.cfg.dtype),
-                                 impl=self.cfg.impl,
-                                 skip_activations=self.cfg.activation_skip)
+        return self._head(params, h)
 
     def loss(self, params, batch, loss_chunk: int = 0) -> jax.Array:
         """Cross entropy + MoE aux.  The vocab matmul runs in bf16 with f32
@@ -361,7 +439,7 @@ class LanguageModel:
 
         def ce(hc, lc):
             logits = layers.matmul_any(hc, w, jnp.dtype(cfg.dtype))
-            logits = logits.astype(jnp.float32)
+            logits = logits.astype(jnp.float32) * cfg.logits_scale
             logz = jax.nn.logsumexp(logits, axis=-1)
             gold = jnp.take_along_axis(logits, lc[..., None],
                                        axis=-1)[..., 0]
@@ -386,11 +464,7 @@ class LanguageModel:
             k8, ks = layers.quantize_kv(cache["k"])
             v8, vs = layers.quantize_kv(cache["v"])
             cache = {"k": k8, "v": v8, "k_scale": ks, "v_scale": vs}
-        last = h[:, -1]
-        logits = layers.matmul_any(last, self._unembed_w(params),
-                                   jnp.dtype(self.cfg.dtype),
-                                   impl=self.cfg.impl,
-                                   skip_activations=self.cfg.activation_skip)
+        logits = self._head(params, h[:, -1])
         # pad KV caches to max length happens in inference.engine; here the
         # cache covers the prefilled prefix exactly.
         return logits, cache
@@ -425,6 +499,13 @@ class LanguageModel:
                 (L, batch, cfg.encoder_seq, kvh, hd), dt)
             return {"k": kv(L, max_len), "v": kv(L, max_len),
                     "cross_k": enc, "cross_v": enc}
+        if fam == "hybrid" and cfg.attn_period:
+            conv, state = ssm.mamba2_cache_spec(cfg, batch)
+            g, m = L // cfg.attn_period, cfg.attn_period - 1
+            stack = lambda sds: jax.ShapeDtypeStruct((g, m) + sds.shape,
+                                                     sds.dtype)
+            return {"k": kv(g, max_len), "v": kv(g, max_len),
+                    "conv": stack(conv), "ssm": stack(state)}
         if fam == "hybrid":
             n_apps = int(np.ceil(L / cfg.attn_every))
             conv, state = ssm.mamba2_cache_spec(cfg, batch)
@@ -570,6 +651,46 @@ class LanguageModel:
                 (params["layers"], cache["cross_k"], cache["cross_v"],
                  jnp.arange(cfg.num_layers)))
             cache = dict(cache, k=k_new, v=v_new)
+        elif fam == "hybrid" and cfg.attn_period:
+            if blocks.cache_seq_sharded():
+                raise NotImplementedError(
+                    "the attn_period hybrid decodes on one device's cache")
+            off, m = cfg.attn_offset, cfg.attn_period - 1
+            stack = params["groups"]["mamba"]
+
+            # The recurrent state stays in the carry and each layer writes
+            # its slice back in place (the cache is donated); attention
+            # reads its KV as xs and its new entries are written per row
+            # after the scan, as in the dense body.
+            def mambas(carry, g, lo, hi):
+                def body(carry, i):
+                    h, conv_all, ssm_all = carry
+                    p_l = _layer(stack, g, i)
+                    h, (conv_c, ssm_c) = ssm.mamba2_apply(
+                        p_l["mixer"], h, cfg,
+                        cache=(_at(conv_all, g, i), _at(ssm_all, g, i)))
+                    h = blocks.mlp_apply(p_l["mlp"], h, cfg)
+                    return (h, _upd(conv_all, conv_c, g, i),
+                            _upd(ssm_all, ssm_c, g, i)), None
+                if lo == hi:
+                    return carry
+                return jax.lax.scan(body, carry, jnp.arange(lo, hi))[0]
+
+            def group_body(carry, xs):
+                p_a, k_g, v_g, g = xs
+                h, conv_all, ssm_all = mambas(carry, g, 0, off)
+                h, new = blocks.attn_apply(
+                    p_a["mixer"], h, cfg, positions=None,
+                    cache=(k_g, v_g), pos=pos, return_kv=True)
+                h = blocks.mlp_apply(p_a["mlp"], h, cfg)
+                return mambas((h, conv_all, ssm_all), g, off, m), new
+            (h, conv_new, ssm_new), new = jax.lax.scan(
+                group_body, (h, cache["conv"], cache["ssm"]),
+                (params["groups"]["attn"], cache["k"], cache["v"],
+                 jnp.arange(cfg.num_layers // cfg.attn_period)))
+            k_new, v_new = _write_rows((cache["k"], cache["v"]), new, pos)
+            cache = {"k": k_new, "v": v_new, "conv": conv_new,
+                     "ssm": ssm_new}
         elif fam == "hybrid":
             def body(carry, xs):
                 h, ks_, vs_, conv_all, ssm_all = carry
@@ -634,9 +755,5 @@ class LanguageModel:
         else:
             raise ValueError(fam)
 
-        h = layers.apply_norm(params["final_norm"], h, cfg.norm)
-        logits = layers.matmul_any(h[:, 0], self._unembed_w(params),
-                                   jnp.dtype(cfg.dtype),
-                                   impl=cfg.impl,
-                                   skip_activations=cfg.activation_skip)
-        return logits, cache
+        h = layers.apply_norm(params["final_norm"], h, cfg.norm, cfg.norm_eps)
+        return self._head(params, h[:, 0]), cache

@@ -36,13 +36,19 @@ def ssd_chunked(
     chunk: int,
     h0: Optional[jax.Array] = None,   # [B, H, p, n]
 ) -> Tuple[jax.Array, jax.Array]:
-    """Returns (y [B, L, H, p], h_final [B, H, p, n]).  Exact linear scan."""
+    """Returns (y [B, L, H, p], h_final [B, H, p, n]).  Exact linear scan.
+
+    Any length: the last chunk is padded with u = 0 and log_a = 0, steps
+    that leave the state as it is, and their outputs are dropped."""
     bsz, l, h, p = u.shape
     n = b.shape[-1]
-    assert l % chunk == 0, (l, chunk)
-    m = l // chunk
+    m = -(-l // chunk)
     f32 = jnp.float32
     u_, b_, c_, la_ = (x.astype(f32) for x in (u, b, c, log_a))
+    if m * chunk != l:
+        pad = lambda x: jnp.pad(
+            x, [(0, 0), (0, m * chunk - l)] + [(0, 0)] * (x.ndim - 2))
+        u_, b_, c_, la_ = (pad(x) for x in (u_, b_, c_, la_))
     u_ = u_.reshape(bsz, m, chunk, h, p)
     b_ = b_.reshape(bsz, m, chunk, h, n)
     c_ = c_.reshape(bsz, m, chunk, h, n)
@@ -72,7 +78,7 @@ def ssd_chunked(
 
     xs = tuple(jnp.moveaxis(x, 1, 0) for x in (u_, b_, c_, la_))
     h_final, ys = jax.lax.scan(chunk_step, h0, xs)
-    y = jnp.moveaxis(ys, 0, 1).reshape(bsz, l, h, p)
+    y = jnp.moveaxis(ys, 0, 1).reshape(bsz, m * chunk, h, p)[:, :l]
     return y.astype(u.dtype), h_final
 
 
@@ -96,10 +102,13 @@ def ssd_step(
         raise ValueError(
             f"ssd_step state heads {h.shape[1]} != input heads {u.shape[1]}"
             " — the decode cache does not match this layer's head count")
-    a = jnp.exp(log_a.astype(jnp.float32))[..., None, None]
-    h_new = h * a + jnp.einsum("bhp,bhn->bhpn", u.astype(jnp.float32),
-                               b.astype(jnp.float32))
-    y = jnp.einsum("bhn,bhpn->bhp", c.astype(jnp.float32), h_new)
+    f32 = jnp.float32
+    a = jnp.exp(log_a.astype(f32))[..., None, None]
+    # the outer product u b as a broadcast multiply, not an einsum: XLA
+    # then fuses it with the decay into the in-place write of h_new into a
+    # donated cache, where a dot would make a state-sized temporary
+    h_new = h * a + u.astype(f32)[..., None] * b.astype(f32)[:, :, None, :]
+    y = jnp.einsum("bhn,bhpn->bhp", c.astype(f32), h_new)
     return y.astype(u.dtype), h_new
 
 
@@ -108,14 +117,18 @@ def ssd_step(
 # ---------------------------------------------------------------------------
 
 def causal_conv(x: jax.Array, w: jax.Array,
-                state: Optional[jax.Array] = None):
-    """x [B, L, C], w [W, C] depthwise.  Returns (y, new_state [B, W-1, C])."""
+                state: Optional[jax.Array] = None,
+                bias: Optional[jax.Array] = None):
+    """x [B, L, C], w [W, C] depthwise, optional bias [C].  Returns
+    (silu(conv + bias), new_state [B, W-1, C])."""
     width = w.shape[0]
     if state is None:
         state = jnp.zeros((x.shape[0], width - 1, x.shape[-1]), x.dtype)
     xp = jnp.concatenate([state, x], axis=1)
     segs = [xp[:, i:i + x.shape[1], :] * w[i] for i in range(width)]
     y = sum(segs)
+    if bias is not None:
+        y = y + bias
     return jax.nn.silu(y), xp[:, -(width - 1):, :]
 
 
@@ -130,7 +143,7 @@ def mamba2_init(key, cfg: ModelConfig) -> dict:
     nh = di // cfg.ssm_head_dim
     ks = jax.random.split(key, 4)
     conv_ch = di + 2 * n
-    return {
+    p = {
         "ln": layers.norm_init(d, cfg.norm),
         "in_proj": layers.dense_init(ks[0], d, 2 * di + 2 * n + nh),
         "conv_w": jax.random.normal(ks[1], (cfg.conv_width, conv_ch),
@@ -142,6 +155,9 @@ def mamba2_init(key, cfg: ModelConfig) -> dict:
         "out_proj": layers.dense_init(
             ks[2], di, d, scale=0.02 / np.sqrt(2 * cfg.num_layers)),
     }
+    if cfg.conv_bias:
+        p["conv_b"] = jnp.zeros((conv_ch,), jnp.float32)
+    return p
 
 
 def _mamba2_project(p, h, cfg: ModelConfig, dtype):
@@ -157,40 +173,53 @@ def _mamba2_project(p, h, cfg: ModelConfig, dtype):
     return z, xc, b, c, dt, (di, n, nh)
 
 
-def mamba2_apply(p, x: jax.Array, cfg: ModelConfig, *,
-                 cache=None, chunk: int = 128):
-    """cache = (conv_state [B,W-1,ch], ssm_state [B,H,p,n]) for decode."""
+def mamba2_apply(p, x: jax.Array, cfg: ModelConfig, *, cache=None):
+    """Pre-norm Mamba-2 mixer with its residual: x + r * out_proj(
+    RMSNorm(y * silu(z))), r = ``cfg.residual_scale``, where
+
+      z, xBC, dt = in_proj(RMSNorm(x)); xBC = silu(conv1d(xBC) + b);
+      dt = softplus(dt + dt_bias); A = -exp(A_log);
+      h_t = exp(dt A) h_{t-1} + dt x_t (x) B_t;  y_t = C_t . h_t + D x_t
+
+    (one B/C group shared by the heads; the gate multiplies before the
+    norm, Mamba-2's ``norm_before_gate=False``).  ``cache`` = (conv_state
+    [B, W-1, ch], ssm_state [B, H, p, n]) for one decode token; without
+    it the whole sequence runs through ``ssd_chunked`` in chunks of
+    ``cfg.ssm_chunk``.  The work between the two
+    projections runs under the name scope ``mamba_mixer``, which the HLO
+    metadata keeps and a TPU trace drops (the benchmark's
+    ``mamba_share.decode`` matches these ops by shape)."""
     dtype = jnp.dtype(cfg.dtype)
     bsz = x.shape[0]
-    h = layers.apply_norm(p["ln"], x, cfg.norm)
+    h = layers.apply_norm(p["ln"], x, cfg.norm, cfg.norm_eps)
     z, xc, b, c, dt, (di, n, nh) = _mamba2_project(p, h, cfg, dtype)
     hd = di // nh
-    conv_in = jnp.concatenate([xc, b, c], axis=-1)
-    conv_state = cache[0] if cache is not None else None
-    conv_out, conv_state = causal_conv(conv_in, p["conv_w"], conv_state)
-    xs, b, c = jnp.split(conv_out, [di, di + n], axis=-1)
-    a = -jnp.exp(p["a_log"])                              # [H] negative
-    log_a = (dt * a).astype(jnp.float32)                  # [B, L, H]
-    u = (xs.reshape(bsz, -1, nh, hd).astype(jnp.float32)
-         * dt[..., None])                                 # dt-scaled input
-    bh = jnp.broadcast_to(b[:, :, None, :], (bsz, b.shape[1], nh, n))
-    ch = jnp.broadcast_to(c[:, :, None, :], (bsz, c.shape[1], nh, n))
-    if cache is None:
-        y, h_final = ssd_chunked(u, bh, ch, log_a, chunk=min(
-            chunk, u.shape[1]))
+    with jax.named_scope("mamba_mixer"):
+        conv_in = jnp.concatenate([xc, b, c], axis=-1)
+        conv_state = cache[0] if cache is not None else None
+        conv_out, conv_state = causal_conv(conv_in, p["conv_w"], conv_state,
+                                           p.get("conv_b"))
+        xs, b, c = jnp.split(conv_out, [di, di + n], axis=-1)
+        a = -jnp.exp(p["a_log"])                              # [H] negative
+        log_a = (dt * a).astype(jnp.float32)                  # [B, L, H]
+        u = (xs.reshape(bsz, -1, nh, hd).astype(jnp.float32)
+             * dt[..., None])                                 # dt-scaled input
+        bh = jnp.broadcast_to(b[:, :, None, :], (bsz, b.shape[1], nh, n))
+        ch = jnp.broadcast_to(c[:, :, None, :], (bsz, c.shape[1], nh, n))
+        if cache is None:
+            y, h_final = ssd_chunked(u, bh, ch, log_a,
+                                     chunk=min(cfg.ssm_chunk, u.shape[1]))
+        else:
+            y1, h_final = ssd_step(u[:, 0], bh[:, 0], ch[:, 0], log_a[:, 0],
+                                   cache[1])
+            y = y1[:, None]
         new_cache = (conv_state, h_final)
-    else:
-        y1, h_final = ssd_step(u[:, 0], bh[:, 0], ch[:, 0], log_a[:, 0],
-                               cache[1])
-        y = y1[:, None]
-        new_cache = (conv_state, h_final)
-    y = y + xs.reshape(bsz, -1, nh, hd) * p["d_skip"][:, None]
-    y = y.reshape(bsz, -1, di)
-    y = layers.apply_norm(p["out_norm"], y, "rmsnorm") * jax.nn.silu(
-        z.astype(jnp.float32)).astype(y.dtype)
+        y = y + xs.reshape(bsz, -1, nh, hd) * p["d_skip"][:, None]
+        y = y.reshape(bsz, -1, di) * jax.nn.silu(z.astype(jnp.float32))
+        y = layers.apply_norm(p["out_norm"], y, "rmsnorm", cfg.norm_eps)
     out = matmul_any(y, p["out_proj"], dtype, impl=cfg.impl,
                      skip_activations=cfg.activation_skip)
-    return x + out, new_cache
+    return layers.add_residual(x, out, cfg.residual_scale), new_cache
 
 
 def mamba2_cache_spec(cfg: ModelConfig, batch: int):
@@ -236,7 +265,7 @@ def mlstm_apply(p, x: jax.Array, cfg: ModelConfig, *, cache=None,
     di = cfg.ssm_expand * d
     nh = cfg.num_heads
     hd = di // nh
-    h = layers.apply_norm(p["ln"], x, cfg.norm)
+    h = layers.apply_norm(p["ln"], x, cfg.norm, cfg.norm_eps)
     skip = cfg.activation_skip
     u2 = matmul_any(h, p["up"], dtype, impl=cfg.impl, skip_activations=skip)
     xm, z = jnp.split(u2, 2, axis=-1)
@@ -268,7 +297,8 @@ def mlstm_apply(p, x: jax.Array, cfg: ModelConfig, *, cache=None,
     y_num, y_den = y_aug[..., :hd], y_aug[..., hd:]
     y = y_num / jnp.maximum(jnp.abs(y_den), 1.0)
     y = y.reshape(bsz, -1, di).astype(dtype)
-    y = layers.apply_norm(p["out_norm"], y, "rmsnorm") * jax.nn.silu(
+    y = layers.apply_norm(p["out_norm"], y, "rmsnorm",
+                          cfg.norm_eps) * jax.nn.silu(
         z.astype(jnp.float32)).astype(dtype)
     out = matmul_any(y, p["down"], dtype, impl=impl, skip_activations=skip)
     return x + out, h_final
@@ -324,7 +354,7 @@ def slstm_apply(p, x: jax.Array, cfg: ModelConfig, *, cache=None):
     """cache = (c, n, h) each [B, d] f32."""
     dtype = jnp.dtype(cfg.dtype)
     bsz, l, d = x.shape
-    h0 = layers.apply_norm(p["ln"], x, cfg.norm)
+    h0 = layers.apply_norm(p["ln"], x, cfg.norm, cfg.norm_eps)
     xt = matmul_any(h0, p["w_in"], jnp.float32, impl=cfg.impl,
                     skip_activations=cfg.activation_skip)   # [B, L, 4d]
     if cache is None:
@@ -342,7 +372,8 @@ def slstm_apply(p, x: jax.Array, cfg: ModelConfig, *, cache=None):
     else:
         state, ys = jax.lax.scan(step, state, jnp.moveaxis(xt, 1, 0))
         ys = jnp.moveaxis(ys, 0, 1)
-    y = layers.apply_norm(p["out_norm"], ys.astype(dtype), "rmsnorm")
+    y = layers.apply_norm(p["out_norm"], ys.astype(dtype), "rmsnorm",
+                          cfg.norm_eps)
     out = matmul_any(y, p["w_out"], dtype, impl=cfg.impl,
                      skip_activations=cfg.activation_skip)
     return x + out, state

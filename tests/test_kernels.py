@@ -73,6 +73,21 @@ def test_pad_activations_m_policy():
         assert padded.shape[0] % bm_eff == 0 and bm_eff % 8 == 0
 
 
+def test_lm_matmul_streams_m_in_1024_row_blocks():
+    """A prefill-sized LM launch takes M blocks of up to MATMUL_BM = 1024
+    rows (oracle and kernel alike, so they stay operand-for-operand
+    comparable); the conv entry keeps its 256-row blocks."""
+    import inspect
+
+    from repro.kernels.sac_matmul.ops import MATMUL_BM, m_block, sac_conv2d
+
+    assert MATMUL_BM == 1024
+    assert [m_block(m) for m in (1, 64, 520, 1024, 2048)] == \
+        [8, 64, 520, 1024, 1024]
+    assert inspect.signature(sac_conv2d).parameters["bm"].default == 256
+    parity.run_case(520, 520, 512, 128)
+
+
 def test_pad_activations_logical_k():
     """Logical-K activations zero-pad to the stored dim for any M, including
     the M<8 clamp; mismatched K still raises."""

@@ -17,9 +17,11 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.base import ModelConfig
 from repro.configs.registry import get_config
 from repro.core.schedule import KneadedSchedule
 from repro.kernels.kneaded_gemm.kernel import kneaded_gemm_pallas_call
@@ -90,6 +92,18 @@ def test_sac_kernel_compiles_smollm_decode(one_chip, k, n):
 def test_sac_kernel_compiles_smollm_prefill(one_chip):
     """smollm-360m prefill: two streamed 256-row M blocks."""
     compiled = _compile_sac(one_chip, 512, 1024, 2560, bm=256,
+                            a_dtype=jnp.bfloat16)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("k,n", [(2048, 8576), (8192, 2048)],
+                         ids=["in_proj", "down"])
+def test_sac_kernel_compiles_granite_prefill_block(one_chip, k, n):
+    """A 1,024-token Granite prefill in one M block of ``ops.MATMUL_BM``
+    rows: the segment scratch of 1,024 rows fits the kernel's VMEM."""
+    from repro.kernels.sac_matmul.ops import MATMUL_BM
+
+    compiled = _compile_sac(one_chip, 1024, k, n, bm=MATMUL_BM,
                             a_dtype=jnp.bfloat16)
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -200,3 +214,47 @@ def test_lm_decode_step_writes_the_kv_cache_in_place(one_chip):
                 and any(s in out for s in shapes)]
     assert not rewrites, rewrites
     assert "tpu_custom_call" not in text
+
+
+def test_granite_decode_step_updates_state_and_kv_in_place(one_chip):
+    """granite-4.0-h-micro's decode step as the cell serves it (20 layers:
+    two periods of 9 Mamba-2 layers around an attention layer), 64 rows,
+    a 1536-position bf16 KV cache and the f32 recurrent state, donated.
+    No top-level select, copy or scatter writes a whole KV or state
+    array, and the temporaries hold no layer's Mamba state (64 x 2.1 MB =
+    134 MB): the state is updated in place, the KV per row.  Weights are
+    bf16 stand-ins for the kneaded ones, and XLA relays out the stack of
+    bf16 in_proj weights [2, 9, 2048, 8512] for its dot, a temporary the
+    served path (a SAC call on bit planes) never makes: the bound counts
+    the temporaries beyond that copy.  (A single period is no shorter
+    test: a one-trip group scan makes this XLA's TPU compiler fail a
+    RET_CHECK on the KV write.)"""
+    import json
+    from pathlib import Path
+
+    path = (Path(__file__).resolve().parents[1] / "benchmarks" / "chip" /
+            "configs" / "granite-4.0-h-micro.json")
+    fields = {f.name for f in dataclasses.fields(ModelConfig)}
+    cfg = ModelConfig(**{k: v for k, v in json.loads(
+        path.read_text())["model"].items() if k in fields})
+    model = LanguageModel(cfg)
+    b, max_len = 64, 1536
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda x: _sds(x.shape, jnp.bfloat16, one_chip),
+                          shapes)
+    sds = lambda x: _sds(x.shape, x.dtype, one_chip)
+    cache = jax.tree.map(sds, model.cache_spec(b, max_len))
+    compiled = jax.jit(model.decode_step, donate_argnums=(3,)).lower(
+        params, _sds((b, 1), jnp.int32, one_chip),
+        _sds((b,), jnp.int32, one_chip), cache).compile()
+    state = cache["ssm"].shape[2:]                       # [64, 64, 64, 128]
+    layer_state_bytes = 4 * int(np.prod(state))
+    in_proj_bytes = 2 * shapes["groups"]["mamba"]["mixer"]["in_proj"].size
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp - in_proj_bytes < layer_state_bytes
+    whole = [",".join(map(str, s)) + "]" for s in (
+        cache["k"].shape, cache["k"].shape[1:], cache["ssm"].shape, state)]
+    rewrites = [(op, out) for op, out in _top_level_ops(compiled.as_text())
+                if op in ("select", "copy", "scatter")
+                and any(s in out for s in whole)]
+    assert not rewrites, rewrites
