@@ -214,8 +214,9 @@ def _act_skip_metrics(kw, a: jax.Array) -> Dict[str, float]:
     from repro.core import activation_occupancy as actocc
 
     pres = actocc.ktile_presence(a, kw.ks)
-    mask = actocc.work_mask(kw.schedule.counts, kw.schedule.ktile_ids, pres)
-    executed = int(np.asarray(jnp.sum(mask)))
+    mask = actocc.work_mask(kw.schedule.plane_bits, kw.schedule.ktile_ids,
+                            pres)
+    executed = int(np.asarray(actocc.mask_passes(mask)))
     weight_only = int(kw.schedule.total_work)
     dense = int(kw.schedule.dense_work(kw.bits))
     return {

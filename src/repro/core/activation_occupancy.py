@@ -4,28 +4,28 @@ Tetris kneads slack out of the *weight* side at knead time: the compacted
 :class:`~repro.core.schedule.KneadedSchedule` is static — built once from the
 weight occupancy map, walked unchanged every step.  The activation side is
 *dynamically* sparse (Cnvlutin2 / Laconic in PAPERS.md): a ReLU trace or an
-MoE residual can zero whole reduction ranges, and a work item whose
-activation K-slice is all zero contributes exactly ``A_t @ P_bt == 0`` no
-matter which bit plane it names.
+MoE residual can zero whole reduction ranges, and a K-tile whose activation
+slice is all zero contributes exactly ``A_t @ P_bt == 0`` through every bit
+plane it holds.
 
 This module is the runtime half of the intersection.  Per SAC call it
 
 1. computes per-K-tile presence bits from the activation block
    (:func:`ktile_presence` — a reshape + ``any``, one pass over the
    single decode row, unioned over rows for micro-batches),
-2. intersects them with the weight-side schedule to produce a per-work-item
-   survival mask (:func:`work_mask`) the Pallas kernel consumes as a fourth
-   scalar-prefetch operand, and
+2. intersects them with the weight-side schedule to produce a per-slot
+   survival mask of planes (:func:`work_mask`) the Pallas kernel consumes
+   as a scalar-prefetch operand, and
 3. accounts executed vs weight-only tile-dots (:func:`record_skip` /
    :func:`skip_stats`) so ``latency_stats()`` and the bench can report
    ``act_skip_frac`` honestly.
 
 Bit-exactness argument (why masking cannot change the output): the mask only
-*drops* items whose activation slice is identically zero, and dropped items
-would have added exactly ``+0.0`` to their f32 segment accumulator.  Adding
-0.0 is a bitwise no-op on every finite f32 (and on the parity tests'
-``assert_array_equal``, where ``-0.0 == +0.0``), and surviving items keep
-their relative k-major order, so per-segment accumulation sequences are
+*drops* K-tile slots whose activation slice is identically zero, and their
+passes would have added exactly ``+0.0`` to their f32 segment accumulators.
+Adding 0.0 is a bitwise no-op on every finite f32 (and on the parity tests'
+``assert_array_equal``, where ``-0.0 == +0.0``), and surviving slots keep
+their ascending K order, so per-segment accumulation sequences are
 operation-for-operation identical to the unmasked walk.  Hence
 ``pallas(skip) == pallas == planes`` bit-for-bit — the property wall in
 ``tests/test_schedule.py`` pins all three.
@@ -38,7 +38,7 @@ surviving the mask, which the property tests also pin.
 from __future__ import annotations
 
 import threading
-from typing import Dict, Optional
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -49,7 +49,7 @@ from repro.core import bitplanes
 __all__ = [
     "ktile_presence",
     "work_mask",
-    "weight_only_mask",
+    "mask_passes",
     "intersect_packed_presence",
     "record_skip",
     "skip_stats",
@@ -71,7 +71,7 @@ def ktile_presence(a: jax.Array, ks: int) -> jax.Array:
     ``presence[t] = any(a[:, t*ks:(t+1)*ks] != 0)`` — the union over the
     (GEMV-few) rows of ``a``.  A tile is absent only when *every* row's
     slice is zero, which is exactly the condition under which dropping the
-    tile's work items is a bitwise no-op for every row of the output.
+    tile's passes is a bitwise no-op for every row of the output.
 
     ``a`` must already be padded to the stored (tile-aligned) K; padding
     columns are zero and never flip a presence bit.
@@ -83,32 +83,26 @@ def ktile_presence(a: jax.Array, ks: int) -> jax.Array:
     return jnp.any(tiles != 0, axis=(0, 2)).astype(jnp.int32)
 
 
-def weight_only_mask(counts: jax.Array, num_work: int) -> jax.Array:
-    """The static schedule's own survival mask: int32 [n_tiles, num_work],
-    1 for real work items (``w < counts[j]``), 0 for the idle padding tail.
-    This is what the kernel guard tested before activation skip existed —
-    passing it reproduces the weight-only walk bit-for-bit."""
-    w = jax.lax.broadcasted_iota(jnp.int32, (counts.shape[0], num_work), 1)
-    return (w < counts[:, None]).astype(jnp.int32)
+def work_mask(plane_bits: jax.Array, ktile_ids: jax.Array,
+              act_presence: jax.Array) -> jax.Array:
+    """Survival mask over schedule slots: int32 [n_tiles, num_work] of plane
+    masks (any leading stack or shard axes pass through).
 
-
-def work_mask(counts: jax.Array, ktile_ids: jax.Array,
-              act_presence: Optional[jax.Array]) -> jax.Array:
-    """Survival mask over schedule slots: int32 [n_tiles, num_work].
-
-    Slot (j, w) survives iff it is a real item (``w < counts[j]``) AND the
-    activation K-tile it names is present.  With ``act_presence=None`` this
-    degrades to :func:`weight_only_mask` — the masked kernel then executes
-    exactly the pre-skip walk.  Monotone by construction: the intersected
-    mask is pointwise <= the weight-only mask (work ⊆ weight-only work),
-    and surviving slots keep their k-major slot positions, preserving the
-    per-segment f32 accumulation order the bit-exactness proof needs.
+    Slot (j, w) keeps its weight-side plane mask ``plane_bits[j, w]`` (0 on
+    the padding tail) iff the activation K-tile it names is present, else
+    0.  The static weight-only walk's mask is ``plane_bits`` itself.
+    Monotone by construction: every surviving
+    plane is a weight-side plane (work ⊆ weight-only work), and surviving
+    slots keep their positions, preserving the per-segment f32
+    accumulation order the bit-exactness proof needs.
     """
-    base = weight_only_mask(counts, ktile_ids.shape[-1])
-    if act_presence is None:
-        return base
-    alive = (act_presence[ktile_ids] != 0).astype(jnp.int32)
-    return base * alive
+    return jnp.where(act_presence[ktile_ids] != 0, plane_bits, 0)
+
+
+def mask_passes(mask: jax.Array) -> jax.Array:
+    """MXU passes a survival mask runs: the popcount of every slot's plane
+    mask, summed (int32 scalar)."""
+    return jnp.sum(bitplanes.popcount(mask))
 
 
 def intersect_packed_presence(occupancy: jax.Array,
@@ -154,18 +148,17 @@ def _accumulate(executed, weight_only) -> None:
         _CALLS += 1
 
 
-def record_skip(mask: jax.Array, counts: jax.Array) -> None:
+def record_skip(mask: jax.Array, plane_bits: jax.Array) -> None:
     """Fold one masked launch into the process-wide skip counters.
 
     Call inside the jitted wrapper, right where the mask is built:
-    ``executed = mask.sum()`` (surviving tile-dots this launch) and
-    ``weight_only = counts.sum()`` (what the static schedule would have
-    run).  Shapes are static so the sums fuse into the step; the callback
-    is the only host hop and fires once per launch.
+    ``executed = mask_passes(mask)`` (surviving tile-dots this launch) and
+    ``weight_only = mask_passes(plane_bits)`` (what the static schedule
+    would have run).  Shapes are static so the sums fuse into the step; the
+    callback is the only host hop and fires once per launch.
     """
-    jax.debug.callback(_accumulate,
-                       jnp.sum(mask.astype(jnp.int32)),
-                       jnp.sum(counts.astype(jnp.int32)))
+    jax.debug.callback(_accumulate, mask_passes(mask),
+                       mask_passes(plane_bits))
 
 
 def skip_stats() -> Dict[str, float]:

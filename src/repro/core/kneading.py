@@ -35,7 +35,8 @@ from repro.core.schedule import (KneadedIntegrityError, KneadedSchedule,
                                  ShardedKneadedWeight,
                                  ShardedStackedKneadedWeight, build_schedule,
                                  integrity_checksums, shard_schedule,
-                                 shard_stacked_schedule, verify_checksums)
+                                 shard_stacked_schedule, tile_passes,
+                                 verify_checksums)
 
 __all__ = [
     "KNEADABLE_NAMES",
@@ -112,9 +113,10 @@ class KneadedWeight:
       occupancy: uint32 [B-1, ceil(K/ks/32), N/n_block] — per-(plane, tile)
                  essential-bit presence, bit-packed along the K-tile axis
                  (the pass-mark metadata; see :meth:`occupancy_map`).
-      schedule:  the occupancy map compacted into per-N-tile work lists of
-                 non-empty (plane, k_tile) items — what the kernel actually
-                 executes (scalar-prefetched; built once at knead time).
+      schedule:  the occupancy map compacted into per-N-tile walks of
+                 occupied K-tiles, each with its non-empty plane mask — what
+                 the kernel actually executes (scalar-prefetched; built once
+                 at knead time).
       bits:      static fixed-point width B.
       ks:        static kneading stride == kernel K-tile extent.
       n_block:   static kernel N-tile extent for occupancy granularity.
@@ -152,8 +154,8 @@ class KneadedWeight:
         metadata=dict(static=True), default=())
 
     _INTEGRITY_FIELDS = ("planes", "signs", "scale", "occupancy",
-                         "schedule.counts", "schedule.plane_ids",
-                         "schedule.ktile_ids")
+                         "schedule.counts", "schedule.ktile_ids",
+                         "schedule.plane_bits")
 
     @property
     def shape(self):
@@ -227,15 +229,15 @@ class KneadedWeight:
         return shard_schedule(self, mesh, axis=axis, partition=partition)
 
     def work_table(self):
-        """Static per-slice work totals: ``schedule.counts`` summed over the
-        N-tile axis, as a host numpy array shaped like the stack's leading
+        """Static per-slice work totals: the MXU passes of every N-tile
+        (the popcount of ``schedule.plane_bits``) summed over the N-tile
+        axis, as a host numpy array shaped like the stack's leading
         axes ([L, E] for an MoE bank, [L] for scan layers, scalar for a
         plain 2-D weight).  This is the ``layer_shard_work``-style input
         the routing-load / work-stealing accounting consumes: experts are
         naturally imbalanced work, and the table quantifies it without
-        touching device data beyond the (tiny) counts array."""
-        import numpy as np
-        return np.asarray(self.schedule.counts).sum(axis=-1)
+        touching device data beyond the (tiny) plane-mask array."""
+        return tile_passes(self.schedule.plane_bits).sum(axis=-1)
 
     def metadata_bytes(self) -> int:
         """Pass-mark metadata footprint: packed presence bits + the
@@ -347,18 +349,18 @@ def knead_stacked(
     what ``knead_padded(w[s])`` would build) and the resulting arrays stack
     with the leading stack axes: ``planes [*S, B-1, K/32, N]``, ``signs``,
     ``scale``, ``occupancy``, and the schedule's ``counts [*S, NN]`` /
-    ``plane_ids``/``ktile_ids [*S, NN, num_work]``.  Scanning this pytree as
+    ``ktile_ids``/``plane_bits [*S, NN, num_work]``.  Scanning this pytree as
     ``xs`` hands the body slice s's :class:`KneadedWeight`, bit-identical to
     the unstacked knead of that slice.
 
     The work dimension is padded to the *max* ``num_work`` across slices by
-    repeating each N-tile's last item — the same convention as intra-tile
-    ragged padding, so padded grid steps re-request resident blocks and idle
-    under the kernel's ``w < counts[j]`` guard.  A fully-empty slice (an
-    expert pruned to all-zero weights) has no last item to repeat and pads
-    with item 0 instead; its counts are all zero, so the guard masks every
-    step.  Statics on the stacked weight: ``num_work`` is the cross-slice
-    max and ``total_work`` the all-slice sum (a per-slice view therefore
+    repeating each N-tile's last K-tile with an empty plane mask — the same
+    convention as intra-tile ragged padding, so padded grid steps re-request
+    resident blocks and run no pass.  A fully-empty slice (an expert pruned
+    to all-zero weights) has no last K-tile to repeat and pads with K-tile 0
+    instead; its masks are all zero, so every step idles.  Statics on the
+    stacked weight: ``num_work`` is the cross-slice max and ``total_work``
+    the all-slice sum (a per-slice view therefore
     reports the stack totals — use :meth:`KneadedWeight.work_table` or
     :func:`knead_padded` per slice when per-slice accounting matters).
     """
@@ -372,13 +374,16 @@ def knead_stacked(
                  for s in range(flat.shape[0])]
     num_work = max(kw.schedule.num_work for kw in per_slice)
 
-    def pad_work(ids: jax.Array, have: int) -> jax.Array:
+    def pad_ktiles(ids: jax.Array, have: int) -> jax.Array:
         if have == num_work:
             return ids
-        if have == 0:   # empty slice: no last item to repeat; counts==0
+        if have == 0:   # empty slice: no last K-tile to repeat; counts==0
             return jnp.zeros((ids.shape[0], num_work), ids.dtype)
         return jnp.concatenate(
             [ids, jnp.repeat(ids[:, -1:], num_work - have, axis=1)], axis=1)
+
+    def pad_masks(bits: jax.Array, have: int) -> jax.Array:
+        return jnp.pad(bits, ((0, 0), (0, num_work - have)))
 
     def restack(xs):
         arr = jnp.stack(xs)
@@ -387,12 +392,12 @@ def knead_stacked(
     first = per_slice[0]
     sched = KneadedSchedule(
         counts=restack([kw.schedule.counts for kw in per_slice]),
-        plane_ids=restack([pad_work(kw.schedule.plane_ids,
-                                    kw.schedule.num_work)
+        ktile_ids=restack([pad_ktiles(kw.schedule.ktile_ids,
+                                      kw.schedule.num_work)
                            for kw in per_slice]),
-        ktile_ids=restack([pad_work(kw.schedule.ktile_ids,
-                                    kw.schedule.num_work)
-                           for kw in per_slice]),
+        plane_bits=restack([pad_masks(kw.schedule.plane_bits,
+                                      kw.schedule.num_work)
+                            for kw in per_slice]),
         num_work=num_work,
         total_work=sum(kw.schedule.total_work for kw in per_slice),
         nk=first.schedule.nk,

@@ -38,14 +38,14 @@ def sac_matmul_planes(a: jax.Array, kw: KneadedWeight) -> jax.Array:
     """Paper-faithful SAC: per-plane matmuls + single rear shift-and-add.
 
     Replays the Pallas kernel's *compacted-schedule order*: K tiles of extent
-    ``ks`` ascend (k-major, the schedule's sort key) with planes walked within
-    each tile, each partial dot accumulating into its plane's segment S_b.
-    The work items the schedule never dispatches are exactly the all-zero
+    ``ks`` ascend (the schedule's walk) with planes walked within each tile,
+    each partial dot accumulating into its plane's segment S_b.
+    The passes the schedule never dispatches are exactly the all-zero
     plane tiles, whose partial is exactly 0.0 — adding it is a bitwise no-op
     — so this dense replay realizes the same per-segment accumulation
     sequence as the compacted kernel, and the parity tests assert bit-exact
     *equality*, not closeness.  (``repro.core.schedule.replay_schedule`` is
-    the item-by-item sparse replay; the property tests pin all three paths
+    the pass-by-pass sparse replay; the property tests pin all three paths
     equal.)  Output = scale * sum_b 2^b S_b — the single rear adder tree,
     summed in plane order as the kernel's epilogue does.
 

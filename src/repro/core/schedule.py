@@ -4,33 +4,35 @@ The occupancy map already knows, at knead time, exactly which
 (plane, K-tile, N-tile) blocks carry essential bits.  The dense-grid kernel
 still *visited* every block and predicated the dot (``pl.when(occ > 0)``) —
 every slack block cost a grid step, an unpack, and a branch.  This module
-turns the metadata into a *schedule* instead: per N-tile, a compacted list of
-the non-empty ``(plane, k_tile)`` work items, so the kernel grid walks real
-work only and executed MXU passes equal the occupancy nonzero count, not
-``(B-1) * K/bk * N/bn``.  This is the TPU realization of front-end
-ineffectual-work scheduling (Bit-Tactical) + essential-bit-only execution
-(Laconic): slack is never dispatched, rather than dispatched-and-skipped.
+turns the metadata into a *schedule* instead: per N-tile, the ascending
+list of **occupied K-tiles** (those holding at least one non-empty plane
+tile), each with a bit mask of its non-empty planes.  The kernel grid walks
+one occupied K-tile per step and runs that tile's present planes inside the
+step, so executed MXU passes equal the occupancy nonzero count, not
+``(B-1) * K/bk * N/bn``, and a wholly empty K-tile costs no grid step.
+This is the TPU realization of front-end ineffectual-work scheduling
+(Bit-Tactical) + essential-bit-only execution (Laconic): slack is never
+dispatched, rather than dispatched-and-skipped.
 
-Work order is **k-major** (k_tile ascending, plane ascending within a
-k_tile): consecutive items then share the activation K-block and the sign
-block, so the kernel's index maps re-request the same blocks and Pallas
-elides the re-fetch.  Within a fixed plane, k_tiles therefore ascend — the
-same per-segment accumulation order as a dense K sweep, which is what keeps
-the compacted kernel bit-exact against the planes oracle.
+K-tiles ascend within each N-tile's list, so every plane segment
+accumulates its K-tiles in ascending order — the same per-segment
+accumulation order as a dense K sweep, which is what keeps the compacted
+kernel bit-exact against the planes oracle.  (The order of planes within
+one step does not matter: they feed separate accumulators.)
 
-Ragged tiles are padded to the max work count by *repeating the last real
-item* (index maps of padded steps request already-resident blocks: no DMA),
-and the kernel guards the dot with ``w < counts[j]``.  All-empty N-tiles
-carry count 0 and execute nothing.
+Ragged tiles are padded to the max K-tile count by *repeating the last
+occupied K-tile* with an empty plane mask (index maps of padded steps
+request already-resident blocks: no DMA; the empty mask runs no pass).
+All-empty N-tiles carry count 0 and execute nothing.
 
 **Sharding** (:func:`shard_schedule`, docs/DESIGN.md §5): because the work
 lists are independent per N-tile, the schedule partitions along N for free —
 each shard of a device mesh takes a contiguous slab of N-tiles together with
-exactly those tiles' work lists.  The per-tile items and their k-major order
+exactly those tiles' work lists.  The per-tile lists and their K order
 are untouched, so a shard computes its output columns through the *same*
 accumulation sequence as the single-device kernel and sharded execution
-stays bit-exact.  Load per device is its shard's *occupancy* (sum of its
-tiles' counts), not its dense tile count — the SCNN/Bit-Tactical principle
+stays bit-exact.  Load per device is its shard's *occupancy* (the MXU passes
+of its tiles), not its dense tile count — the SCNN/Bit-Tactical principle
 of distributing the compacted work list rather than the dense iteration
 space — and :meth:`ShardedKneadedWeight.imbalance` reports how unevenly the
 occupancy landed.
@@ -39,11 +41,11 @@ occupancy landed.
 contiguous slabs inherit whatever skew the occupancy happens to carry — a
 column-pruned layer can land all of its work on shard 0 while shard 3 idles.
 Because per-tile work is static, the partitioner can do better at shard
-time: LPT greedy bin-packing assigns tiles (largest count first) to the
+time: LPT greedy bin-packing assigns tiles (most passes first) to the
 least-loaded shard with free capacity, and the tile→slot permutation is
 recorded in ``tile_slot`` so the execution layer can gather the output
-columns back into original order.  Per-tile work lists and their k-major
-order are untouched — only *which shard runs which tile* changes — so the
+columns back into original order.  Per-tile work lists and their K order
+are untouched — only *which shard runs which tile* changes — so the
 per-tile f32 accumulation sequence, and therefore the output bits, are
 identical to the contiguous and unsharded kernels.  All-empty padding tiles
 participate in the packing as zero-cost filler, so indivisible tile counts
@@ -74,7 +76,7 @@ if TYPE_CHECKING:  # avoid the import cycle (kneading imports this module)
 __all__ = ["KneadedIntegrityError", "KneadedSchedule", "ShardedKneadedWeight",
            "ShardedStackedKneadedWeight", "build_schedule", "replay_schedule",
            "shard_schedule", "shard_stacked_schedule", "integrity_checksums",
-           "verify_checksums"]
+           "verify_checksums", "tile_passes"]
 
 
 class KneadedIntegrityError(RuntimeError):
@@ -113,41 +115,62 @@ def verify_checksums(obj, checksums: Tuple[Tuple[str, int], ...]
                  if _crc32(_walk(obj, name)) != want)
 
 
+def tile_passes(plane_bits) -> np.ndarray:
+    """Host-side MXU passes per N-tile: the popcount of every slot's plane
+    mask, summed over the slot (last) axis.  Works on any leading stack or
+    shard axes."""
+    return np.bitwise_count(np.asarray(plane_bits)).astype(np.int64).sum(
+        axis=-1)
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class KneadedSchedule:
-    """Compacted per-N-tile work lists for one kneaded weight.
+    """Compacted per-N-tile K-tile walks for one kneaded weight.
 
     Attributes:
-      counts:    int32 [N/n_block] — number of real work items per N-tile.
-      plane_ids: int32 [N/n_block, num_work] — plane index of each item.
-      ktile_ids: int32 [N/n_block, num_work] — K-tile index of each item.
-                 Entries past ``counts[j]`` repeat the tile's last real item
-                 (or 0 for all-empty tiles) so padded grid steps re-request
-                 resident blocks.
-      num_work:  static grid extent of the work dimension:
-                 ``max(1, max(counts))`` (>= 1 so init/epilogue always run).
-      total_work: static sum of counts == occupancy nonzero count == MXU
-                 passes the kernel executes per M-step row of the grid.
+      counts:     int32 [N/n_block] — occupied K-tiles per N-tile (the grid
+                  steps that tile runs).
+      ktile_ids:  int32 [N/n_block, num_work] — the occupied K-tiles,
+                  ascending.  Entries past ``counts[j]`` repeat the tile's
+                  last occupied K-tile (or 0 for all-empty tiles) so padded
+                  grid steps re-request resident blocks.
+      plane_bits: int32 [N/n_block, num_work] — per slot, bit ``b`` set iff
+                  plane ``b`` of that K-tile is non-empty; 0 past
+                  ``counts[j]``.  This is the kernel's static survival mask.
+      num_work:   static grid extent of the work dimension:
+                  ``max(1, max(counts))`` (>= 1 so init/epilogue always run).
+      total_work: static popcount of ``plane_bits`` == occupancy nonzero
+                  count == MXU passes the kernel executes per M-step row of
+                  the grid.
       nk, n_tiles: static dense extents (K/ks, N/n_block) — the dense
-                 schedule would be ``(B-1) * nk`` items per N-tile.
+                  schedule would be ``(B-1) * nk`` passes per N-tile.
     """
 
     counts: jax.Array
-    plane_ids: jax.Array
     ktile_ids: jax.Array
+    plane_bits: jax.Array
     num_work: int = dataclasses.field(metadata=dict(static=True), default=1)
     total_work: int = dataclasses.field(metadata=dict(static=True), default=0)
     nk: int = dataclasses.field(metadata=dict(static=True), default=0)
     n_tiles: int = dataclasses.field(metadata=dict(static=True), default=0)
 
     def dense_work(self, bits: int) -> int:
-        """Work items the dense grid would execute: (B-1) * K/ks * N/n_block."""
+        """MXU passes the dense grid would execute: (B-1) * K/ks * N/n_block."""
         return (bits - 1) * self.nk * self.n_tiles
 
+    @property
+    def planes_per_step(self) -> float:
+        """MXU passes per executed grid step, ``total_work / sum(counts)``
+        (host-side): B-1 where every occupied K-tile has all its planes,
+        falling toward 1 as the weight-side skip empties planes; 0.0 for a
+        schedule with no work."""
+        steps = int(np.asarray(self.counts).sum())
+        return self.total_work / steps if steps else 0.0
+
     def metadata_bytes(self) -> int:
-        return (self.counts.size + self.plane_ids.size
-                + self.ktile_ids.size) * 4
+        return (self.counts.size + self.ktile_ids.size
+                + self.plane_bits.size) * 4
 
 
 def build_schedule(occupancy_map: jax.Array) -> KneadedSchedule:
@@ -158,28 +181,29 @@ def build_schedule(occupancy_map: jax.Array) -> KneadedSchedule:
         pass-mark metadata).  Host-side (numpy) — kneading is an offline
         conversion, and ``num_work``/``total_work`` must be static.
     Returns:
-      A :class:`KneadedSchedule` whose items enumerate exactly the nonzero
-      occupancy entries, k-major per N-tile.
+      A :class:`KneadedSchedule` whose slots enumerate exactly the occupied
+      K-tiles per N-tile, ascending, each with its non-empty plane mask.
     """
     occ = np.asarray(occupancy_map) != 0                   # [B-1, NK, NN]
     nb, nk, nn = occ.shape
-    counts = occ.sum(axis=(0, 1)).astype(np.int32)         # [NN]
+    weights = (1 << np.arange(nb, dtype=np.int64)).reshape(-1, 1, 1)
+    masks = (occ * weights).sum(axis=0).astype(np.int32)   # [NK, NN]
+    counts = (masks != 0).sum(axis=0).astype(np.int32)     # [NN]
     num_work = max(1, int(counts.max(initial=0)))
-    plane_ids = np.zeros((nn, num_work), np.int32)
     ktile_ids = np.zeros((nn, num_work), np.int32)
+    plane_bits = np.zeros((nn, num_work), np.int32)
     for j in range(nn):
-        # [NK, B-1] nonzero -> row-major: k_tile ascending, plane within
-        kt, pb = np.nonzero(occ[:, :, j].T)
+        kt = np.flatnonzero(masks[:, j])                   # ascending
         c = kt.size
         if c:
-            plane_ids[j, :c], ktile_ids[j, :c] = pb, kt
-            plane_ids[j, c:], ktile_ids[j, c:] = pb[-1], kt[-1]
+            ktile_ids[j, :c], ktile_ids[j, c:] = kt, kt[-1]
+            plane_bits[j, :c] = masks[kt, j]
     return KneadedSchedule(
         counts=jnp.asarray(counts),
-        plane_ids=jnp.asarray(plane_ids),
         ktile_ids=jnp.asarray(ktile_ids),
+        plane_bits=jnp.asarray(plane_bits),
         num_work=num_work,
-        total_work=int(counts.sum()),
+        total_work=int(occ.sum()),
         nk=nk,
         n_tiles=nn,
     )
@@ -188,21 +212,23 @@ def build_schedule(occupancy_map: jax.Array) -> KneadedSchedule:
 def replay_schedule(a, kw, act_presence=None) -> jax.Array:
     """Executable spec of the compacted kernel: walk the schedule on the host.
 
-    Replays, in numpy, exactly the work items the kernel's grid executes —
-    per N-tile, per work item ``w < counts[j]``, one f32 dot accumulated into
-    that item's plane segment, then the rear-adder-tree epilogue.  Used by
-    the schedule property tests as the order-faithful oracle; bit-exact
-    against both ``impl="planes"`` and ``impl="pallas"``.
+    Replays, in numpy, exactly the passes the kernel's grid executes — per
+    N-tile, per occupied K-tile slot ``w < counts[j]``, per plane in that
+    slot's mask, one f32 dot accumulated into the plane's segment, then the
+    rear-adder-tree epilogue.  Used by the schedule property tests as the
+    order-faithful oracle; bit-exact against both ``impl="planes"`` and
+    ``impl="pallas"``.
 
-    Control flow (which items run, in what order) is host-side numpy over the
-    schedule arrays; the arithmetic itself is the same jnp ops as the planes
-    oracle, so accumulation rounding is identical operation-for-operation.
+    Control flow (which passes run, in what order) is host-side numpy over
+    the schedule arrays; the arithmetic itself is the same jnp ops as the
+    planes oracle, so accumulation rounding is identical
+    operation-for-operation.
 
     ``act_presence`` ({0,1} [nk], e.g. from
     :func:`repro.core.activation_occupancy.ktile_presence`) replays the
-    activation-*intersected* order of the two-sided skip (docs/DESIGN.md
-    §12): real items whose K-tile presence bit is 0 are dropped, survivors
-    keep their k-major order — the oracle the masked Pallas walk is pinned
+    activation-*intersected* walk of the two-sided skip (docs/DESIGN.md
+    §12): slots whose K-tile presence bit is 0 are dropped, survivors keep
+    their ascending order — the oracle the masked Pallas walk is pinned
     bit-exact against, and (when the presence honestly reflects ``a``'s
     zeros) bit-exact against the unskipped replay too, since every dropped
     dot is exactly 0.
@@ -220,8 +246,8 @@ def replay_schedule(a, kw, act_presence=None) -> jax.Array:
     sign = 1 - 2 * bitplanes.unpack_bits(kw.signs, axis=0).astype(jnp.int8)
     a32 = jnp.asarray(a, jnp.float32)
     counts = np.asarray(sched.counts)
-    plane_ids = np.asarray(sched.plane_ids)
     ktile_ids = np.asarray(sched.ktile_ids)
+    plane_bits = np.asarray(sched.plane_bits)
     presence = None if act_presence is None else np.asarray(act_presence)
     ks, nb = kw.ks, kw.n_block
     m = a32.shape[0]
@@ -230,14 +256,17 @@ def replay_schedule(a, kw, act_presence=None) -> jax.Array:
     for j in range(sched.n_tiles):
         nsl = slice(j * nb, (j + 1) * nb)
         seg = [jnp.zeros((m, nb), jnp.float32) for _ in range(kw.bits - 1)]
-        for w in range(int(counts[j])):                # real items only
-            b, t = int(plane_ids[j, w]), int(ktile_ids[j, w])
+        for w in range(int(counts[j])):                # occupied K-tiles only
+            t, bits_w = int(ktile_ids[j, w]), int(plane_bits[j, w])
             if presence is not None and not presence[t]:
                 continue                               # activation-side skip
             ksl = slice(t * ks, (t + 1) * ks)
-            plane = (mag[b, ksl, nsl].astype(jnp.int8)
-                     * sign[ksl, nsl]).astype(jnp.float32)
-            seg[b] = seg[b] + a32[:, ksl] @ plane      # S_b += A_t @ P_bt
+            for b in range(kw.bits - 1):
+                if not (bits_w >> b) & 1:
+                    continue                           # empty plane tile
+                plane = (mag[b, ksl, nsl].astype(jnp.int8)
+                         * sign[ksl, nsl]).astype(jnp.float32)
+                seg[b] = seg[b] + a32[:, ksl] @ plane  # S_b += A_t @ P_bt
         out_tiles.append(jnp.sum(jnp.stack(seg) * weights, axis=0))
     out = jnp.concatenate(out_tiles, axis=1)
     return out * kw.scale
@@ -328,8 +357,10 @@ class ShardedKneadedWeight:
       planes:    uint32 [S, B-1, K/32, n/S] — magnitude planes, N-sliced.
       signs:     uint32 [S, K/32, n/S].
       scale:     f32   [S, 1, n/S].
-      counts:    int32 [S, T] per-shard work counts (T = tiles_per_shard).
-      plane_ids / ktile_ids: int32 [S, T, num_work] per-shard work lists.
+      counts:    int32 [S, T] per-shard occupied K-tile counts
+                 (T = tiles_per_shard).
+      ktile_ids / plane_bits: int32 [S, T, num_work] per-shard K-tile walks
+                 and their plane masks.
       tile_slot: int32 [S*T] — the tile→slot permutation: original N-tile
                  ``j`` lives in flattened packed slot ``tile_slot[j]``
                  (shard ``tile_slot[j] // T``, position ``% T``).  Identity
@@ -352,8 +383,8 @@ class ShardedKneadedWeight:
     signs: jax.Array
     scale: jax.Array
     counts: jax.Array
-    plane_ids: jax.Array
     ktile_ids: jax.Array
+    plane_bits: jax.Array
     tile_slot: jax.Array
     num_shards: int = dataclasses.field(metadata=dict(static=True), default=1)
     num_work: int = dataclasses.field(metadata=dict(static=True), default=1)
@@ -376,7 +407,7 @@ class ShardedKneadedWeight:
         metadata=dict(static=True), default=())
 
     _INTEGRITY_FIELDS = ("planes", "signs", "scale", "counts",
-                         "plane_ids", "ktile_ids", "tile_slot")
+                         "ktile_ids", "plane_bits", "tile_slot")
 
     def with_checksums(self) -> "ShardedKneadedWeight":
         """Stamp shard-time CRC32s over every array field (host-side)."""
@@ -419,7 +450,7 @@ class ShardedKneadedWeight:
         return -(-self.logical_n // self.n_block)
 
     def dense_work(self) -> int:
-        """Work items the dense grid would execute across all shards.
+        """MXU passes the dense grid would execute across all shards.
 
         Counts the pre-shard-padding tiles only, so this equals the
         unsharded ``KneadedSchedule.dense_work`` — all-empty shard-padding
@@ -431,8 +462,8 @@ class ShardedKneadedWeight:
         """Shard ``s``'s compacted schedule (the program each device runs)."""
         return KneadedSchedule(
             counts=self.counts[s],
-            plane_ids=self.plane_ids[s],
             ktile_ids=self.ktile_ids[s],
+            plane_bits=self.plane_bits[s],
             num_work=self.num_work,
             total_work=self.shard_work[s],
             nk=self.nk,
@@ -456,8 +487,8 @@ class ShardedKneadedWeight:
         }
 
     def metadata_bytes(self) -> int:
-        return (self.counts.size + self.plane_ids.size
-                + self.ktile_ids.size + self.tile_slot.size) * 4
+        return (self.counts.size + self.ktile_ids.size
+                + self.plane_bits.size + self.tile_slot.size) * 4
 
     def packed_bytes(self) -> int:
         """HBM bytes across all shards: planes + signs + scales + schedule."""
@@ -485,16 +516,16 @@ def shard_schedule(kw: "KneadedWeight",
 
     ``partition="contiguous"`` (default): each of the ``mesh.shape[axis]``
     shards receives a contiguous slab of N-tiles with exactly those tiles'
-    compacted work lists — per-tile items and k-major order unchanged, so
-    sharded outputs are bit-exact against the single-device kernel.
+    compacted work lists — per-tile K-tile walks unchanged, so sharded
+    outputs are bit-exact against the single-device kernel.
 
     ``partition="balanced"``: tiles are LPT bin-packed onto shards by their
-    static occupancy counts (:func:`_lpt_tile_slots`), so
+    static MXU passes (:func:`_lpt_tile_slots`), so
     ``max(shard_work)`` approaches ``ceil(total_work / S)`` regardless of
     where the occupancy landed.  The tile→slot permutation is recorded in
     ``tile_slot``; the execution layer gathers output N-blocks back into
     original order (``sac_matmul_pallas_sharded``), and because per-tile
-    work lists and k-major order are untouched, the gathered output is
+    work lists and their K order are untouched, the gathered output is
     still bit-exact against the single-device kernel (docs/DESIGN.md §11).
 
     When the N-tile count does not divide the shard count, all-empty
@@ -532,18 +563,18 @@ def shard_schedule(kw: "KneadedWeight",
     scale = jnp.broadcast_to(jnp.asarray(kw.scale, jnp.float32)
                              .reshape(1, -1), (1, kw.n))
     counts = sched.counts
-    plane_ids, ktile_ids = sched.plane_ids, sched.ktile_ids
+    ktile_ids, plane_bits = sched.ktile_ids, sched.plane_bits
     if pad_tiles:
         planes = jnp.pad(planes, ((0, 0), (0, 0), (0, pad_cols)))
         signs = jnp.pad(signs, ((0, 0), (0, pad_cols)))
         scale = jnp.pad(scale, ((0, 0), (0, pad_cols)), constant_values=1.0)
         counts = jnp.pad(counts, (0, pad_tiles))
-        plane_ids = jnp.pad(plane_ids, ((0, pad_tiles), (0, 0)))
         ktile_ids = jnp.pad(ktile_ids, ((0, pad_tiles), (0, 0)))
+        plane_bits = jnp.pad(plane_bits, ((0, pad_tiles), (0, 0)))
 
-    host_counts = np.asarray(counts)
+    host_passes = tile_passes(plane_bits)
     if partition == "balanced":
-        slot = _balanced_tile_slots(host_counts, num, tps)
+        slot = _balanced_tile_slots(host_passes, num, tps)
         inv = np.argsort(slot).astype(np.int32)  # inv[s] = tile in slot s
         inv_j = jnp.asarray(inv)
         nb_ = kw.bits - 1
@@ -555,9 +586,9 @@ def shard_schedule(kw: "KneadedWeight",
         scale = jnp.take(scale.reshape(1, total, kw.n_block),
                          inv_j, axis=1).reshape(1, n_pad)
         counts = jnp.take(counts, inv_j, axis=0)
-        plane_ids = jnp.take(plane_ids, inv_j, axis=0)
         ktile_ids = jnp.take(ktile_ids, inv_j, axis=0)
-        host_counts = host_counts[inv]
+        plane_bits = jnp.take(plane_bits, inv_j, axis=0)
+        host_passes = host_passes[inv]
     else:
         slot = np.arange(total, dtype=np.int32)
 
@@ -565,14 +596,14 @@ def shard_schedule(kw: "KneadedWeight",
     nb = kw.bits - 1
     kwords = kw.k // 32
     shard_work = tuple(
-        int(c) for c in host_counts.reshape(num, tps).sum(axis=1))
+        int(c) for c in host_passes.reshape(num, tps).sum(axis=1))
     return ShardedKneadedWeight(
         planes=planes.reshape(nb, kwords, num, shard_n).transpose(2, 0, 1, 3),
         signs=signs.reshape(kwords, num, shard_n).transpose(1, 0, 2),
         scale=scale.reshape(1, num, shard_n).transpose(1, 0, 2),
         counts=counts.reshape(num, tps),
-        plane_ids=plane_ids.reshape(num, tps, sched.num_work),
         ktile_ids=ktile_ids.reshape(num, tps, sched.num_work),
+        plane_bits=plane_bits.reshape(num, tps, sched.num_work),
         tile_slot=jnp.asarray(slot),
         num_shards=num,
         num_work=sched.num_work,
@@ -624,8 +655,8 @@ class ShardedStackedKneadedWeight(ShardedKneadedWeight):
         :meth:`ShardedKneadedWeight.schedule_for` instead)."""
         return KneadedSchedule(
             counts=self.counts[layer, s],
-            plane_ids=self.plane_ids[layer, s],
             ktile_ids=self.ktile_ids[layer, s],
+            plane_bits=self.plane_bits[layer, s],
             num_work=self.num_work,
             total_work=self.layer_shard_work[layer][s],
             nk=self.nk,
@@ -633,7 +664,7 @@ class ShardedStackedKneadedWeight(ShardedKneadedWeight):
         )
 
     def dense_work(self) -> int:
-        """Dense-grid work items across all layers and shards (stack-level
+        """Dense-grid MXU passes across all layers and shards (stack-level
         accounting, matching the stacked ``total_work`` convention)."""
         return self.num_layers * super().dense_work()
 
@@ -676,13 +707,13 @@ def shard_stacked_schedule(kw: "KneadedWeight",
     lists ``[L, NN, num_work]``).  Every layer's per-N-tile work lists are
     partitioned exactly as :func:`shard_schedule` partitions one layer's —
     shard *s* of layer *l* takes the same contiguous slab of N-tiles with
-    those tiles' compacted items, k-major order untouched, so the sharded
+    those tiles' K-tile walks, order untouched, so the sharded
     stack is bit-exact against the unsharded one layer by layer.  All layers
     share the (already cross-layer-padded) ``num_work``, so the whole stack
     runs one kernel program.
 
     ``partition="balanced"`` repartitions **per layer**: each layer's tiles
-    are LPT-packed on that layer's own counts (occupancy skew is per-layer
+    are LPT-packed on that layer's own passes (occupancy skew is per-layer
     — one layer's hot tiles are another's empty ones), giving ``tile_slot``
     a leading layer axis ``[L, S*T]`` that ``jax.lax.scan`` slices together
     with the weight arrays, so the per-layer gather in the execution layer
@@ -726,19 +757,19 @@ def shard_stacked_schedule(kw: "KneadedWeight",
         jnp.asarray(kw.scale, jnp.float32).reshape(layers, 1, -1),
         (layers, 1, kw.n))
     counts = sched.counts                                 # [L, NN]
-    plane_ids, ktile_ids = sched.plane_ids, sched.ktile_ids
+    ktile_ids, plane_bits = sched.ktile_ids, sched.plane_bits
     if pad_tiles:
         planes = jnp.pad(planes, ((0, 0),) * 3 + ((0, pad_cols),))
         signs = jnp.pad(signs, ((0, 0),) * 2 + ((0, pad_cols),))
         scale = jnp.pad(scale, ((0, 0), (0, 0), (0, pad_cols)),
                         constant_values=1.0)
         counts = jnp.pad(counts, ((0, 0), (0, pad_tiles)))
-        plane_ids = jnp.pad(plane_ids, ((0, 0), (0, pad_tiles), (0, 0)))
         ktile_ids = jnp.pad(ktile_ids, ((0, 0), (0, pad_tiles), (0, 0)))
+        plane_bits = jnp.pad(plane_bits, ((0, 0), (0, pad_tiles), (0, 0)))
 
-    host_counts = np.asarray(counts)                      # [L, total]
+    host_passes = tile_passes(plane_bits)                 # [L, total]
     if partition == "balanced":
-        slot = np.stack([_balanced_tile_slots(host_counts[layer], num, tps)
+        slot = np.stack([_balanced_tile_slots(host_passes[layer], num, tps)
                          for layer in range(layers)])     # [L, total]
         inv = np.argsort(slot, axis=1).astype(np.int32)
         inv_j = jnp.asarray(inv)
@@ -757,9 +788,10 @@ def shard_stacked_schedule(kw: "KneadedWeight",
             inv_j[:, None, :, None], axis=2,
         ).reshape(layers, 1, n_pad)
         counts = jnp.take_along_axis(counts, inv_j, axis=1)
-        plane_ids = jnp.take_along_axis(plane_ids, inv_j[:, :, None], axis=1)
         ktile_ids = jnp.take_along_axis(ktile_ids, inv_j[:, :, None], axis=1)
-        host_counts = np.take_along_axis(host_counts, inv, axis=1)
+        plane_bits = jnp.take_along_axis(plane_bits, inv_j[:, :, None],
+                                         axis=1)
+        host_passes = np.take_along_axis(host_passes, inv, axis=1)
     else:
         slot = np.broadcast_to(np.arange(total, dtype=np.int32),
                                (layers, total)).copy()
@@ -767,7 +799,7 @@ def shard_stacked_schedule(kw: "KneadedWeight",
     shard_n = n_pad // num
     nb = kw.bits - 1
     kwords = kw.k // 32
-    per_layer_work = host_counts.reshape(layers, num, tps).sum(axis=2)
+    per_layer_work = host_passes.reshape(layers, num, tps).sum(axis=2)
     layer_shard_work = tuple(tuple(int(c) for c in row)
                              for row in per_layer_work)
     shard_work = tuple(int(c) for c in per_layer_work.sum(axis=0))
@@ -778,8 +810,8 @@ def shard_stacked_schedule(kw: "KneadedWeight",
                    .transpose(0, 2, 1, 3),
         scale=scale.reshape(layers, 1, num, shard_n).transpose(0, 2, 1, 3),
         counts=counts.reshape(layers, num, tps),
-        plane_ids=plane_ids.reshape(layers, num, tps, sched.num_work),
         ktile_ids=ktile_ids.reshape(layers, num, tps, sched.num_work),
+        plane_bits=plane_bits.reshape(layers, num, tps, sched.num_work),
         tile_slot=jnp.asarray(slot),
         num_shards=num,
         num_work=sched.num_work,

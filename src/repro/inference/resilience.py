@@ -212,8 +212,8 @@ _CORRUPTIBLE = {
     "signs": "signs",
     "occupancy": "occupancy",
     "schedule.counts": "counts",
-    "schedule.plane_ids": "plane_ids",
     "schedule.ktile_ids": "ktile_ids",
+    "schedule.plane_bits": "plane_bits",
 }
 
 

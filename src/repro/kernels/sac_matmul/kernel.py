@@ -1,48 +1,58 @@
-"""Pallas TPU kernel: SAC bit-plane matmul on a compacted work schedule.
+"""Pallas TPU kernel: SAC bit-plane matmul on a compacted K-tile walk.
 
 Hardware mapping of the paper's PE (Fig 5) onto the TPU memory hierarchy:
 
   throttle buffer + pass marks  -> :class:`~repro.core.schedule.KneadedSchedule`
                                    — the occupancy map compacted at knead time
-                                   into per-N-tile work lists of non-empty
-                                   (plane, K-tile) items, delivered via scalar
-                                   prefetch (SMEM).  The grid walks the lists,
-                                   so slack work is never *dispatched*, rather
-                                   than dispatched-and-predicated-away
-  splitter array                -> in-VMEM unpack of the one bit-packed plane
-                                   the current work item names (32 weights/
-                                   uint32 word) + sign application
-  16x16 segment adder fabric    -> one MXU dot per scheduled work item
+                                   into per-N-tile walks of *occupied* K-tiles,
+                                   each with a mask of its non-empty planes,
+                                   delivered via scalar prefetch (SMEM).  The
+                                   grid walks the lists, so an empty K-tile is
+                                   never *dispatched*, rather than
+                                   dispatched-and-predicated-away
+  splitter array                -> in-VMEM unpack of each present plane of the
+                                   staged K-tile (32 weights/uint32 word) +
+                                   sign application
+  16x16 segment adder fabric    -> one MXU dot per present plane
   segment registers S0..S15     -> VMEM scratch accumulator [B-1, bm, bn] f32,
-                                   indexed by the item's plane id
+                                   one segment per plane
   rear adder tree (shift once)  -> epilogue ``sum_b 2^b * S_b`` executed once
                                    per output tile at the last work step
   per-channel scale             -> applied once in the same epilogue (SAC's
                                    "no intermediate pair-wise partial sums")
 
-Grid: ``(M/bm, N/bn, num_work)`` with the *work list* innermost (revisiting =
-output-stationary).  ``num_work`` is the max per-N-tile work count; tile j
-executes exactly its surviving mask entries as MXU passes and idles through
-the rest — padded schedule entries repeat the tile's last real item, so their
-index maps request already-resident blocks and Pallas elides the DMA.  The
-guard consults a scalar-prefetched *survival mask* rather than the raw work
-counts: the static weight-only mask (``w < counts[j]`` expanded per slot)
-reproduces the original walk bit-for-bit, while the runtime
-activation-intersected mask (docs/DESIGN.md §12) additionally drops real
-items whose activation K-slice is all zero — the two-sided skip.  Total
-executed MXU passes per M-step therefore equal the *intersected* occupancy
-nonzero count, not the dense ``(B-1) * K/bk * N/bn`` — the paper's "skip the
-slack" realized at the front-end scheduler rather than in the kernel body.
+Grid: ``(M/bm, N/bn, num_work)`` with the *K-tile walk* innermost
+(revisiting = output-stationary).  ``num_work`` is the max occupied K-tile
+count of any N-tile.  One grid step stages the whole plane stack of its
+K-tile — one DMA of ``(B-1, bk/32, bn)`` packed words, 28 KB at 8 bits —
+with its activation and sign blocks, unpacks the signs once, and runs an
+unrolled ``for b in range(B-1)`` whose body (unpack plane ``b``, apply the
+signs, one MXU dot, add into ``S_b``) executes under bit ``b`` of the
+slot's scalar-prefetched *survival mask*.  Padded slots repeat the tile's
+last K-tile with mask 0: their index maps request already-resident blocks
+(Pallas elides the DMA) and the body idles.  The static mask is the
+schedule's ``plane_bits``; the runtime activation-intersected mask
+(docs/DESIGN.md §12) additionally zeroes slots whose activation K-slice is
+all zero — the two-sided skip.  Total executed MXU passes per M-step
+therefore equal the *intersected* occupancy nonzero count, not the dense
+``(B-1) * K/bk * N/bn``, while grid steps equal occupied K-tiles — the
+paper's "skip the slack" realized at the front-end scheduler, with the
+per-step overhead paid once per K-tile rather than once per plane.
 
-Work items are k-major (K-tile ascending, plane within), so consecutive items
-share the activation and sign blocks, and per-plane segments accumulate their
-K-tiles in ascending order — the same accumulation sequence as a dense K
-sweep, which keeps this kernel bit-exact against the planes oracle.
+Skip granularity, DMA versus compute: an empty K-tile (every plane empty in
+this N-tile) costs neither a step nor a DMA.  An occupied K-tile's empty
+planes are DMA'd with its plane stack but never unpacked or multiplied —
+the pass count is exact, the bytes are the K-tile's.
+
+Each segment ``S_b`` accumulates its K-tiles in ascending order through the
+same ``[bm, bk] x [bk, bn]`` dot at the same precision as a dense K sweep;
+the order of planes within one step is free because they feed separate
+accumulators.  That keeps this kernel bit-exact against the planes oracle.
 
 Multi-device (docs/DESIGN.md §5): the grid's N dimension partitions across a
 mesh by sharding the *schedule* — ``ops.sac_matmul_pallas_sharded`` launches
 this same kernel under ``jax.shard_map`` with each device holding a
-contiguous slab of N-tiles plus exactly those tiles' work lists
+contiguous slab of N-tiles plus exactly those tiles' walks
 (``ShardedKneadedWeight``), so per-device executed MXU passes equal the
 shard's occupancy nonzeros and per-tile accumulation order — hence
 bit-exactness — is preserved shard by shard.
@@ -51,28 +61,29 @@ bit-exactness — is preserved shard by shard.
 paper sweeps in Fig 11.  Larger KS: fewer, coarser skip chances but less
 metadata; smaller KS: finer skips, more metadata.  With packed presence bits
 (1 bit per (plane, K-tile, N-tile)) plus the int32 schedule (a count per
-N-tile + 2 words per work slot, slots = N-tiles x the *max* per-tile
-occupied count), metadata scales with the worst occupied N-tile rather than
+N-tile + 2 words per slot, slots = N-tiles x the *max* per-tile occupied
+K-tile count), metadata scales with the worst occupied N-tile rather than
 the dense tile count, so small-KS schedules on sparse weights stay cheap.
 
-VMEM budget per step (bm=bn=256, bk=512, B=8):
-  A tile 256x512x4B = 512KB; one plane tile (512/32)x256x4B = 16KB;
-  segment scratch 7x256x256x4B = 1.8MB; sign-multiplier cache
-  512x256x4B = 512KB; out 256KB  => ~3.1MB << VMEM.
-(The dense-grid kernel staged all B-1 plane tiles per step; the schedule
-names one plane per item, cutting the staged plane footprint (B-1)x.)
-MXU alignment: bm, bn multiples of 128; bk multiple of 256 (>= 8 sublanes of
-packed words after the x32 unpack).
+VMEM budget per step (B=8, bk=256, bn=128), each input double-buffered:
+  A tile bm x 256 (bf16 64 KB at bm=128, f32 1 MB at bm=1024); plane stack
+  7 x 8 x 128 x 4B = 28 KB; signs 4 KB; segment scratch 7 x bm x 128 x 4B
+  (3.7 MB at bm=1024); out bm x 128 x 4B; plus the unpacked sign multiplier
+  (128 KB) and one plane's temporaries (128 KB unpacked + the bm x 128 dot)
+  per unrolled plane.  Well inside the v5e's scoped VMEM at every block the
+  ops layer uses.
+MXU alignment: bm a multiple of 8, bn of 128; bk a multiple of 256 (>= 8
+sublanes of packed words after the x32 unpack).
 
 Decode / GEMV regime (LM serving, M = batch, often 1): the same kernel runs
 with ``bm`` shrunk to the 8-row f32 sublane floor — the ops-layer
 ``_pad_activations`` rounds M up to a multiple of 8 and caps the M block at
 that, so a one-token decode step is a single M-step grid whose A tile is
-8 x bk instead of a 97%-padding 256-row slab.  The work-list walk, segment
-scratch indexing, and epilogue are identical to the streamed prefill grid;
-only the block shape changes, so decode output stays bit-exact against the
-planes oracle (and therefore against prefill logits for the same row).
-``bm`` must stay a multiple of 8 (sublane floor) — asserted below.
+8 x bk instead of a 97%-padding 256-row slab.  The walk, segment scratch
+indexing, and epilogue are identical to the streamed prefill grid; only the
+block shape changes, so decode output stays bit-exact against the planes
+oracle (and therefore against prefill logits for the same row).  ``bm``
+must stay a multiple of 8 (sublane floor) — asserted below.
 """
 from __future__ import annotations
 
@@ -102,17 +113,14 @@ def _unpack_words(words: jax.Array, bk: int) -> jax.Array:
 
 
 def sac_matmul_kernel(
-    mask_ref,       # scalar prefetch: [N/bn, num_work] int32 survival mask
-    plane_ids_ref,  # scalar prefetch: [N/bn, num_work] int32
+    mask_ref,       # scalar prefetch: [N/bn, num_work] int32 plane masks
     ktile_ids_ref,  # scalar prefetch: [N/bn, num_work] int32
-    a_ref,          # [bm, bk] activations (block of the scheduled K-tile)
-    plane_ref,      # [1, bk//32, bn] uint32 — the scheduled plane, packed
+    a_ref,          # [bm, bk] activations (block of the slot's K-tile)
+    planes_ref,     # [B-1, bk//32, bn] uint32 — the K-tile's plane stack
     signs_ref,      # [bk//32, bn] uint32 packed sign bits
     scale_ref,      # [1, bn] f32 per-channel scales
     out_ref,        # [bm, bn] f32
     seg_ref,        # VMEM scratch: [B-1, bm, bn] f32 segment accumulators
-    signf_ref,      # VMEM scratch: [bk, bn] f32 cached sign multiplier
-    last_kt_ref,    # SMEM scratch: [1] int32 K-tile the sign cache holds
     *,
     bits: int,
     num_work: int,
@@ -123,32 +131,27 @@ def sac_matmul_kernel(
     @pl.when(w == 0)
     def _init():
         seg_ref[...] = jnp.zeros_like(seg_ref)
-        last_kt_ref[0] = -1                # invalidate the sign cache
 
-    @pl.when(mask_ref[j, w] != 0)          # surviving work item (else idle)
-    def _mxu_pass():
-        b = plane_ids_ref[j, w]            # segment register select
-        kt = ktile_ids_ref[j, w]
+    live = mask_ref[j, w]                  # this slot's surviving planes
+
+    @pl.when(live != 0)                    # occupied K-tile (else idle)
+    def _ktile():
         a = a_ref[...].astype(jnp.float32)
+        bk = a.shape[1]
+        # sign multiplier in {-1, +1}: 1 - 2*bit, shared by every plane
+        signf = 1.0 - 2.0 * _unpack_words(signs_ref[...], bk).astype(
+            jnp.float32)
+        for b in range(bits - 1):
 
-        # k-major order makes consecutive items share the (K-tile, N-tile)
-        # sign block: unpack the {-1,+1} multiplier once per K-tile change,
-        # not once per plane item (j is fixed within a tile's work walk, so
-        # the K-tile id alone keys the cache).
-        @pl.when(kt != last_kt_ref[0])
-        def _refresh_sign_cache():
-            sign_bits = _unpack_words(signs_ref[...], a.shape[1])
-            # sign multiplier in {-1, +1}: 1 - 2*bit
-            signf_ref[...] = 1.0 - 2.0 * sign_bits.astype(jnp.float32)
-            last_kt_ref[0] = kt
-
-        plane = _unpack_words(plane_ref[0], a.shape[1]).astype(jnp.float32)
-        seg_ref[b] += jax.lax.dot_general(
-            a, plane * signf_ref[...],
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            precision=exact_dot_precision(a_ref.dtype),
-            preferred_element_type=jnp.float32,
-        )
+            @pl.when(((live >> b) & 1) != 0)
+            def _mxu_pass(b=b):
+                plane = _unpack_words(planes_ref[b], bk).astype(jnp.float32)
+                seg_ref[b] += jax.lax.dot_general(
+                    a, plane * signf,
+                    dimension_numbers=(((1,), (0,)), ((), ())),
+                    precision=exact_dot_precision(a_ref.dtype),
+                    preferred_element_type=jnp.float32,
+                )
 
     @pl.when(w == num_work - 1)
     def _rear_adder_tree():
@@ -177,15 +180,13 @@ def sac_matmul_pallas_call(
 ) -> jax.Array:
     """Raw pallas_call wrapper (shapes must already be tile-aligned).
 
-    ``mask`` is the per-slot survival mask, int32 [N/bn, num_work] — the
-    *runtime* half of the two-sided skip (docs/DESIGN.md §12).  ``None``
-    (the static weight-only walk) expands the schedule counts to the mask
-    the pre-skip guard ``w < counts[j]`` tested, so the masked kernel is
-    bit-for-bit the unmasked one.  An activation-intersected mask may
-    additionally drop real items whose activation K-slice is all zero;
-    surviving items keep their k-major slot positions, so per-segment f32
-    accumulation order — hence bit-exactness vs the planes oracle — is
-    preserved.
+    ``mask`` is the per-slot survival mask of planes, int32 [N/bn,
+    num_work] — the *runtime* half of the two-sided skip (docs/DESIGN.md
+    §12).  ``None`` runs the static weight-only walk, the schedule's own
+    ``plane_bits``.  An activation-intersected mask may additionally zero
+    slots whose activation K-slice is all zero; surviving slots keep their
+    positions, so per-segment f32 accumulation order — hence bit-exactness
+    vs the planes oracle — is preserved.
     """
     m, k = a.shape
     n = planes.shape[-1]
@@ -196,32 +197,28 @@ def sac_matmul_pallas_call(
     num_work = schedule.num_work
     grid = (m // bm, n // bn, num_work)
     if mask is None:
-        from repro.core.activation_occupancy import weight_only_mask
-        mask = weight_only_mask(schedule.counts, num_work)
-    assert mask.shape == schedule.plane_ids.shape, (
-        mask.shape, schedule.plane_ids.shape)
+        mask = schedule.plane_bits
+    assert mask.shape == schedule.ktile_ids.shape, (
+        mask.shape, schedule.ktile_ids.shape)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=2,
         grid=grid,
         # NB: with scalar prefetch, index maps receive the prefetch refs
-        # last; they *walk the schedule* — block indices come from the work
-        # lists, not from the grid coordinates.
+        # last; they *walk the schedule* — block indices come from the
+        # K-tile lists, not from the grid coordinates.
         in_specs=[
             pl.BlockSpec((bm, bk),
-                         lambda i, j, w, msk, pid, kid: (i, kid[j, w])),
-            pl.BlockSpec((1, bk // WORD, bn),
-                         lambda i, j, w, msk, pid, kid: (pid[j, w],
-                                                         kid[j, w], j)),
+                         lambda i, j, w, msk, kid: (i, kid[j, w])),
+            pl.BlockSpec((bits - 1, bk // WORD, bn),
+                         lambda i, j, w, msk, kid: (0, kid[j, w], j)),
             pl.BlockSpec((bk // WORD, bn),
-                         lambda i, j, w, msk, pid, kid: (kid[j, w], j)),
-            pl.BlockSpec((1, bn), lambda i, j, w, msk, pid, kid: (0, j)),
+                         lambda i, j, w, msk, kid: (kid[j, w], j)),
+            pl.BlockSpec((1, bn), lambda i, j, w, msk, kid: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn),
-                               lambda i, j, w, msk, pid, kid: (i, j)),
-        scratch_shapes=[pltpu.VMEM((bits - 1, bm, bn), jnp.float32),
-                        pltpu.VMEM((bk, bn), jnp.float32),
-                        pltpu.SMEM((1,), jnp.int32)],
+                               lambda i, j, w, msk, kid: (i, j)),
+        scratch_shapes=[pltpu.VMEM((bits - 1, bm, bn), jnp.float32)],
     )
     kernel = functools.partial(sac_matmul_kernel, bits=bits,
                                num_work=num_work)
@@ -231,5 +228,4 @@ def sac_matmul_pallas_call(
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
         name="sac_matmul",
-    )(mask.astype(jnp.int32), schedule.plane_ids, schedule.ktile_ids,
-      a, planes, signs, scale)
+    )(mask.astype(jnp.int32), schedule.ktile_ids, a, planes, signs, scale)

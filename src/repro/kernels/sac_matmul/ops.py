@@ -50,10 +50,10 @@ from repro.kernels.backend import interpret_mode
 from repro.kernels.sac_matmul.kernel import sac_matmul_pallas_call
 
 # Most rows in one M block of a matmul launch.  A grid step's cost is
-# mostly fixed (unpack one plane tile, pipeline the next), whatever its M,
-# so a prefill-sized launch walks its work list once per 1024 rows rather
-# than once per 256 (segment scratch at 1024 rows: 7 x 1024 x 128 f32 =
-# 3.7 MB of VMEM).  ``sac_conv2d`` keeps 256-row blocks.
+# largely fixed (stage one K-tile, pipeline the next), whatever its M, so a
+# prefill-sized launch walks its K-tile list once per 1024 rows rather than
+# once per 256 (segment scratch at 1024 rows: 7 x 1024 x 128 f32 = 3.7 MB
+# of VMEM).  ``sac_conv2d`` keeps 256-row blocks.
 MATMUL_BM = 1024
 
 
@@ -87,10 +87,10 @@ def sac_matmul_pallas(
     ``skip_activations=True`` arms the two-sided skip (docs/DESIGN.md §12):
     per-K-tile presence bits computed from the (padded) activations are
     intersected into the schedule walk via the kernel's survival mask, so
-    real work items whose activation K-slice is all zero never execute an
-    MXU pass.  Bit-exact against the unskipped walk — a dropped item would
-    have contributed exactly 0.0 to its f32 segment, and surviving items
-    keep their k-major order.  ``core.sac.sac_matmul`` gates this to the
+    occupied K-tiles whose activation slice is all zero never execute an
+    MXU pass.  Bit-exact against the unskipped walk — a dropped pass would
+    have contributed exactly 0.0 to its f32 segment, and surviving slots
+    keep their ascending K order.  ``core.sac.sac_matmul`` gates this to the
     decode-GEMV regime; this raw entry applies it at any M when asked.
 
     The kernel itself is strictly 2-D: stacked weights (LM layer stacks,
@@ -106,14 +106,12 @@ def sac_matmul_pallas(
             f"one slice first (expert banks: models.blocks."
             f"_dispatch_compute_kneaded, docs/DESIGN.md §13)")
     a, m, bm_eff = _pad_activations(a, kw, bm)
+    mask = kw.schedule.plane_bits
     if skip_activations:
         presence = activation_occupancy.ktile_presence(a, kw.ks)
         mask = activation_occupancy.work_mask(
-            kw.schedule.counts, kw.schedule.ktile_ids, presence)
-        activation_occupancy.record_skip(mask, kw.schedule.counts)
-    else:
-        mask = activation_occupancy.weight_only_mask(
-            kw.schedule.counts, kw.schedule.num_work)
+            mask, kw.schedule.ktile_ids, presence)
+        activation_occupancy.record_skip(mask, kw.schedule.plane_bits)
     out = _run(
         a, kw.planes, kw.signs, kw.scale, kw.schedule, mask,
         bits=kw.bits, ks=kw.ks, n_block=kw.n_block, bm=bm_eff,
@@ -183,7 +181,7 @@ def sac_matmul_pallas_sharded(
 
     With ``mesh=None``, executes the shards serially on the local device and
     concatenates — bit-identical output (each shard's N-tiles keep their
-    single-device work lists and k-major order), used as the parity oracle
+    single-device K-tile walks), used as the parity oracle
     and for host-side analysis without a mesh.
 
     ``partition="balanced"`` weights (docs/DESIGN.md §11) come out of the
@@ -191,8 +189,8 @@ def sac_matmul_pallas_sharded(
     whole N-tiles between shards.  The epilogue gathers the [m, n_block]
     output blocks back into original column order through ``skw.tile_slot``
     (``out_tile[j] = packed_tile[tile_slot[j]]``).  Each tile's value was
-    produced by the same work items in the same k-major order as on one
-    device, so the gathered output is bit-exact against the unsharded
+    produced by the same passes in the same K order as on one device, so
+    the gathered output is bit-exact against the unsharded
     kernel; for a mesh run the gather is the only cross-shard data movement
     the op introduces.
 
@@ -202,28 +200,26 @@ def sac_matmul_pallas_sharded(
     ``skip_activations=True``: the activation K-tile presence is computed
     *once* from the replicated (padded) activations — sharding is along N,
     so every shard sees the same presence bits — and intersected with each
-    shard's own work list into a per-shard survival mask [S, T, num_work],
-    sliced per device alongside the schedule arrays.  The balanced
-    partition's ``tile_slot`` gather epilogue is untouched: masking changes
-    which items a tile executes, never which shard/slot the tile lives in.
+    shard's own plane masks into a per-shard survival mask [S, T,
+    num_work], sliced per device alongside the schedule arrays.  The
+    balanced partition's ``tile_slot`` gather epilogue is untouched:
+    masking changes which passes a tile executes, never which shard/slot
+    the tile lives in.
     """
     interpret = interpret_mode()
     a, m, bm_eff = _pad_activations(a, skw, bm)
     # per-slot survival masks, one row of shards: [S, T, num_work]
-    base = jax.lax.broadcasted_iota(
-        jnp.int32, skw.ktile_ids.shape, 2) < skw.counts[:, :, None]
+    mask = skw.plane_bits
     if skip_activations:
         presence = activation_occupancy.ktile_presence(a, skw.ks)
-        mask = (base & (presence[skw.ktile_ids] != 0)).astype(jnp.int32)
-        activation_occupancy.record_skip(mask, skw.counts)
-    else:
-        mask = base.astype(jnp.int32)
+        mask = activation_occupancy.work_mask(mask, skw.ktile_ids, presence)
+        activation_occupancy.record_skip(mask, skw.plane_bits)
 
-    def one_shard(a_, planes, signs, scale, counts, pids, kids, mask_):
+    def one_shard(a_, planes, signs, scale, counts, kids, pbits, mask_):
         # inside shard_map every arg holds this device's slab with the
         # leading shard axis collapsed to extent 1
         sched = KneadedSchedule(
-            counts=counts[0], plane_ids=pids[0], ktile_ids=kids[0],
+            counts=counts[0], ktile_ids=kids[0], plane_bits=pbits[0],
             num_work=skw.num_work, total_work=skw.total_work,
             nk=skw.nk, n_tiles=skw.tiles_per_shard)
         return sac_matmul_pallas_call(
@@ -234,7 +230,7 @@ def sac_matmul_pallas_sharded(
     if mesh is None:
         outs = [one_shard(a, skw.planes[s:s + 1], skw.signs[s:s + 1],
                           skw.scale[s:s + 1], skw.counts[s:s + 1],
-                          skw.plane_ids[s:s + 1], skw.ktile_ids[s:s + 1],
+                          skw.ktile_ids[s:s + 1], skw.plane_bits[s:s + 1],
                           mask[s:s + 1])
                 for s in range(skw.num_shards)]
         out = jnp.concatenate(outs, axis=1)
@@ -244,7 +240,7 @@ def sac_matmul_pallas_sharded(
             one_shard, mesh=mesh, in_specs=(P(),) + sharded,
             out_specs=P(None, axis), check_vma=False,
         )(a, skw.planes, skw.signs, skw.scale, skw.counts,
-          skw.plane_ids, skw.ktile_ids, mask)
+          skw.ktile_ids, skw.plane_bits, mask)
     if skw.partition == "balanced":
         tiles = out.reshape(out.shape[0], -1, skw.n_block)
         out = jnp.take(tiles, skw.tile_slot, axis=1

@@ -6,6 +6,8 @@ parity (float/int/planes/pallas agreement) comes from the shared
 ``parity`` harness — the sweep below and the per-bit-width cases both run
 through it.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,6 +15,7 @@ import parity
 import pytest
 
 from repro.core import knead, quantize
+from repro.core.schedule import replay_schedule
 from repro.kernels.kneaded_gemm.ops import kneaded_gemm
 from repro.kernels.kneaded_gemm.ref import kneaded_gemm_ref, pack_int4, unpack_int4
 from repro.kernels.sac_matmul.ops import _pad_activations, sac_matmul_pallas
@@ -131,6 +134,28 @@ def test_sac_kernel_occupancy_skipping_exact():
     out = sac_matmul_pallas(a, kw, bm=8)
     np.testing.assert_allclose(np.asarray(out), np.asarray(sac_matmul_ref(a, kw)),
                                rtol=1e-5, atol=1e-4)
+
+
+def test_sac_kernel_runs_only_the_masked_planes():
+    """One grid step runs its K-tile's planes under the bits of the slot's
+    plane mask: clearing the bits of non-empty planes drops exactly those
+    passes, bit for bit as the pass-by-pass replay of the same masks."""
+    w, a = _wa(11, 8, 768, 256)
+    kw = knead(w, bits=8, ks=256, n_block=128)
+    sched = kw.schedule
+    pbits = np.asarray(sched.plane_bits).copy()
+    assert (pbits == 0x7F).all()     # dense: every plane of every K-tile
+    pbits[0, 1] &= ~(1 << 6)         # N-tile 0, K-tile 1: drop the top plane
+    pbits[1, 0] = 0b0000101          # N-tile 1, K-tile 0: planes 0 and 2 only
+    cut = dataclasses.replace(kw, schedule=dataclasses.replace(
+        sched, plane_bits=jnp.asarray(pbits),
+        total_work=int(np.bitwise_count(pbits).sum())))
+    out = np.asarray(sac_matmul_pallas(a, cut, bm=8))
+    np.testing.assert_array_equal(out, np.asarray(replay_schedule(a, cut)))
+    full = np.asarray(sac_matmul_pallas(a, kw, bm=8))
+    for j in range(2):               # both N-tiles lost real passes
+        cols = slice(j * 128, (j + 1) * 128)
+        assert not np.array_equal(out[:, cols], full[:, cols])
 
 
 @pytest.mark.parametrize("m,k,n", SHAPES)

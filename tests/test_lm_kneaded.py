@@ -67,7 +67,7 @@ def test_knead_params_stacks_scan_layers(smol):
             assert kw.planes.shape[0] == L
             assert kw.signs.shape[0] == L
             assert kw.schedule.counts.shape[0] == L
-            assert kw.schedule.plane_ids.shape == (
+            assert kw.schedule.plane_bits.shape == (
                 L, kw.schedule.n_tiles, kw.schedule.num_work)
             assert (kw.logical_k, kw.logical_n) == orig.shape[-2:]
     # embeddings/norms stay float (tied smollm has no unembed leaf)
@@ -78,7 +78,8 @@ def test_stacked_layer_schedules_independent(smol):
     """The stacked kneading invariant: layer l's planes/signs/scale and
     compacted schedule equal exactly ``knead_padded(w[l])``'s — per-layer
     schedules are built independently, and the work-dim padding to the
-    cross-layer max repeats each tile's last item."""
+    cross-layer max repeats each tile's last K-tile with an empty plane
+    mask."""
     cfg, _, params, _ = smol
     w = params["layers"]["attn"]["wq"]             # [L, K, N]
     stacked = knead_stacked(w, bits=8)
@@ -95,15 +96,16 @@ def test_stacked_layer_schedules_independent(smol):
             np.asarray(solo.schedule.counts))
         W = solo.schedule.num_work
         np.testing.assert_array_equal(
-            np.asarray(stacked.schedule.plane_ids[layer, :, :W]),
-            np.asarray(solo.schedule.plane_ids))
+            np.asarray(stacked.schedule.plane_bits[layer, :, :W]),
+            np.asarray(solo.schedule.plane_bits))
         np.testing.assert_array_equal(
             np.asarray(stacked.schedule.ktile_ids[layer, :, :W]),
             np.asarray(solo.schedule.ktile_ids))
-        # padding columns repeat the last item of each tile's list
-        pid = np.asarray(stacked.schedule.plane_ids[layer])
+        # padding columns repeat the last K-tile of each tile's list and
+        # run no plane
+        pbits = np.asarray(stacked.schedule.plane_bits[layer])
         kid = np.asarray(stacked.schedule.ktile_ids[layer])
-        assert (pid[:, W:] == pid[:, W - 1:W]).all()
+        assert (pbits[:, W:] == 0).all()
         assert (kid[:, W:] == kid[:, W - 1:W]).all()
     assert stacked.schedule.num_work == max(
         knead_padded(w[i], bits=8).schedule.num_work
@@ -195,7 +197,7 @@ def test_serving_engine_activation_skip_bit_exact(smol, impl):
     ``activation_skip=True`` is BIT-IDENTICAL to skip-off on both the
     planes oracle and the pallas kernel — the runtime activation-occupancy
     intersection only drops tile-dots whose contribution is exactly 0 and
-    preserves the k-major accumulation order of the survivors."""
+    preserves the ascending K accumulation order of the survivors."""
     from repro.core import activation_occupancy
 
     cfg, _, params, _ = smol

@@ -58,7 +58,8 @@ def moe():
 def test_knead_stacked_expert_bank_structure():
     """[L, E, K, N] kneads to a bank whose (l, e) slice equals the
     independent 2-D knead of w[l, e] exactly, with the work dim padded to
-    the cross-slice max by repeating each tile's last item."""
+    the cross-slice max by repeating each tile's last K-tile with an empty
+    plane mask."""
     key = jax.random.split(jax.random.PRNGKey(3), 2)
     w = jax.random.normal(key[0], (2, 3, 96, 128)) * 0.05
     keep = jax.random.uniform(key[1], w.shape) >= 0.7
@@ -86,18 +87,20 @@ def test_knead_stacked_expert_bank_structure():
                 np.asarray(solo.schedule.counts))
             width = solo.schedule.num_work
             np.testing.assert_array_equal(
-                np.asarray(bank.schedule.plane_ids[l, e, :, :width]),
-                np.asarray(solo.schedule.plane_ids))
+                np.asarray(bank.schedule.plane_bits[l, e, :, :width]),
+                np.asarray(solo.schedule.plane_bits))
             np.testing.assert_array_equal(
                 np.asarray(bank.schedule.ktile_ids[l, e, :, :width]),
                 np.asarray(solo.schedule.ktile_ids))
-            pid = np.asarray(bank.schedule.plane_ids[l, e])
-            assert (pid[:, width:] == pid[:, width - 1:width]).all()
+            kid = np.asarray(bank.schedule.ktile_ids[l, e])
+            assert (kid[:, width:] == kid[:, width - 1:width]).all()
+            assert (np.asarray(bank.schedule.plane_bits[l, e, :, width:])
+                    == 0).all()
 
 
 def test_expert_bank_work_table():
-    """work_table() sums each slice's compacted counts — a [L, E] static
-    load map whose total equals the schedule's total_work."""
+    """work_table() sums each slice's MXU passes — a [L, E] static load
+    map whose total equals the schedule's total_work."""
     w = jax.random.normal(jax.random.PRNGKey(4), (2, 4, 64, 128)) * 0.05
     bank = knead_stacked(w, bits=8)
     table = bank.work_table()

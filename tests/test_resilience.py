@@ -364,7 +364,7 @@ def test_kneaded_checksums_detect_corruption():
     kw = knead_padded(w, bits=4, ks=16, n_block=16)
     assert kw.verify() == ()
     for field in ("occupancy", "planes", "schedule.counts",
-                  "schedule.plane_ids"):
+                  "schedule.plane_bits"):
         bad = corrupt_kneaded(kw, field, flat_index=1)
         assert bad.verify() == (field,)
         with pytest.raises(KneadedIntegrityError, match=field):

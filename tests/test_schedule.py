@@ -3,7 +3,7 @@
 The KneadedSchedule is *the* execution plan of the Pallas kernel — these
 tests pin (a) its structural invariants against the occupancy map it was
 built from, (b) bit-exact output parity of the schedule-driven kernel vs the
-dense planes oracle vs the item-by-item ``replay_schedule`` spec across
+dense planes oracle vs the pass-by-pass ``replay_schedule`` spec across
 random shapes and sparsities, (c) the all-empty / all-dense occupancy
 extremes the grid must survive (num_work floor of 1; zero dispatched work),
 and (d) the balanced shard partitioner's invariants (docs/DESIGN.md §11):
@@ -21,8 +21,9 @@ from _hypothesis_compat import given, settings, st
 from repro.core import activation_occupancy as actocc
 from repro.core import knead, sac_matmul
 from repro.core.bitplanes import pack_presence, popcount, unpack_presence
-from repro.core.kneading import knead_padded
-from repro.core.schedule import build_schedule, replay_schedule, shard_schedule
+from repro.core.kneading import knead_padded, knead_stacked
+from repro.core.schedule import (build_schedule, replay_schedule,
+                                 shard_schedule, shard_stacked_schedule)
 from repro.kernels.sac_matmul.ops import (sac_matmul_pallas,
                                           sac_matmul_pallas_sharded)
 
@@ -41,29 +42,32 @@ def _sparse_w(seed, k, n, sparsity):
 
 # ----------------------------------------------------------- structure
 def test_schedule_structure_matches_occupancy():
-    """Schedule items enumerate exactly the nonzero occupancy entries,
-    k-major per N-tile, padded by repeating the last real item."""
+    """Schedule slots enumerate exactly the occupied K-tiles per N-tile,
+    ascending, each with the mask of its nonzero planes, padded by
+    repeating the last K-tile with an empty mask."""
     rng = np.random.default_rng(0)
     occ = (rng.random((7, 5, 3)) < 0.3).astype(np.int32)
     sched = build_schedule(occ)
     assert sched.total_work == int(occ.sum())
     assert sched.nk == 5 and sched.n_tiles == 3
-    assert sched.num_work == max(1, int(occ.sum(axis=(0, 1)).max()))
+    occupied = occ.any(axis=0)                            # [NK, NN]
+    assert sched.num_work == max(1, int(occupied.sum(axis=0).max()))
     counts = np.asarray(sched.counts)
-    pid, kid = np.asarray(sched.plane_ids), np.asarray(sched.ktile_ids)
+    pbits, kid = np.asarray(sched.plane_bits), np.asarray(sched.ktile_ids)
+    assert int(np.bitwise_count(pbits).sum()) == sched.total_work
     for j in range(3):
         c = int(counts[j])
-        assert c == int(occ[:, :, j].sum())
-        items = list(zip(kid[j, :c].tolist(), pid[j, :c].tolist()))
-        # exactly the nonzero (k_tile, plane) pairs, sorted k-major
-        expect = sorted((k, b) for b in range(7) for k in range(5)
-                        if occ[b, k, j])
-        assert items == expect
-        if c:  # padding repeats the last real item (no new blocks fetched)
-            assert (pid[j, c:] == pid[j, c - 1]).all()
+        assert c == int(occupied[:, j].sum())
+        # exactly the occupied K-tiles, ascending
+        assert kid[j, :c].tolist() == np.flatnonzero(occupied[:, j]).tolist()
+        for w in range(c):       # each slot's mask names its nonzero planes
+            planes = [b for b in range(7) if (pbits[j, w] >> b) & 1]
+            assert planes == np.flatnonzero(occ[:, kid[j, w], j]).tolist()
+        assert (pbits[j, c:] == 0).all()       # padding runs no plane
+        if c:  # padding repeats the last K-tile (no new blocks fetched)
             assert (kid[j, c:] == kid[j, c - 1]).all()
         else:
-            assert (pid[j] == 0).all() and (kid[j] == 0).all()
+            assert (kid[j] == 0).all()
 
 
 def test_pack_presence_roundtrip():
@@ -139,18 +143,113 @@ def test_schedule_all_dense():
     a = jax.random.normal(jax.random.PRNGKey(8), (8, 512))
     kw = knead(w, bits=8, ks=256, n_block=128)
     assert kw.schedule.total_work == kw.schedule.dense_work(kw.bits)
-    assert kw.schedule.num_work == (kw.bits - 1) * (kw.k // kw.ks)
+    assert kw.schedule.num_work == kw.k // kw.ks
+    assert kw.schedule.planes_per_step == kw.bits - 1
     out_planes = sac_matmul(a, kw, impl="planes")
     out_pallas = sac_matmul(a, kw, impl="pallas")
     np.testing.assert_array_equal(np.asarray(out_pallas),
                                   np.asarray(out_planes))
 
 
+# ------------------------------------------------------- the K-tile walk
+#
+# One grid step per occupied K-tile, running that tile's present planes in
+# its body.  The weight below has planes empty inside occupied K-tiles (a
+# block 100x smaller than its column's scale has only low planes) and whole
+# K-tiles empty in some N-tiles, so the walk's step count, its plane masks
+# and its executed passes all differ from the dense walk's.
+
+def _ktile_sparse_w():
+    """[1024, 384] (4 K-tiles x 3 N-tiles): N-tile 0 with K-tile 3 empty;
+    N-tile 1 with K-tile 1 empty and K-tile 2 small; N-tile 2 with K-tiles
+    0 and 3 empty and K-tile 1 small."""
+    w = np.array(_sparse_w(71, 1024, 384, 0.0))
+    w[768:1024, 0:128] = 0.0
+    w[256:512, 128:256] = 0.0
+    w[512:768, 128:256] *= 0.01
+    w[0:256, 256:384] = 0.0
+    w[768:1024, 256:384] = 0.0
+    w[256:512, 256:384] *= 0.01
+    return jnp.asarray(w)
+
+
+@pytest.mark.parametrize("m", [1, 8, 64, 1024])
+def test_ktile_walk_sparse_bit_exact(m):
+    """pallas == planes bitwise at decode and prefill M; ``counts`` is the
+    occupied K-tiles per N-tile; executed passes (``total_work``, and
+    ``skip_stats()`` with the activation skip on) are the occupancy
+    nonzeros."""
+    kw = knead(_ktile_sparse_w(), bits=8, ks=256, n_block=128)
+    occ = np.asarray(kw.occupancy_map())                  # [7, 4, 3]
+    occupied = occ.any(axis=0)
+    assert not occ[:, occupied].all()          # empty planes in live K-tiles
+    assert not occupied.all()                  # wholly empty K-tiles
+    np.testing.assert_array_equal(np.asarray(kw.schedule.counts),
+                                  occupied.sum(axis=0))
+    assert np.asarray(kw.schedule.counts).tolist() == [3, 3, 2]
+    assert kw.schedule.total_work == int(occ.sum())
+    a = jax.random.normal(jax.random.PRNGKey(m), (m, 1024))
+    actocc.reset_skip_stats()
+    on = sac_matmul_pallas(a, kw, skip_activations=True)
+    jax.block_until_ready(on)
+    stats = actocc.skip_stats()
+    assert stats["executed_tile_dots"] == stats["weight_tile_dots"] \
+        == int(occ.sum())
+    out = sac_matmul(a, kw, impl="pallas")
+    ref = sac_matmul(a, kw, impl="planes")
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    np.testing.assert_array_equal(np.asarray(on), np.asarray(out))
+
+
+@pytest.mark.parametrize("case", ["dense", "sparse"])
+def test_planes_per_step(case):
+    """MXU passes per executed grid step: all seven planes on every
+    occupied K-tile of a dense random 8-bit weight, fewer where the
+    weight-side skip empties planes."""
+    w = (_sparse_w(72, 1024, 384, 0.0) if case == "dense"
+         else _ktile_sparse_w())
+    sched = knead(w, bits=8, ks=256, n_block=128).schedule
+    steps = int(np.asarray(sched.counts).sum())
+    assert sched.planes_per_step == sched.total_work / steps
+    if case == "dense":
+        assert sched.planes_per_step == 7.0
+        assert steps == 4 * 3
+    else:
+        assert 1.0 < sched.planes_per_step < 7.0
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["2d", "stacked"])
+@pytest.mark.parametrize("partition", ["contiguous", "balanced"])
+def test_ktile_walk_sharded_replays_unsharded(partition, stacked):
+    """Sharded walks (both partitions), one layer of a stacked weight, and
+    a stacked weight sharded per layer, all replay the unsharded kernel on
+    the sparse K-tile weight bit-exactly."""
+    w = _ktile_sparse_w()
+    a = jax.random.normal(jax.random.PRNGKey(73), (8, 1024))
+    kw = knead(w, bits=8, ks=256, n_block=128)
+    ref = np.asarray(sac_matmul_pallas(a, kw, bm=8))
+    if stacked:
+        # layer 1 is dense, so the stack pads layer 0's walks to its width
+        stack = knead_stacked(jnp.stack([w, _sparse_w(74, 1024, 384, 0.0)]),
+                              bits=8, ks=256, n_block=128)
+        assert stack.schedule.num_work > kw.schedule.num_work
+        layer0 = jax.tree.map(lambda x: x[0], stack)
+        np.testing.assert_array_equal(
+            np.asarray(sac_matmul_pallas(a, layer0, bm=8)), ref)
+        skw = jax.tree.map(lambda x: x[0],
+                           shard_stacked_schedule(stack, 2,
+                                                  partition=partition))
+    else:
+        skw = shard_schedule(kw, 2, partition=partition)
+    out = sac_matmul_pallas_sharded(a, skw, bm=8)[:, :kw.n]
+    np.testing.assert_array_equal(np.asarray(out), ref)
+
+
 # ------------------------------- balanced partitioner (docs/DESIGN.md §11)
 #
 # Load properties run on crafted occupancy maps (``with_occupancy`` installs
-# them over an all-zero weight — shard accounting reads counts only, so no
-# execution is needed); bit-exactness properties run real sparse weights
+# them over an all-zero weight — shard accounting reads the schedule only, so
+# no execution is needed); bit-exactness properties run real sparse weights
 # through the gathered sharded kernel against the unsharded one.
 
 def _occ_kw(occ):
@@ -210,7 +309,7 @@ def test_balanced_partition_properties_smoke():
 
 def test_balanced_never_worse_than_optimal_contiguous():
     """The greedy LPT packing alone can LOSE to a contiguous layout that
-    happens to be optimal (LPT is a 4/3-approximation): per-tile counts
+    happens to be optimal (LPT is a 4/3-approximation): per-tile passes
     [3,3,0,2,2,2] at 2 shards pack greedily to max 7 while the contiguous
     slabs hit the optimal 6.  Balanced mode must take the better of the
     two — pinned here so the property above can never regress."""
@@ -310,17 +409,20 @@ def _check_intersection_invariants(kw, a):
     """Subset + packed-popcount agreement for one (weight, activation)."""
     pres = actocc.ktile_presence(a, kw.ks)
     sched = kw.schedule
-    mask = np.asarray(actocc.work_mask(sched.counts, sched.ktile_ids, pres))
-    base = np.asarray(actocc.weight_only_mask(sched.counts, sched.num_work))
-    # intersected work ⊆ weight-only work, slot by slot
-    assert ((mask == 0) | (base == 1)).all()
-    assert mask.sum() <= base.sum() == sched.total_work
+    mask = np.asarray(actocc.work_mask(sched.plane_bits, sched.ktile_ids,
+                                       pres))
+    base = np.asarray(sched.plane_bits)          # the weight-only walk
+    passes = np.bitwise_count(mask)
+    # intersected work ⊆ weight-only work, slot by slot and plane by plane
+    assert ((mask & ~base) == 0).all()
+    assert passes.sum() <= np.bitwise_count(base).sum() == sched.total_work
+    assert int(actocc.mask_passes(jnp.asarray(mask))) == passes.sum()
     # the packed-word view of the same intersection counts the same work
     inter = actocc.intersect_packed_presence(kw.occupancy, pres)
-    assert int(np.asarray(popcount(inter)).sum()) == int(mask.sum())
+    assert int(np.asarray(popcount(inter)).sum()) == int(passes.sum())
     # per N-tile too, not just in aggregate
     per_tile = np.asarray(popcount(inter)).sum(axis=(0, 1))
-    np.testing.assert_array_equal(per_tile, mask.sum(axis=1))
+    np.testing.assert_array_equal(per_tile, passes.sum(axis=1))
     return pres, mask
 
 
@@ -366,7 +468,7 @@ def test_act_skip_work_conservation_smoke():
     kw = knead(_sparse_w(11, 1024, 128, 0.5), bits=8)
     a = _gappy_activation(17, 1, 1024, 256, 0.5)
     pres, mask = _check_intersection_invariants(kw, a)
-    assert mask.sum() < kw.schedule.total_work     # really dropped items
+    assert np.bitwise_count(mask).sum() < kw.schedule.total_work  # dropped
     full = replay_schedule(a, kw)
     skipped = replay_schedule(a, kw, act_presence=pres)
     np.testing.assert_array_equal(np.asarray(skipped), np.asarray(full))
@@ -374,10 +476,10 @@ def test_act_skip_work_conservation_smoke():
 
 @pytest.mark.parametrize("case", ["all_zero", "all_dense", "single_hot"])
 def test_act_skip_activation_extremes(case):
-    """Activation extremes: an all-zero activation drops EVERY item (output
+    """Activation extremes: an all-zero activation drops EVERY pass (output
     exactly zero), a fully-dense one drops none (mask == weight-only mask),
-    and a single-hot one keeps exactly the one tile's items — all bit-exact
-    against the unskipped kernel and the planes oracle."""
+    and a single-hot one keeps exactly the one K-tile's passes — all
+    bit-exact against the unskipped kernel and the planes oracle."""
     kw = knead(_sparse_w(31, 1024, 256, 0.5), bits=8)
     sched = kw.schedule
     rng = np.random.default_rng(32)
@@ -388,19 +490,19 @@ def test_act_skip_activation_extremes(case):
         a[:, 256:512] = rng.normal(size=(2, 256)).astype(np.float32)
     a = jnp.asarray(a)
     pres = actocc.ktile_presence(a, kw.ks)
-    mask = np.asarray(actocc.work_mask(sched.counts, sched.ktile_ids, pres))
+    mask = np.asarray(actocc.work_mask(sched.plane_bits, sched.ktile_ids,
+                                       pres))
     counts = np.asarray(sched.counts)
     kids = np.asarray(sched.ktile_ids)
+    pbits = np.asarray(sched.plane_bits)
     if case == "all_zero":
         assert mask.sum() == 0
     elif case == "all_dense":
-        np.testing.assert_array_equal(
-            mask, np.asarray(actocc.weight_only_mask(sched.counts,
-                                                     sched.num_work)))
+        np.testing.assert_array_equal(mask, pbits)
     else:
-        expect = sum(int((kids[j, :counts[j]] == 1).sum())
-                     for j in range(sched.n_tiles))
-        assert mask.sum() == expect > 0
+        expect = sum(int(np.bitwise_count(pbits[j, :counts[j]][
+            kids[j, :counts[j]] == 1]).sum()) for j in range(sched.n_tiles))
+        assert np.bitwise_count(mask).sum() == expect > 0
     on = sac_matmul_pallas(a, kw, bm=8, skip_activations=True)
     off = sac_matmul_pallas(a, kw, bm=8)
     ref = sac_matmul(a, kw, impl="planes")

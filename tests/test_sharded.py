@@ -66,8 +66,8 @@ def test_shard_schedule_splits_work_lists():
         tiles = slice(2 * s, 2 * s + 2)
         np.testing.assert_array_equal(np.asarray(sub.counts),
                                       np.asarray(sched.counts)[tiles])
-        np.testing.assert_array_equal(np.asarray(sub.plane_ids),
-                                      np.asarray(sched.plane_ids)[tiles])
+        np.testing.assert_array_equal(np.asarray(sub.plane_bits),
+                                      np.asarray(sched.plane_bits)[tiles])
         np.testing.assert_array_equal(np.asarray(sub.ktile_ids),
                                       np.asarray(sched.ktile_ids)[tiles])
         assert sub.total_work == int(occ[:, :, tiles].sum())
@@ -138,8 +138,8 @@ def test_shard_schedule_structure_from_occupancy():
         np.testing.assert_array_equal(np.asarray(sub.counts),
                                       np.asarray(local.counts))
         w = local.num_work          # sub pads the work dim to the global max
-        np.testing.assert_array_equal(np.asarray(sub.plane_ids[:, :w]),
-                                      np.asarray(local.plane_ids))
+        np.testing.assert_array_equal(np.asarray(sub.plane_bits[:, :w]),
+                                      np.asarray(local.plane_bits))
         np.testing.assert_array_equal(np.asarray(sub.ktile_ids[:, :w]),
                                       np.asarray(local.ktile_ids))
 

@@ -78,8 +78,8 @@ def test_shard_stacked_matches_per_layer_shard():
                                       np.asarray(solo.counts))
         width = solo.num_work      # stacked pads work to the cross-layer max
         np.testing.assert_array_equal(
-            np.asarray(ssk.plane_ids[layer][..., :width]),
-            np.asarray(solo.plane_ids))
+            np.asarray(ssk.plane_bits[layer][..., :width]),
+            np.asarray(solo.plane_bits))
         np.testing.assert_array_equal(
             np.asarray(ssk.ktile_ids[layer][..., :width]),
             np.asarray(solo.ktile_ids))
@@ -323,7 +323,7 @@ def test_sharded_lm_engine_activation_skip_bit_exact(
     with ``activation_skip=True`` must stay bit-identical to the clean
     single-device *skip-off* oracle — presence is computed once from the
     full decode row (shard-invariant under N-sharding), the survival mask
-    is sliced per shard, and surviving tile-dots keep the k-major order, so
+    is sliced per shard, and surviving tile-dots keep their ascending K order, so
     neither the mask intersection nor the balanced permutation epilogue may
     move a single bit of the prefill logits or the 32-token generation."""
     oracle_prefix, oracle_meta = oracle_run

@@ -55,14 +55,15 @@ def _sds(shape, dtype, sharding):
 
 def _compile_sac(one_chip, m, k, n, bm, a_dtype):
     """Compile the SAC kernel for [m, k] x kneaded [k, n] with a dense
-    schedule (every (plane, K-tile) item present — the largest walk)."""
+    schedule (every K-tile occupied, every plane present — the largest
+    walk)."""
     nk, n_tiles = k // KS, n // N_BLOCK
-    num_work = (BITS - 1) * nk
+    num_work = nk
 
-    def run(a, planes, signs, scale, counts, pids, kids, mask):
-        sched = KneadedSchedule(counts=counts, plane_ids=pids, ktile_ids=kids,
-                                num_work=num_work,
-                                total_work=num_work * n_tiles,
+    def run(a, planes, signs, scale, counts, kids, pbits, mask):
+        sched = KneadedSchedule(counts=counts, ktile_ids=kids,
+                                plane_bits=pbits, num_work=num_work,
+                                total_work=(BITS - 1) * nk * n_tiles,
                                 nk=nk, n_tiles=n_tiles)
         return sac_matmul_pallas_call(a, planes, signs, scale, sched,
                                       bits=BITS, bm=bm, bn=N_BLOCK, bk=KS,
@@ -116,6 +117,14 @@ def test_sac_kernel_compiles_vgg16_conv(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_sac_kernel_compiles_vgg16_fc1(one_chip):
+    """VGG-16 fc1 as served: K = 7*7*512 = 25088 -> N 4096, f32
+    activations (``HIGHEST`` dots) at the 8-row M block."""
+    compiled = _compile_sac(one_chip, 8, 25088, 4096, bm=8,
+                            a_dtype=jnp.float32)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_kneaded_gemm_int8_compiles(one_chip):
     m, k, n = 256, 1024, 2560
     args = (_sds((m, k), jnp.bfloat16, one_chip),
@@ -136,12 +145,12 @@ def test_kernels_keep_their_names_inside_jitted_wrappers(one_chip):
 
     m, k, n = 8, 1024, 1024
     nk, n_tiles = k // KS, n // N_BLOCK
-    num_work = (BITS - 1) * nk
+    num_work = nk
 
-    def sac_step(a, planes, signs, scale, counts, pids, kids, mask):
-        sched = KneadedSchedule(counts=counts, plane_ids=pids, ktile_ids=kids,
-                                num_work=num_work,
-                                total_work=num_work * n_tiles,
+    def sac_step(a, planes, signs, scale, counts, kids, pbits, mask):
+        sched = KneadedSchedule(counts=counts, ktile_ids=kids,
+                                plane_bits=pbits, num_work=num_work,
+                                total_work=(BITS - 1) * nk * n_tiles,
                                 nk=nk, n_tiles=n_tiles)
         return jnp.tanh(sac_ops._run(a, planes, signs, scale, sched, mask,
                                      bits=BITS, ks=KS, n_block=N_BLOCK, bm=8,
