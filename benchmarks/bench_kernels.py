@@ -188,96 +188,6 @@ def alexnet_sweep(bits: int = 8, ks: int = 256,
         f"tile_dots={met['executed_tile_dots']}/{met['dense_tile_dots']} "
         f"skip={100 * met['tile_dot_skip_frac']:.1f}% "
         f"(block-pruned at the kernel's ks x n_block skip granularity)", met))
-    rows += _act_skip_rows(params, bits=bits, ks=ks)
-    return rows
-
-
-def _relu_sparse_trace(seed: int, k: int, ks: int,
-                       dead_frac: float = 0.5) -> jax.Array:
-    """A deterministic decode-GEMV activation row with ReLU + dead-channel
-    structure: elementwise ReLU sparsity alone (~50% zeros) never empties a
-    ``ks``-wide K-tile, so tile-granular runtime skip sees nothing — the
-    payoff comes from *dead channels* (whole feature maps stuck at zero in
-    trained ReLU nets), modeled here by zeroing ``dead_frac`` of the K-tiles
-    wholesale."""
-    kk = jax.random.split(jax.random.PRNGKey(seed), 2)
-    a = jax.nn.relu(jax.random.normal(kk[0], (1, k)))
-    nk = k // ks
-    alive = (jax.random.uniform(kk[1], (nk,)) >= dead_frac).astype(a.dtype)
-    return (a.reshape(1, nk, ks) * alive[None, :, None]).reshape(1, k)
-
-
-def _act_skip_metrics(kw, a: jax.Array) -> Dict[str, float]:
-    """Two-sided (weight x activation) tile-dot accounting for one decode
-    row.  NOT :func:`_schedule_metrics`: with the runtime intersection the
-    executed count sits *below* the occupancy nonzeros, which is the point."""
-    from repro.core import activation_occupancy as actocc
-
-    pres = actocc.ktile_presence(a, kw.ks)
-    mask = actocc.work_mask(kw.schedule.plane_bits, kw.schedule.ktile_ids,
-                            pres)
-    executed = int(np.asarray(actocc.mask_passes(mask)))
-    weight_only = int(kw.schedule.total_work)
-    dense = int(kw.schedule.dense_work(kw.bits))
-    return {
-        "executed_tile_dots": executed,
-        "weight_tile_dots": weight_only,
-        "dense_tile_dots": dense,
-        "act_skip_frac": 1.0 - executed / max(1, weight_only),
-        "tile_dot_skip_frac": 1.0 - executed / max(1, dense),
-    }
-
-
-def _act_skip_rows(params, bits: int, ks: int) -> List[BenchRow]:
-    """Activation-intersected rows on trained AlexNet fc layers
-    (docs/DESIGN.md §12).  Dense trained weights occupy every tile, so the
-    weight-only rows above report skip=0.0% on the fc layers — the honesty
-    gap of one-sided kneading.  Against a ReLU-sparse decode trace the
-    runtime intersection drops the dead channels' tile-dots, so these rows
-    report ``tile_dot_skip_frac > 0`` on the SAME dense weights (asserted,
-    plus strict executed < weight-only — and bit-exactness on the row that
-    runs the masked kernel); ``act_skip_frac`` joins the higher-is-better
-    CI gate."""
-    from repro.core.sac import sac_matmul
-    from repro.models import cnn
-
-    rows: List[BenchRow] = []
-    wmats = cnn.weight_matrices(params)
-    # (row suffix, weight, run the masked kernel?) — fc10 is small enough
-    # to pay interpret-mode kernel parity at bench time; fc8 rows are
-    # accounting-only (the test wall owns their bit-exactness)
-    cases = (("fc8_actskip", jnp.asarray(wmats["fc8"]), 31, False),
-             ("fc8_blocksparse50_actskip", _blocksparse_fc8(params, ks),
-              31, False),
-             # seed 30 leaves fc10's 4 K-tiles half alive — a *partial*
-             # mask, so the kernel row exercises mixed survive/drop steps
-             ("fc10_actskip", jnp.asarray(wmats["fc10"]), 30, True))
-    for suffix, w, seed, run_kernel in cases:
-        kw = knead_padded(w, bits=bits, ks=ks)
-        a = _relu_sparse_trace(seed, kw.k, ks)
-        met = _act_skip_metrics(kw, a)
-        # the two-sided accounting must actually bite on dense weights
-        assert met["executed_tile_dots"] < met["weight_tile_dots"], \
-            (suffix, met)
-        assert met["tile_dot_skip_frac"] > 0.0, (suffix, met)
-        derived = (
-            f"tile_dots={met['executed_tile_dots']}"
-            f"/{met['weight_tile_dots']}(w-only)"
-            f"/{met['dense_tile_dots']}(dense) "
-            f"act_skip={100 * met['act_skip_frac']:.1f}%")
-        if run_kernel:
-            us, out = timed(
-                lambda: sac_matmul_pallas(a, kw, skip_activations=True),
-                repeats=1)
-            ref = np.asarray(sac_matmul(a, kw, impl="planes"))
-            err = float(np.max(np.abs(
-                np.asarray(out)[:, :kw.logical_n] - ref)))
-            assert err == 0.0, (suffix, err)     # masked walk is bit-exact
-            met["max_err"] = err
-            derived += f" max_err={err:.1e}"
-        else:
-            us = 0.0
-        rows.append((f"alexnet_sweep/{suffix}", us, derived, met))
     return rows
 
 
@@ -358,35 +268,6 @@ def decode_sweep(quick: bool) -> List[BenchRow]:
             f"tile_dots={met['executed_tile_dots']}/{met['dense_tile_dots']} "
             f"max_err={err:.1e}", met))
 
-    # activation-skip decode row (docs/DESIGN.md §12): an LM-projection-
-    # sized kneaded weight driven by a ReLU-sparse single-token trace
-    # through the masked kernel walk — executed tile-dots drop strictly
-    # below the weight-only schedule at zero error (both asserted;
-    # act_skip_frac joins the higher-is-better CI gate).  Fixed at
-    # K=1024 even under --quick: the quick shapes have a single K-tile,
-    # where tile-granular skip is all-or-nothing
-    from repro.core.sac import sac_matmul
-
-    k, n = 1024, 512
-    w = jax.random.normal(jax.random.PRNGKey(11), (k, n)) * 0.02
-    kw = knead(w, bits=8, ks=256, n_block=128)
-    a = _relu_sparse_trace(32, k, 256)
-    met = _act_skip_metrics(kw, a)
-    us, out = timed(lambda: sac_matmul_pallas(a, kw, skip_activations=True),
-                    repeats=1)
-    err = float(np.max(np.abs(np.asarray(out)
-                              - np.asarray(sac_matmul(a, kw,
-                                                      impl="planes")))))
-    assert err == 0.0, err
-    assert met["executed_tile_dots"] < met["weight_tile_dots"], met
-    met["max_err"] = err
-    met["tokens_per_s"] = 1 / (us * 1e-6)        # wall clock: not gated
-    rows.append((
-        "decode_sweep/gemv_b1_actskip", us,
-        f"tok_s={met['tokens_per_s']:.1f} "
-        f"tile_dots={met['executed_tile_dots']}"
-        f"/{met['weight_tile_dots']}(w-only) "
-        f"act_skip={100 * met['act_skip_frac']:.1f}% max_err={err:.1e}", met))
     return rows
 
 
@@ -490,24 +371,20 @@ def moe_decode_sweep(quick: bool) -> List[BenchRow]:
     [E, K, N]) and driven through the routed per-expert decode-GEMV path
     (``models.blocks._dispatch_compute_kneaded``) on a HANDCRAFTED skewed
     routing trace — deterministic token->expert assignments that leave half
-    the experts without a single routed token.  The two-sided skip then
-    turns routing sparsity into skipped MXU passes: an expert with no
-    routed tokens gathers only the zero pad row, its activation presence is
-    all-zero, and its entire schedule walk is masked off.
+    the experts without a single routed token.  Every local expert still
+    walks its whole schedule (an idle expert multiplies the zero pad row).
 
-    Gated metrics (CI): ``executed_tile_dots`` (runtime-masked passes,
-    asserted STRICTLY below the dense expert slab's tile-dot count — the
-    ISSUE acceptance), ``expert_imbalance`` (static work-table max/mean —
+    Gated metrics (CI): ``executed_tile_dots`` (the bank's static
+    weight-side passes, asserted STRICTLY below the dense expert slab's
+    tile-dot count), ``expert_imbalance`` (static work-table max/mean —
     the load-skew signal expert placement has to live with), and
     ``max_err`` — the emulated expert-parallel run (per-shard expert slices
     dispatched at their global offsets, partials summed like the mesh
     psum) against the all-experts-local oracle, asserted == 0.0 at bench
-    time for EP ∈ {2, 4}.  Reported honestly: the derived string names how
-    many experts the trace leaves active — a capped trace *overstates*
-    skip on traffic that actually spreads across all experts.
+    time for EP ∈ {2, 4}.  The derived string names how many experts the
+    trace leaves active.
     """
     from repro.configs.base import ModelConfig
-    from repro.core import activation_occupancy
     from repro.core.kneading import knead_stacked
     from repro.models import blocks
 
@@ -516,7 +393,7 @@ def moe_decode_sweep(quick: bool) -> List[BenchRow]:
     f = 256 if quick else 512
     cfg = ModelConfig(name="bench-moe", family="moe", num_experts=e,
                       top_k=2, moe_dff=f, d_model=k, activation="gelu",
-                      impl="pallas", activation_skip=True)
+                      impl="pallas")
     wi = jax.random.normal(jax.random.PRNGKey(31), (e, k, f)) * 0.02
     wo = jax.random.normal(jax.random.PRNGKey(32), (e, f, k)) * 0.02
     # skewed static occupancy: expert i keeps ~(e - i)/e of its N-tiles
@@ -529,6 +406,7 @@ def moe_decode_sweep(quick: bool) -> List[BenchRow]:
     kwo = knead_stacked(wo, bits=bits)
     table = kwi.work_table() + kwo.work_table()          # static [E] load
     expert_imbalance = float(table.max() / max(table.mean(), 1e-9))
+    executed = int(table.sum())          # every local expert walks its bank
     dense = e * (kwi.schedule.dense_work(bits)
                  + kwo.schedule.dense_work(bits))
 
@@ -547,27 +425,14 @@ def moe_decode_sweep(quick: bool) -> List[BenchRow]:
     rows: List[BenchRow] = []
     for batch, eids in traces.items():
         active = int(np.unique(np.asarray(eids)).size)
-        if active < e:
-            # bench honesty (satellite): a capped trace inflates skip
-            print(f"[moe_decode_sweep] b{batch}: routing trace caps "
-                  f"active experts at {active}/{e} — skip fractions below "
-                  f"overstate a uniformly-routed workload")
         gates = jnp.full(eids.shape, 1.0 / eids.shape[1], jnp.float32)
         x2d = jax.random.normal(jax.random.PRNGKey(34), (batch, k))
         cap = blocks._capacity(batch, cfg)
-        # skip accounting from exactly ONE dispatch — the counters are
-        # process-global and timed() adds a warmup launch on top of its
-        # repeats, which would multiply executed_tile_dots per run
-        activation_occupancy.reset_skip_stats()
-        y_local = dispatch(x2d, eids, gates, kwi, kwo, 0, cap)
-        jax.block_until_ready(y_local)
-        stats = activation_occupancy.skip_stats()
-        executed = int(stats["executed_tile_dots"])
-        us, _ = timed(
+        us, y_local = timed(
             lambda: dispatch(x2d, eids, gates, kwi, kwo, 0, cap),
             repeats=1)
-        # the ISSUE acceptance: the routed kneaded path executes strictly
-        # fewer tile-dots than the capacity-padded dense expert slab
+        # the routed kneaded path executes strictly fewer tile-dots than
+        # the capacity-padded dense expert slab
         assert 0 < executed < dense, (executed, dense)
 
         # emulated expert parallelism: per-shard expert slices at their
@@ -590,7 +455,6 @@ def moe_decode_sweep(quick: bool) -> List[BenchRow]:
         tok_s = batch / (us * 1e-6)
         met = {
             "executed_tile_dots": executed,
-            "weight_tile_dots": int(stats["weight_tile_dots"]),
             "dense_tile_dots": dense,
             "expert_imbalance": expert_imbalance,
             "active_experts": active,

@@ -52,12 +52,6 @@ GATED = ("executed_tile_dots", "cycle_ratio", "max_err",
          "shard_executed_max", "shard_imbalance", "expert_imbalance",
          "p50_latency_ticks",
          "p95_latency_ticks", "total_ticks", "failed_requests", "retries")
-# higher-is-better metrics: act_skip_frac is the activation-intersected
-# skip fraction of the two-sided decode rows (docs/DESIGN.md §12) — a
-# change that quietly stops intersecting the runtime activation occupancy
-# (executed creeps back toward the weight-only count) drops the fraction
-# and fails the build, symmetric to executed_tile_dots rising
-GATED_HIGHER = ("act_skip_frac",)
 # max_err floor: don't flag 1e-6-scale float noise as a "regression"
 ABS_FLOOR = {"max_err": 1e-4}
 
@@ -70,8 +64,7 @@ def compare(current: Dict[str, dict], baseline: Dict[str, dict],
             tolerance: float) -> list:
     failures = []
     for name, base_met in baseline.items():
-        gated = {k: v for k, v in base_met.items()
-                 if k in GATED or k in GATED_HIGHER}
+        gated = {k: v for k, v in base_met.items() if k in GATED}
         if not gated:
             continue
         if name not in current:
@@ -83,14 +76,6 @@ def compare(current: Dict[str, dict], baseline: Dict[str, dict],
                 failures.append(f"{name}.{key}: metric missing")
                 continue
             cur_val = float(cur_met[key])
-            if key in GATED_HIGHER:
-                floor = float(base_val) * (1.0 - tolerance)
-                if cur_val < floor:
-                    failures.append(
-                        f"{name}.{key}: {cur_val:.6g} fell below baseline "
-                        f"{float(base_val):.6g} by more than "
-                        f"{100 * tolerance:.0f}%")
-                continue
             limit = float(base_val) * (1.0 + tolerance) + \
                 ABS_FLOOR.get(key, 0.0)
             if cur_val > limit:
@@ -121,7 +106,7 @@ def main(argv=None) -> int:
             print(f"  FAIL {msg}", file=sys.stderr)
         return 1
     n = sum(1 for met in baseline.values()
-            if any(k in GATED or k in GATED_HIGHER for k in met))
+            if any(k in GATED for k in met))
     print(f"perf gate OK: {n} baselined rows within "
           f"{100 * args.tolerance:.0f}% of {args.baseline}")
     return 0

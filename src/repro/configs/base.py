@@ -102,14 +102,6 @@ class ModelConfig:
     impl: str = "int"
     sac_impl: Optional[str] = dataclasses.field(default=None, repr=False,
                                                 compare=False)
-    # Runtime activation-side skip for KneadedWeight leaves (two-sided skip,
-    # docs/DESIGN.md §12): intersect per-K-tile activation presence into the
-    # kernel's schedule walk on decode-GEMV calls (<= 8 flattened rows);
-    # prefill falls back to the static weight-only skip.  Bit-exact on/off —
-    # dropped work items contribute exactly 0.0.  Float-weight leaves and
-    # the non-pallas impls ignore it; ServingEngine overrides it from
-    # ``ServingConfig.activation_skip``.
-    activation_skip: bool = False
     window: int = 0                   # >0: sliding-window attention (long ctx)
     # training
     microbatch: int = 0               # 0 -> no gradient accumulation

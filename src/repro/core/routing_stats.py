@@ -3,10 +3,9 @@
 The MoE dispatch records, from inside jitted prefill/decode steps, how many
 (token, expert) assignments each step actually executed and how many were
 dropped by the capacity limit (``mypos >= cap`` in the slot routing).  The
-counters are process-global — accumulated via ``jax.debug.callback`` exactly
-like the activation-skip accounting in
-:mod:`repro.core.activation_occupancy` — so each serving engine snapshots a
-baseline at construction and reports its own delta in ``latency_stats()``.
+counters are process-global — accumulated via ``jax.debug.callback`` — so
+each serving engine snapshots a baseline at construction and reports its own
+delta in ``latency_stats()``.
 
 Together with the static per-expert work table
 (:meth:`repro.core.kneading.KneadedWeight.work_table`) this is the input the

@@ -94,8 +94,6 @@ def sac_matmul(
     a: jax.Array,
     kw: KneadedWeight,
     impl: Literal["float", "planes", "int", "pallas"] = "int",
-    *,
-    skip_activations: bool = False,
 ) -> jax.Array:
     """SAC matmul of activations [..., K] against a kneaded weight [K, N].
 
@@ -103,18 +101,6 @@ def sac_matmul(
     reduction dim: logical inputs are zero-padded up to ``kw.k`` and the
     output is sliced back to ``kw.logical_n`` — exact, since padded rows/
     channels are all-zero codes.
-
-    ``skip_activations=True`` arms the runtime activation-side skip
-    (docs/DESIGN.md §12) on the Pallas paths, gated to the decode-GEMV
-    regime: it engages only when the flattened activation has at most
-    ``GEMV_ROWS_MAX`` (8) rows — a decode step — where per-K-tile presence
-    bits from the activation row are intersected into the kernel's schedule
-    walk.  Prefill-shaped calls (M > 8) silently fall back to the static
-    weight-only skip: unioned presence over hundreds of rows is all ones,
-    so masking would cost runtime for zero skipped work.  The switch never
-    changes results on any impl: dropped items contribute exactly 0.0, so
-    the non-pallas impls ("planes"/"int"/"float"), which ignore the flag,
-    double as the skip-off oracles the parity tests compare against.
 
     impl="float" dequantizes the codes and runs one f32 matmul — the
     quantized-model reference the SAC paths must match (identical math to
@@ -133,8 +119,6 @@ def sac_matmul(
         raise ValueError(
             f"activation K {a2.shape[1]} matches neither stored "
             f"{kw.k} nor logical {kw.logical_k}")
-    from repro.core.activation_occupancy import GEMV_ROWS_MAX
-    skip = bool(skip_activations) and a2.shape[0] <= GEMV_ROWS_MAX
     if isinstance(kw, ShardedKneadedWeight):
         if impl != "pallas":
             raise ValueError("sharded kneaded weights execute through the "
@@ -146,12 +130,11 @@ def sac_matmul(
         from repro.kernels.sac_matmul.ops import sac_matmul_pallas_sharded
         from repro.runtime.sharding import current_serving_mesh
         mesh, axis = current_serving_mesh()
-        out = sac_matmul_pallas_sharded(a2, kw, mesh, axis,
-                                        skip_activations=skip)
+        out = sac_matmul_pallas_sharded(a2, kw, mesh, axis)
     elif impl == "pallas":
         # the ops-level wrapper owns the logical-K zero-pad policy
         from repro.kernels.sac_matmul.ops import sac_matmul_pallas
-        out = sac_matmul_pallas(a2, kw, skip_activations=skip)
+        out = sac_matmul_pallas(a2, kw)
     else:
         if a2.shape[1] != kw.k:
             a2 = jnp.pad(a2, ((0, 0), (0, kw.k - a2.shape[1])))

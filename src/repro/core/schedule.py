@@ -137,7 +137,7 @@ class KneadedSchedule:
                   grid steps re-request resident blocks.
       plane_bits: int32 [N/n_block, num_work] — per slot, bit ``b`` set iff
                   plane ``b`` of that K-tile is non-empty; 0 past
-                  ``counts[j]``.  This is the kernel's static survival mask.
+                  ``counts[j]``.  This is the kernel's static plane mask.
       num_work:   static grid extent of the work dimension:
                   ``max(1, max(counts))`` (>= 1 so init/epilogue always run).
       total_work: static popcount of ``plane_bits`` == occupancy nonzero
@@ -209,7 +209,7 @@ def build_schedule(occupancy_map: jax.Array) -> KneadedSchedule:
     )
 
 
-def replay_schedule(a, kw, act_presence=None) -> jax.Array:
+def replay_schedule(a, kw) -> jax.Array:
     """Executable spec of the compacted kernel: walk the schedule on the host.
 
     Replays, in numpy, exactly the passes the kernel's grid executes — per
@@ -224,20 +224,9 @@ def replay_schedule(a, kw, act_presence=None) -> jax.Array:
     planes oracle, so accumulation rounding is identical
     operation-for-operation.
 
-    ``act_presence`` ({0,1} [nk], e.g. from
-    :func:`repro.core.activation_occupancy.ktile_presence`) replays the
-    activation-*intersected* walk of the two-sided skip (docs/DESIGN.md
-    §12): slots whose K-tile presence bit is 0 are dropped, survivors keep
-    their ascending order — the oracle the masked Pallas walk is pinned
-    bit-exact against, and (when the presence honestly reflects ``a``'s
-    zeros) bit-exact against the unskipped replay too, since every dropped
-    dot is exactly 0.
-
     Args:
       a:  [M, K] activations (K == kw.k, stored/padded dim).
       kw: a :class:`repro.core.kneading.KneadedWeight` with a schedule.
-      act_presence: optional {0,1} [kw.k // kw.ks] activation K-tile
-        presence bits; None replays the static weight-only walk.
     """
     from repro.core import bitplanes
 
@@ -248,7 +237,6 @@ def replay_schedule(a, kw, act_presence=None) -> jax.Array:
     counts = np.asarray(sched.counts)
     ktile_ids = np.asarray(sched.ktile_ids)
     plane_bits = np.asarray(sched.plane_bits)
-    presence = None if act_presence is None else np.asarray(act_presence)
     ks, nb = kw.ks, kw.n_block
     m = a32.shape[0]
     weights = (2.0 ** jnp.arange(kw.bits - 1)).reshape(-1, 1, 1)
@@ -258,8 +246,6 @@ def replay_schedule(a, kw, act_presence=None) -> jax.Array:
         seg = [jnp.zeros((m, nb), jnp.float32) for _ in range(kw.bits - 1)]
         for w in range(int(counts[j])):                # occupied K-tiles only
             t, bits_w = int(ktile_ids[j, w]), int(plane_bits[j, w])
-            if presence is not None and not presence[t]:
-                continue                               # activation-side skip
             ksl = slice(t * ks, (t + 1) * ks)
             for b in range(kw.bits - 1):
                 if not (bits_w >> b) & 1:

@@ -220,13 +220,6 @@ class ServingConfig:
     # continuous: cap on admitted prompt tokens per scheduler step (0 =
     # uncapped) — bounds how much prefill work interleaves one decode step
     prefill_chunk: int = 0
-    # Runtime activation-side skip (two-sided skip, docs/DESIGN.md §12):
-    # intersect per-K-tile presence bits from the decode activation row
-    # into every kneaded projection's schedule walk.  Decode-GEMV steps
-    # only (prefill falls back to the static weight-only skip); bit-exact
-    # on/off.  Effective on the kneaded impls; "quant"/"float" ignore it.
-    # Surfaces executed_tile_dots / act_skip_frac in latency_stats().
-    activation_skip: bool = False
     # Fault handling (docs/DESIGN.md §10): bounded per-request retries,
     # NaN-logit quarantine, decode-step watchdog, impl-demotion ladder,
     # and knead-time checksum verification.  None (default) keeps the
@@ -284,11 +277,8 @@ class ServingEngine(RequestFrontEnd):
                            else params)
         else:
             # kneaded serving: the model dispatches every KneadedWeight
-            # matmul through the configured SAC path (and, when asked, the
-            # runtime activation-side skip — decode-GEMV only, bit-exact)
-            self.cfg = dataclasses.replace(
-                cfg, impl=scfg.impl,
-                activation_skip=scfg.activation_skip)
+            # matmul through the configured SAC path
+            self.cfg = dataclasses.replace(cfg, impl=scfg.impl)
             self.params = knead_params(
                 params, bits=scfg.quant_bits or 8,
                 min_dim=scfg.knead_min_dim, kneaded=True,

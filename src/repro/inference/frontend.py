@@ -219,14 +219,10 @@ class RequestFrontEnd:
         self._fault_counters: collections.Counter = collections.Counter()
         self._fault_events: Deque[Dict[str, Any]] = collections.deque(
             maxlen=stats_window)
-        # Activation-skip accounting baseline (docs/DESIGN.md §12): the
-        # counters are process-wide (they accumulate from inside jitted
-        # decode steps via debug callback), so each engine snapshots at
-        # construction and latency_stats() reports its own delta.
-        from repro.core import activation_occupancy
-        self._skip_stats_base = activation_occupancy.skip_stats()
-        # MoE routing-load accounting (docs/DESIGN.md §13): same process-
-        # global counter pattern — snapshot at construction, report deltas.
+        # MoE routing-load accounting (docs/DESIGN.md §13): the counters
+        # are process-wide (they accumulate from inside jitted steps via
+        # debug callback), so each engine snapshots at construction and
+        # latency_stats() reports its own delta.
         from repro.core import routing_stats
         self._routing_stats_base = routing_stats.routing_stats()
 
@@ -306,7 +302,6 @@ class RequestFrontEnd:
             return {"requests": 0,
                     **{k: int(v) for k, v in self._fault_counters.items()
                        if v},
-                    **self._skip_stats_delta(),
                     **self._routing_stats_delta()}
         out = {
             "requests": int(lat.size),
@@ -328,30 +323,10 @@ class RequestFrontEnd:
         # resilience counters (docs/DESIGN.md §10): zero-valued keys are
         # omitted — a fault-free engine's stats look exactly as before
         out.update({k: int(v) for k, v in self._fault_counters.items() if v})
-        # activation-skip accounting (docs/DESIGN.md §12): present only
-        # when masked launches actually ran under this engine
-        out.update(self._skip_stats_delta())
         # MoE routing load (docs/DESIGN.md §13): present only when routed
         # MoE layers actually ran under this engine
         out.update(self._routing_stats_delta())
         return out
-
-    def _skip_stats_delta(self) -> Dict[str, float]:
-        """This engine's activation-skip traffic since construction:
-        ``executed_tile_dots``, ``weight_tile_dots`` and the derived
-        ``act_skip_frac`` — empty when no masked launch ran (skip off),
-        so stats dicts are unchanged for skip-off engines."""
-        from repro.core import activation_occupancy
-        cur = activation_occupancy.skip_stats()
-        weight = (cur["weight_tile_dots"]
-                  - self._skip_stats_base["weight_tile_dots"])
-        if weight <= 0:
-            return {}
-        executed = (cur["executed_tile_dots"]
-                    - self._skip_stats_base["executed_tile_dots"])
-        return {"executed_tile_dots": int(executed),
-                "weight_tile_dots": int(weight),
-                "act_skip_frac": float(1.0 - executed / weight)}
 
     def _routing_stats_delta(self) -> Dict[str, int]:
         """This engine's MoE routing load since construction: per-step

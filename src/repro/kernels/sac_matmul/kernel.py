@@ -28,16 +28,13 @@ K-tile — one DMA of ``(B-1, bk/32, bn)`` packed words, 28 KB at 8 bits —
 with its activation and sign blocks, unpacks the signs once, and runs an
 unrolled ``for b in range(B-1)`` whose body (unpack plane ``b``, apply the
 signs, one MXU dot, add into ``S_b``) executes under bit ``b`` of the
-slot's scalar-prefetched *survival mask*.  Padded slots repeat the tile's
-last K-tile with mask 0: their index maps request already-resident blocks
-(Pallas elides the DMA) and the body idles.  The static mask is the
-schedule's ``plane_bits``; the runtime activation-intersected mask
-(docs/DESIGN.md §12) additionally zeroes slots whose activation K-slice is
-all zero — the two-sided skip.  Total executed MXU passes per M-step
-therefore equal the *intersected* occupancy nonzero count, not the dense
-``(B-1) * K/bk * N/bn``, while grid steps equal occupied K-tiles — the
-paper's "skip the slack" realized at the front-end scheduler, with the
-per-step overhead paid once per K-tile rather than once per plane.
+slot's scalar-prefetched plane mask, the schedule's ``plane_bits``.  Padded
+slots repeat the tile's last K-tile with mask 0: their index maps request
+already-resident blocks (Pallas elides the DMA) and the body idles.  Total
+executed MXU passes per M-step therefore equal the occupancy nonzero count,
+not the dense ``(B-1) * K/bk * N/bn``, while grid steps equal occupied
+K-tiles — the paper's "skip the slack" realized at the front-end scheduler,
+with the per-step overhead paid once per K-tile rather than once per plane.
 
 Skip granularity, DMA versus compute: an empty K-tile (every plane empty in
 this N-tile) costs neither a step nor a DMA.  An occupied K-tile's empty
@@ -132,7 +129,7 @@ def sac_matmul_kernel(
     def _init():
         seg_ref[...] = jnp.zeros_like(seg_ref)
 
-    live = mask_ref[j, w]                  # this slot's surviving planes
+    live = mask_ref[j, w]                  # this slot's occupied planes
 
     @pl.when(live != 0)                    # occupied K-tile (else idle)
     def _ktile():
@@ -176,17 +173,11 @@ def sac_matmul_pallas_call(
     bn: int = 128,
     bk: int = 256,
     interpret: bool = True,
-    mask: jax.Array | None = None,
 ) -> jax.Array:
     """Raw pallas_call wrapper (shapes must already be tile-aligned).
 
-    ``mask`` is the per-slot survival mask of planes, int32 [N/bn,
-    num_work] — the *runtime* half of the two-sided skip (docs/DESIGN.md
-    §12).  ``None`` runs the static weight-only walk, the schedule's own
-    ``plane_bits``.  An activation-intersected mask may additionally zero
-    slots whose activation K-slice is all zero; surviving slots keep their
-    positions, so per-segment f32 accumulation order — hence bit-exactness
-    vs the planes oracle — is preserved.
+    The schedule's ``plane_bits`` (int32 [N/bn, num_work], one plane mask
+    per slot) and ``ktile_ids`` are the two scalar-prefetch operands.
     """
     m, k = a.shape
     n = planes.shape[-1]
@@ -196,10 +187,6 @@ def sac_matmul_pallas_call(
         schedule.nk, schedule.n_tiles, k // bk, n // bn)
     num_work = schedule.num_work
     grid = (m // bm, n // bn, num_work)
-    if mask is None:
-        mask = schedule.plane_bits
-    assert mask.shape == schedule.ktile_ids.shape, (
-        mask.shape, schedule.ktile_ids.shape)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -228,4 +215,4 @@ def sac_matmul_pallas_call(
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
         name="sac_matmul",
-    )(mask.astype(jnp.int32), schedule.ktile_ids, a, planes, signs, scale)
+    )(schedule.plane_bits, schedule.ktile_ids, a, planes, signs, scale)

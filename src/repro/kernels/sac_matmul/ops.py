@@ -43,7 +43,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core import activation_occupancy
 from repro.core.kneading import KneadedWeight, ShardedKneadedWeight
 from repro.core.schedule import KneadedSchedule
 from repro.kernels.backend import interpret_mode
@@ -59,12 +58,12 @@ MATMUL_BM = 1024
 
 @functools.partial(
     jax.jit, static_argnames=("bits", "ks", "n_block", "bm", "interpret"))
-def _run(a, planes, signs, scale, schedule, mask, *, bits, ks, n_block, bm,
+def _run(a, planes, signs, scale, schedule, *, bits, ks, n_block, bm,
          interpret):
     return sac_matmul_pallas_call(
         a, planes, signs, scale, schedule,
         bits=bits, bm=bm, bn=n_block, bk=ks,
-        interpret=interpret, mask=mask,
+        interpret=interpret,
     )
 
 
@@ -73,7 +72,6 @@ def sac_matmul_pallas(
     kw: KneadedWeight,
     *,
     bm: int = MATMUL_BM,
-    skip_activations: bool = False,
 ) -> jax.Array:
     """[M, K] @ kneaded [K, N] -> [M, N] f32 via the Pallas SAC kernel.
 
@@ -83,15 +81,6 @@ def sac_matmul_pallas(
     need no padding logic of their own.  N alignment is guaranteed by the
     kneaded format (n_block | N); the output keeps the stored N (slice to
     ``kw.logical_n`` at the call site if needed).
-
-    ``skip_activations=True`` arms the two-sided skip (docs/DESIGN.md §12):
-    per-K-tile presence bits computed from the (padded) activations are
-    intersected into the schedule walk via the kernel's survival mask, so
-    occupied K-tiles whose activation slice is all zero never execute an
-    MXU pass.  Bit-exact against the unskipped walk — a dropped pass would
-    have contributed exactly 0.0 to its f32 segment, and surviving slots
-    keep their ascending K order.  ``core.sac.sac_matmul`` gates this to the
-    decode-GEMV regime; this raw entry applies it at any M when asked.
 
     The kernel itself is strictly 2-D: stacked weights (LM layer stacks,
     MoE expert banks — planes ndim > 3) must be sliced to one [K, N]
@@ -106,14 +95,8 @@ def sac_matmul_pallas(
             f"one slice first (expert banks: models.blocks."
             f"_dispatch_compute_kneaded, docs/DESIGN.md §13)")
     a, m, bm_eff = _pad_activations(a, kw, bm)
-    mask = kw.schedule.plane_bits
-    if skip_activations:
-        presence = activation_occupancy.ktile_presence(a, kw.ks)
-        mask = activation_occupancy.work_mask(
-            mask, kw.schedule.ktile_ids, presence)
-        activation_occupancy.record_skip(mask, kw.schedule.plane_bits)
     out = _run(
-        a, kw.planes, kw.signs, kw.scale, kw.schedule, mask,
+        a, kw.planes, kw.signs, kw.scale, kw.schedule,
         bits=kw.bits, ks=kw.ks, n_block=kw.n_block, bm=bm_eff,
         interpret=interpret_mode(),
     )
@@ -166,7 +149,6 @@ def sac_matmul_pallas_sharded(
     axis: str = "model",
     *,
     bm: int = MATMUL_BM,
-    skip_activations: bool = False,
 ) -> jax.Array:
     """[M, K] @ N-sharded kneaded [K, N] -> [M, N] f32, one kernel per shard.
 
@@ -196,26 +178,11 @@ def sac_matmul_pallas_sharded(
 
     Output keeps the sharded stored N (slice to ``skw.logical_n`` at the
     call site, as with the unsharded op).
-
-    ``skip_activations=True``: the activation K-tile presence is computed
-    *once* from the replicated (padded) activations — sharding is along N,
-    so every shard sees the same presence bits — and intersected with each
-    shard's own plane masks into a per-shard survival mask [S, T,
-    num_work], sliced per device alongside the schedule arrays.  The
-    balanced partition's ``tile_slot`` gather epilogue is untouched:
-    masking changes which passes a tile executes, never which shard/slot
-    the tile lives in.
     """
     interpret = interpret_mode()
     a, m, bm_eff = _pad_activations(a, skw, bm)
-    # per-slot survival masks, one row of shards: [S, T, num_work]
-    mask = skw.plane_bits
-    if skip_activations:
-        presence = activation_occupancy.ktile_presence(a, skw.ks)
-        mask = activation_occupancy.work_mask(mask, skw.ktile_ids, presence)
-        activation_occupancy.record_skip(mask, skw.plane_bits)
 
-    def one_shard(a_, planes, signs, scale, counts, kids, pbits, mask_):
+    def one_shard(a_, planes, signs, scale, counts, kids, pbits):
         # inside shard_map every arg holds this device's slab with the
         # leading shard axis collapsed to extent 1
         sched = KneadedSchedule(
@@ -225,22 +192,21 @@ def sac_matmul_pallas_sharded(
         return sac_matmul_pallas_call(
             a_, planes[0], signs[0], scale[0], sched,
             bits=skw.bits, bm=bm_eff, bn=skw.n_block, bk=skw.ks,
-            interpret=interpret, mask=mask_[0])
+            interpret=interpret)
 
     if mesh is None:
         outs = [one_shard(a, skw.planes[s:s + 1], skw.signs[s:s + 1],
                           skw.scale[s:s + 1], skw.counts[s:s + 1],
-                          skw.ktile_ids[s:s + 1], skw.plane_bits[s:s + 1],
-                          mask[s:s + 1])
+                          skw.ktile_ids[s:s + 1], skw.plane_bits[s:s + 1])
                 for s in range(skw.num_shards)]
         out = jnp.concatenate(outs, axis=1)
     else:
-        sharded = (P(axis),) * 7
+        sharded = (P(axis),) * 6
         out = jax.shard_map(
             one_shard, mesh=mesh, in_specs=(P(),) + sharded,
             out_specs=P(None, axis), check_vma=False,
         )(a, skw.planes, skw.signs, skw.scale, skw.counts,
-          skw.ktile_ids, skw.plane_bits, mask)
+          skw.ktile_ids, skw.plane_bits)
     if skw.partition == "balanced":
         tiles = out.reshape(out.shape[0], -1, skw.n_block)
         out = jnp.take(tiles, skw.tile_slot, axis=1

@@ -57,17 +57,6 @@ def main():
                          "contiguous N-tile slabs, or occupancy-balanced "
                          "LPT bin-packing with a recorded permutation "
                          "(bit-exact either way; docs/DESIGN.md §11)")
-    ap.add_argument("--activation-skip", action="store_true",
-                    help="arm the runtime activation-side skip (two-sided "
-                         "skip, docs/DESIGN.md §12): per-K-tile presence "
-                         "bits from the decode activation row are "
-                         "intersected into every kneaded projection's "
-                         "schedule walk, so work items whose activation "
-                         "slice is all zero never execute.  Decode-GEMV "
-                         "steps only (prefill keeps the static weight-only "
-                         "skip); bit-exact on/off.  Effective with the "
-                         "kneaded impls (int/planes/pallas); reports "
-                         "act_skip_frac in the latency stats")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--tokens", type=int, default=32)
@@ -141,7 +130,6 @@ def main():
         impl=args.impl, knead_min_dim=args.knead_min_dim,
         shards=args.shards, shard_partition=args.shard_partition,
         expert_shards=args.expert_shards,
-        activation_skip=args.activation_skip,
         scheduler=args.scheduler,
         max_inflight=args.max_inflight, fault_policy=fault_policy))
     if args.impl in ("int", "planes", "pallas"):
@@ -212,10 +200,6 @@ def main():
         print(f"routing: {stats['routed_tokens']} tokens routed over "
               f"{stats['routing_steps']} steps, "
               f"{stats['capacity_dropped']} dropped at capacity")
-    if args.activation_skip and "act_skip_frac" in stats:
-        print(f"activation skip: {stats['executed_tile_dots']} of "
-              f"{stats['weight_tile_dots']} scheduled tile-dots executed "
-              f"(act_skip_frac={stats['act_skip_frac']:.3f})")
     if fault_policy is not None:
         fault_keys = ("retries", "failed_requests", "recoveries",
                       "nan_quarantined", "watchdog_timeouts",

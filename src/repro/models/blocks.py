@@ -15,8 +15,7 @@ Kneaded expert banks (docs/DESIGN.md §13) take a second serving path: when
 ``p["wi"]``/``p["wo"]`` are stacked :class:`~repro.core.kneading.KneadedWeight`
 banks ([E, K, N] per layer), the capacity-padded dense einsum is replaced by a
 per-expert walk — each local expert's routed rows ([cap, D], M <= 8 at decode)
-run through the SAC kernel's decode-GEMV fast path with the activation-skip
-mask computed from exactly those routed rows.  Experts shard over the
+run through the SAC kernel's decode-GEMV fast path.  Experts shard over the
 dedicated "expert" mesh axis (the "model" axis keeps N-sharding the dense
 projections); slot routing and the f32 scatter-add combine are shared with
 the dense path, so EP == all-local stays bit-exact through the psum.
@@ -130,13 +129,10 @@ def _qkv(p, x, kv_src, cfg: ModelConfig, dtype):
     b = x.shape[0]
     hd, nh, nkv = cfg.hd, cfg.num_heads, cfg.num_kv_heads
     g = nh // nkv
-    impl, skip = cfg.impl, cfg.activation_skip
-    q = matmul_any(x, p["wq"], dtype, impl=impl,
-                   skip_activations=skip).reshape(b, -1, nkv, g, hd)
-    k = matmul_any(kv_src, p["wk"], dtype, impl=impl,
-                   skip_activations=skip).reshape(b, -1, nkv, hd)
-    v = matmul_any(kv_src, p["wv"], dtype, impl=impl,
-                   skip_activations=skip).reshape(b, -1, nkv, hd)
+    impl = cfg.impl
+    q = matmul_any(x, p["wq"], dtype, impl=impl).reshape(b, -1, nkv, g, hd)
+    k = matmul_any(kv_src, p["wk"], dtype, impl=impl).reshape(b, -1, nkv, hd)
+    v = matmul_any(kv_src, p["wv"], dtype, impl=impl).reshape(b, -1, nkv, hd)
     if cfg.qk_norm:
         q = layers.rms_head_norm(q, p["qnorm"], cfg.norm_eps)
         k = layers.rms_head_norm(k, p["knorm"], cfg.norm_eps)
@@ -214,7 +210,7 @@ def attn_apply(
             out = layers.decode_attention(q, *read(cache), pos,
                                           window=cfg.window)
         y = matmul_any(out.reshape(out.shape[0], 1, -1), p["wo"], dtype,
-                       impl=cfg.impl, skip_activations=cfg.activation_skip)
+                       impl=cfg.impl)
         return layers.add_residual(x, y, cfg.residual_scale), cache
 
     if kv_const is not None:                      # ---- cross-attn w/ cached KV
@@ -242,8 +238,7 @@ def attn_apply(
                             and _attn_shard_mode(cfg) is None
                             and pspec.current_mesh() is not None)
     b, s = out.shape[:2]
-    y = matmul_any(out.reshape(b, s, -1), p["wo"], dtype, impl=cfg.impl,
-                   skip_activations=cfg.activation_skip)
+    y = matmul_any(out.reshape(b, s, -1), p["wo"], dtype, impl=cfg.impl)
     y = res_constrain(layers.add_residual(x, y, cfg.residual_scale), cfg)
     if return_kv:
         return y, (k, v)
@@ -275,25 +270,20 @@ def mlp_init(key, cfg: ModelConfig, d_ff: Optional[int] = None) -> dict:
     return p
 
 
-def _ffn(h, p, activation: str, dtype, impl: str = "int",
-         skip: bool = False) -> jax.Array:
+def _ffn(h, p, activation: str, dtype, impl: str = "int") -> jax.Array:
     if activation == "swiglu":
-        u = (jax.nn.silu(matmul_any(h, p["wi_gate"], dtype, impl=impl,
-                                    skip_activations=skip))
-             * matmul_any(h, p["wi_up"], dtype, impl=impl,
-                          skip_activations=skip))
+        u = (jax.nn.silu(matmul_any(h, p["wi_gate"], dtype, impl=impl))
+             * matmul_any(h, p["wi_up"], dtype, impl=impl))
     else:
-        u = layers.activate(matmul_any(h, p["wi"], dtype, impl=impl,
-                                       skip_activations=skip),
+        u = layers.activate(matmul_any(h, p["wi"], dtype, impl=impl),
                             activation)
-    return matmul_any(u, p["wo"], dtype, impl=impl, skip_activations=skip)
+    return matmul_any(u, p["wo"], dtype, impl=impl)
 
 
 def mlp_apply(p, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     dtype = jnp.dtype(cfg.dtype)
     h = sp_gather(layers.apply_norm(p["ln"], x, cfg.norm, cfg.norm_eps), cfg)
-    y = _ffn(h, p, cfg.activation, dtype, impl=cfg.impl,
-             skip=cfg.activation_skip)
+    y = _ffn(h, p, cfg.activation, dtype, impl=cfg.impl)
     return res_constrain(layers.add_residual(x, y, cfg.residual_scale), cfg)
 
 
@@ -423,10 +413,8 @@ def _dispatch_compute_kneaded(x2d, eids, gates, kwi, kwo,
     kneaded weight).  Instead of the capacity-padded [E, C, D] dense slab,
     each expert runs only its own gathered [cap, D] rows through
     ``matmul_any`` -> SAC: at decode cap <= 8, so this is the decode-GEMV
-    fast path and the PR-9 activation-skip mask is computed from exactly
-    the routed rows (unfilled capacity slots gather the zero pad row and
-    contribute no K-tile presence — routing sparsity becomes skipped MXU
-    passes for free).  Routing and combine are shared with the dense path
+    fast path (unfilled capacity slots gather the zero pad row).  Routing
+    and combine are shared with the dense path
     (:func:`_route_slots` / :func:`_combine_slots`), so the f32 reduction
     order — and therefore bit-exactness of EP vs all-local through the
     psum — is unchanged.  ``combine_dtype`` overrides the combine output
@@ -441,17 +429,17 @@ def _dispatch_compute_kneaded(x2d, eids, gates, kwi, kwo,
     if combine_dtype is None:
         combine_dtype = x2d.dtype
     xg, disp, slot_gate = _route_slots(x2d, eids, gates, e_loc, e_offset, cap)
-    impl, skip = cfg.impl, cfg.activation_skip
+    impl = cfg.impl
 
     def expert_body(carry, ew):
         kwi_e, kwo_e, xg_e = ew
-        h = matmul_any(xg_e, kwi_e, dtype, impl=impl, skip_activations=skip)
+        h = matmul_any(xg_e, kwi_e, dtype, impl=impl)
         if cfg.activation == "swiglu":
             gate_h, up = jnp.split(h, 2, axis=-1)
             h = jax.nn.silu(gate_h) * up
         else:
             h = layers.activate(h, cfg.activation)
-        y_e = matmul_any(h, kwo_e, dtype, impl=impl, skip_activations=skip)
+        y_e = matmul_any(h, kwo_e, dtype, impl=impl)
         return carry, y_e
 
     _, y = jax.lax.scan(expert_body, None, (kwi, kwo, xg))
@@ -540,7 +528,7 @@ def moe_apply(p, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
             dense_h = layers.apply_norm(p["dense"]["ln"], x, cfg.norm,
                                         cfg.norm_eps)
             y = y + _ffn(dense_h, p["dense"], cfg.activation, dtype,
-                         impl=cfg.impl, skip=cfg.activation_skip)
+                         impl=cfg.impl)
         return res_constrain(x + y.astype(x.dtype), cfg), aux
     wi_q, wi_s, wi_p4 = _split_quant(p["wi"])
     wo_q, wo_s, wo_p4 = _split_quant(p["wo"])
@@ -588,6 +576,6 @@ def moe_apply(p, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
         dense_h = layers.apply_norm(p["dense"]["ln"], x, cfg.norm,
                                     cfg.norm_eps)
         y = y + _ffn(dense_h, p["dense"], cfg.activation, dtype,
-                     impl=cfg.impl, skip=cfg.activation_skip)
+                     impl=cfg.impl)
     out = res_constrain(x + y.astype(x.dtype), cfg)
     return out, aux

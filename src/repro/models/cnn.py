@@ -100,8 +100,7 @@ def init(key, cfg: CNNConfig) -> Dict:
 
 def apply(params: Dict, x: jax.Array, cfg: CNNConfig,
           collect_activations: bool = False, impl: str = "float",
-          mesh=None, shard_axis: str = "model",
-          skip_activations: bool = False):
+          mesh=None, shard_axis: str = "model"):
     """x [B, H, W, C] -> logits [B, classes] (+ per-layer matmul inputs).
 
     ``impl`` selects the execution path for kneaded layers (see module
@@ -148,13 +147,10 @@ def apply(params: Dict, x: jax.Array, cfg: CNNConfig,
             p = params[f"fc{i}"]
             if isinstance(p["w"], ShardedKneadedWeight):
                 from repro.kernels.sac_matmul.ops import sac_matmul_pallas_sharded
-                out = sac_matmul_pallas_sharded(
-                    x, p["w"], mesh, shard_axis,
-                    skip_activations=skip_activations and x.shape[0] <= 8)
+                out = sac_matmul_pallas_sharded(x, p["w"], mesh, shard_axis)
                 x = out[:, :p["w"].logical_n] + p["b"]
             else:
-                x = L.matmul_any(x, p["w"], jnp.float32, impl=impl,
-                                 skip_activations=skip_activations) + p["b"]
+                x = L.matmul_any(x, p["w"], jnp.float32, impl=impl) + p["b"]
             if i != len(cfg.spec) - 1:
                 x = jax.nn.relu(x)
     if x.ndim == 4:                 # NiN: global average pooling head

@@ -48,7 +48,7 @@ class PackedInt4:
 
 
 def matmul_any(x: jax.Array, w, compute_dtype=jnp.bfloat16,
-               impl: str = "int", skip_activations: bool = False) -> jax.Array:
+               impl: str = "int") -> jax.Array:
     """x @ w for float, QuantizedTensor (int8), KneadedWeight, or PackedInt4.
 
     Quantized paths follow SAC: integer-code contraction with the per-channel
@@ -58,16 +58,11 @@ def matmul_any(x: jax.Array, w, compute_dtype=jnp.bfloat16,
     ignore it.  N-sharded kneaded leaves (per-layer scan slices of a
     ``ShardedStackedKneadedWeight``, or plain ``ShardedKneadedWeight``)
     dispatch through the sharded Pallas entry under the serving mesh
-    (docs/DESIGN.md §8).  ``skip_activations`` arms the runtime two-sided
-    skip on kneaded leaves (``cfg.activation_skip``; docs/DESIGN.md §12) —
-    decode-GEMV calls only, bit-exact on/off, ignored by every other leaf
-    type.
+    (docs/DESIGN.md §8).
     """
     if isinstance(w, (KneadedWeight, ShardedKneadedWeight)):
         from repro.core.sac import sac_matmul
-        return sac_matmul(x, w, impl=impl,
-                          skip_activations=skip_activations
-                          ).astype(compute_dtype)
+        return sac_matmul(x, w, impl=impl).astype(compute_dtype)
     if isinstance(w, QuantizedTensor):
         out = jnp.einsum("...k,kn->...n", x.astype(compute_dtype),
                          w.q.astype(compute_dtype),

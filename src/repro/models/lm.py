@@ -415,8 +415,7 @@ class LanguageModel:
     def _head(self, params, h) -> jax.Array:
         cfg = self.cfg
         logits = layers.matmul_any(h, self._unembed_w(params),
-                                   jnp.dtype(cfg.dtype), impl=cfg.impl,
-                                   skip_activations=cfg.activation_skip)
+                                   jnp.dtype(cfg.dtype), impl=cfg.impl)
         return logits if cfg.logits_scale == 1.0 else logits * cfg.logits_scale
 
     def logits(self, params, batch) -> jax.Array:

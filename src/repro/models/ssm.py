@@ -165,8 +165,7 @@ def _mamba2_project(p, h, cfg: ModelConfig, dtype):
     di = cfg.ssm_expand * d
     n = cfg.ssm_state
     nh = di // cfg.ssm_head_dim
-    zxbcdt = matmul_any(h, p["in_proj"], dtype, impl=cfg.impl,
-                        skip_activations=cfg.activation_skip)
+    zxbcdt = matmul_any(h, p["in_proj"], dtype, impl=cfg.impl)
     z, xc, b, c, dt = jnp.split(
         zxbcdt, [di, 2 * di, 2 * di + n, 2 * di + 2 * n], axis=-1)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
@@ -217,8 +216,7 @@ def mamba2_apply(p, x: jax.Array, cfg: ModelConfig, *, cache=None):
         y = y + xs.reshape(bsz, -1, nh, hd) * p["d_skip"][:, None]
         y = y.reshape(bsz, -1, di) * jax.nn.silu(z.astype(jnp.float32))
         y = layers.apply_norm(p["out_norm"], y, "rmsnorm", cfg.norm_eps)
-    out = matmul_any(y, p["out_proj"], dtype, impl=cfg.impl,
-                     skip_activations=cfg.activation_skip)
+    out = matmul_any(y, p["out_proj"], dtype, impl=cfg.impl)
     return layers.add_residual(x, out, cfg.residual_scale), new_cache
 
 
@@ -266,20 +264,15 @@ def mlstm_apply(p, x: jax.Array, cfg: ModelConfig, *, cache=None,
     nh = cfg.num_heads
     hd = di // nh
     h = layers.apply_norm(p["ln"], x, cfg.norm, cfg.norm_eps)
-    skip = cfg.activation_skip
-    u2 = matmul_any(h, p["up"], dtype, impl=cfg.impl, skip_activations=skip)
-    xm, z = jnp.split(u2, 2, axis=-1)
     impl = cfg.impl
-    q = matmul_any(xm, p["wq"], dtype, impl=impl,
-                   skip_activations=skip).reshape(bsz, l, nh,
-                                                  hd) / np.sqrt(hd)
-    k = matmul_any(xm, p["wk"], dtype, impl=impl,
-                   skip_activations=skip).reshape(bsz, l, nh,
-                                                  hd) / np.sqrt(hd)
-    v = matmul_any(xm, p["wv"], dtype, impl=impl,
-                   skip_activations=skip).reshape(bsz, l, nh, hd)
-    gif = matmul_any(xm, p["w_if"], jnp.float32, impl=impl,
-                     skip_activations=skip)
+    u2 = matmul_any(h, p["up"], dtype, impl=impl)
+    xm, z = jnp.split(u2, 2, axis=-1)
+    q = matmul_any(xm, p["wq"], dtype, impl=impl).reshape(
+        bsz, l, nh, hd) / np.sqrt(hd)
+    k = matmul_any(xm, p["wk"], dtype, impl=impl).reshape(
+        bsz, l, nh, hd) / np.sqrt(hd)
+    v = matmul_any(xm, p["wv"], dtype, impl=impl).reshape(bsz, l, nh, hd)
+    gif = matmul_any(xm, p["w_if"], jnp.float32, impl=impl)
     ig, fg = jnp.split(gif, 2, axis=-1)                    # [B, L, H]
     log_a = jax.nn.log_sigmoid(fg + p["f_bias"])
     i_lin = jnp.exp(jnp.clip(ig, -10.0, 10.0))
@@ -300,7 +293,7 @@ def mlstm_apply(p, x: jax.Array, cfg: ModelConfig, *, cache=None,
     y = layers.apply_norm(p["out_norm"], y, "rmsnorm",
                           cfg.norm_eps) * jax.nn.silu(
         z.astype(jnp.float32)).astype(dtype)
-    out = matmul_any(y, p["down"], dtype, impl=impl, skip_activations=skip)
+    out = matmul_any(y, p["down"], dtype, impl=impl)
     return x + out, h_final
 
 
@@ -355,8 +348,7 @@ def slstm_apply(p, x: jax.Array, cfg: ModelConfig, *, cache=None):
     dtype = jnp.dtype(cfg.dtype)
     bsz, l, d = x.shape
     h0 = layers.apply_norm(p["ln"], x, cfg.norm, cfg.norm_eps)
-    xt = matmul_any(h0, p["w_in"], jnp.float32, impl=cfg.impl,
-                    skip_activations=cfg.activation_skip)   # [B, L, 4d]
+    xt = matmul_any(h0, p["w_in"], jnp.float32, impl=cfg.impl)  # [B, L, 4d]
     if cache is None:
         state = tuple(jnp.zeros((bsz, d), jnp.float32) for _ in range(3))
     else:
@@ -374,8 +366,7 @@ def slstm_apply(p, x: jax.Array, cfg: ModelConfig, *, cache=None):
         ys = jnp.moveaxis(ys, 0, 1)
     y = layers.apply_norm(p["out_norm"], ys.astype(dtype), "rmsnorm",
                           cfg.norm_eps)
-    out = matmul_any(y, p["w_out"], dtype, impl=cfg.impl,
-                     skip_activations=cfg.activation_skip)
+    out = matmul_any(y, p["w_out"], dtype, impl=cfg.impl)
     return x + out, state
 
 

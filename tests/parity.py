@@ -79,23 +79,6 @@ def check_parity(a: jax.Array, w: jax.Array, kw, *, rtol: float = 1e-5,
     return outs
 
 
-def check_skip_parity(a: jax.Array, kw, *, impls=("planes", "pallas")) -> dict:
-    """Activation-skip agreement (docs/DESIGN.md §12): for each impl,
-    ``skip_activations=True`` must be BIT-IDENTICAL to skip-off — the
-    runtime mask only drops work items whose contribution is exactly 0.
-    Returns the skip-on outputs (all also asserted equal to each other)."""
-    outs = {}
-    for impl in impls:
-        on = np.asarray(sac_matmul(a, kw, impl=impl, skip_activations=True))
-        off = np.asarray(sac_matmul(a, kw, impl=impl))
-        np.testing.assert_array_equal(on, off)
-        outs[impl] = on
-    vals = list(outs.values())
-    for other in vals[1:]:
-        np.testing.assert_array_equal(vals[0], other)
-    return outs
-
-
 def run_case(seed: int, m: int, k: int, n: int, *, bits: int = 8,
              ks: int = 256, n_block: int = 128,
              sparsity: float = 0.0) -> dict:

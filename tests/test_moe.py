@@ -266,22 +266,6 @@ def test_moe_engine_pallas_bit_exact_vs_planes(moe):
     np.testing.assert_array_equal(outs["pallas"][1], outs["planes"][1])
 
 
-def test_moe_engine_activation_skip_bit_exact(moe):
-    """Two-sided skip on the routed per-expert GEMV calls (the PR-9 mask
-    computed from exactly the routed rows) changes nothing bitwise."""
-    cfg, _, params, _ = moe
-    toks = jax.random.randint(jax.random.PRNGKey(13), (2, 8), 0,
-                              cfg.vocab_size)
-    gens = {}
-    for skip in (False, True):
-        eng = ServingEngine(cfg, params,
-                            ServingConfig(max_len=48, impl="pallas",
-                                          knead_min_dim=MIN_DIM,
-                                          activation_skip=skip))
-        gens[skip] = np.asarray(eng.generate({"tokens": toks}, 16))
-    np.testing.assert_array_equal(gens[True], gens[False])
-
-
 def test_moe_engine_quant_serves_dense_slab(moe):
     """The quantized (non-kneaded) MoE serving path is untouched: it still
     runs the capacity-padded dense slab and decodes."""

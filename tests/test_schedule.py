@@ -18,9 +18,8 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.core import activation_occupancy as actocc
 from repro.core import knead, sac_matmul
-from repro.core.bitplanes import pack_presence, popcount, unpack_presence
+from repro.core.bitplanes import pack_presence, unpack_presence
 from repro.core.kneading import knead_padded, knead_stacked
 from repro.core.schedule import (build_schedule, replay_schedule,
                                  shard_schedule, shard_stacked_schedule)
@@ -173,60 +172,92 @@ def _ktile_sparse_w():
     return jnp.asarray(w)
 
 
-@pytest.mark.parametrize("m", [1, 8, 64, 1024])
-def test_ktile_walk_sparse_bit_exact(m):
+# 8-bit cases keep their bare ids; 4 and 16 bits stack 3 and 15 planes in
+# one kernel body.
+_WALK_CASES = ([pytest.param(8, m, id=str(m)) for m in (1, 8, 64, 1024)]
+               + [pytest.param(b, m, id=f"{b}bit-{m}")
+                  for b in (4, 16) for m in (1, 8, 64)])
+
+
+@pytest.mark.parametrize("bits,m", _WALK_CASES)
+def test_ktile_walk_sparse_bit_exact(bits, m):
     """pallas == planes bitwise at decode and prefill M; ``counts`` is the
-    occupied K-tiles per N-tile; executed passes (``total_work``, and
-    ``skip_stats()`` with the activation skip on) are the occupancy
-    nonzeros."""
-    kw = knead(_ktile_sparse_w(), bits=8, ks=256, n_block=128)
-    occ = np.asarray(kw.occupancy_map())                  # [7, 4, 3]
+    occupied K-tiles per N-tile and ``total_work`` (the popcount of the
+    slots' plane masks) the occupancy nonzeros, at every width."""
+    kw = knead(_ktile_sparse_w(), bits=bits, ks=256, n_block=128)
+    occ = np.asarray(kw.occupancy_map())           # [bits - 1, 4, 3]
     occupied = occ.any(axis=0)
-    assert not occ[:, occupied].all()          # empty planes in live K-tiles
     assert not occupied.all()                  # wholly empty K-tiles
-    np.testing.assert_array_equal(np.asarray(kw.schedule.counts),
-                                  occupied.sum(axis=0))
-    assert np.asarray(kw.schedule.counts).tolist() == [3, 3, 2]
+    counts = np.asarray(kw.schedule.counts)
+    np.testing.assert_array_equal(counts, occupied.sum(axis=0))
+    if bits == 8:
+        assert not occ[:, occupied].all()      # empty planes in live K-tiles
+        assert counts.tolist() == [3, 3, 2]
     assert kw.schedule.total_work == int(occ.sum())
-    a = jax.random.normal(jax.random.PRNGKey(m), (m, 1024))
-    actocc.reset_skip_stats()
-    on = sac_matmul_pallas(a, kw, skip_activations=True)
-    jax.block_until_ready(on)
-    stats = actocc.skip_stats()
-    assert stats["executed_tile_dots"] == stats["weight_tile_dots"] \
+    assert int(np.bitwise_count(np.asarray(kw.schedule.plane_bits)).sum()) \
         == int(occ.sum())
+    a = jax.random.normal(jax.random.PRNGKey(m), (m, 1024))
     out = sac_matmul(a, kw, impl="pallas")
     ref = sac_matmul(a, kw, impl="planes")
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-    np.testing.assert_array_equal(np.asarray(on), np.asarray(out))
 
 
-@pytest.mark.parametrize("case", ["dense", "sparse"])
-def test_planes_per_step(case):
-    """MXU passes per executed grid step: all seven planes on every
-    occupied K-tile of a dense random 8-bit weight, fewer where the
-    weight-side skip empties planes."""
+@pytest.mark.parametrize("case,bits", [
+    pytest.param("dense", 8, id="dense"),
+    pytest.param("sparse", 8, id="sparse"),
+    pytest.param("dense", 4, id="dense4"),
+    pytest.param("dense", 16, id="dense16"),
+])
+def test_planes_per_step(case, bits):
+    """MXU passes per executed grid step: all ``bits - 1`` planes on every
+    occupied K-tile of a dense random weight, fewer where the weight-side
+    skip empties planes."""
     w = (_sparse_w(72, 1024, 384, 0.0) if case == "dense"
          else _ktile_sparse_w())
-    sched = knead(w, bits=8, ks=256, n_block=128).schedule
+    sched = knead(w, bits=bits, ks=256, n_block=128).schedule
     steps = int(np.asarray(sched.counts).sum())
     assert sched.planes_per_step == sched.total_work / steps
     if case == "dense":
-        assert sched.planes_per_step == 7.0
+        assert sched.planes_per_step == bits - 1
         assert steps == 4 * 3
     else:
-        assert 1.0 < sched.planes_per_step < 7.0
+        assert 1.0 < sched.planes_per_step < bits - 1
 
 
-@pytest.mark.parametrize("stacked", [False, True], ids=["2d", "stacked"])
-@pytest.mark.parametrize("partition", ["contiguous", "balanced"])
-def test_ktile_walk_sharded_replays_unsharded(partition, stacked):
-    """Sharded walks (both partitions), one layer of a stacked weight, and
-    a stacked weight sharded per layer, all replay the unsharded kernel on
-    the sparse K-tile weight bit-exactly."""
+@pytest.mark.parametrize("case", ["all_zero", "all_dense", "single_hot"])
+def test_ktile_walk_activation_extremes(case):
+    """Activation extremes on the static walk: an all-zero, a dense and a
+    single-hot (one live K-tile) activation all give pallas == planes
+    bitwise, and the all-zero one gives exact zeros."""
+    kw = knead(_sparse_w(31, 1024, 256, 0.5), bits=8)
+    rng = np.random.default_rng(32)
+    a = np.zeros((2, 1024), np.float32)
+    if case == "all_dense":
+        a = rng.normal(size=(2, 1024)).astype(np.float32)
+    elif case == "single_hot":
+        a[:, 256:512] = rng.normal(size=(2, 256)).astype(np.float32)
+    a = jnp.asarray(a)
+    out = np.asarray(sac_matmul_pallas(a, kw, bm=8))
+    ref = np.asarray(sac_matmul(a, kw, impl="planes"))
+    np.testing.assert_array_equal(out[:, :kw.logical_n], ref)
+    if case == "all_zero":
+        np.testing.assert_array_equal(out, np.zeros_like(out))
+    else:
+        assert np.abs(out).max() > 0
+
+
+@pytest.mark.parametrize("partition,stacked,bits", [
+    pytest.param(p, s, 8, id=f"{p}-{'stacked' if s else '2d'}")
+    for p in ("contiguous", "balanced") for s in (False, True)] + [
+    pytest.param("contiguous", False, b, id=f"contiguous-2d-{b}bit")
+    for b in (4, 16)])
+def test_ktile_walk_sharded_replays_unsharded(partition, stacked, bits):
+    """Sharded walks (both partitions, and 3- and 15-plane bodies), one
+    layer of a stacked weight, and a stacked weight sharded per layer, all
+    replay the unsharded kernel on the sparse K-tile weight bit-exactly."""
     w = _ktile_sparse_w()
     a = jax.random.normal(jax.random.PRNGKey(73), (8, 1024))
-    kw = knead(w, bits=8, ks=256, n_block=128)
+    kw = knead(w, bits=bits, ks=256, n_block=128)
     ref = np.asarray(sac_matmul_pallas(a, kw, bm=8))
     if stacked:
         # layer 1 is dense, so the stack pads layer 0's walks to its width
@@ -380,204 +411,6 @@ def test_balanced_sharded_bit_exact_random(seed, shards):
     out = sac_matmul_pallas_sharded(a, skw, bm=8)[:, :kw.n]
     ref = sac_matmul_pallas(a, kw, bm=8)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-
-
-# ------------------- activation-side skip (two-sided; docs/DESIGN.md §12)
-#
-# The runtime half of the skip intersects per-K-tile activation presence
-# into the static weight-side schedule.  The property wall: intersected
-# work ⊆ weight-only work (with the packed-presence popcount agreeing),
-# dropped items contribute exactly 0 to the replay oracle (work
-# conservation), the activation extremes survive, and the masked Pallas
-# walk stays bit-exact against planes AND the unskipped walk across random
-# sparsities.
-
-def _gappy_activation(seed, m, k, ks, dead_frac):
-    """[m, k] activations with whole K-tiles zeroed (a dead-channel ReLU
-    trace shape — elementwise sparsity alone never empties a 256-wide
-    tile, so tile-granular skip needs structured gaps)."""
-    rng = np.random.default_rng(seed)
-    a = rng.normal(size=(m, k)).astype(np.float32)
-    nk = k // ks
-    dead = rng.random(nk) < dead_frac
-    for t in np.nonzero(dead)[0]:
-        a[:, t * ks:(t + 1) * ks] = 0.0
-    return jnp.asarray(a)
-
-
-def _check_intersection_invariants(kw, a):
-    """Subset + packed-popcount agreement for one (weight, activation)."""
-    pres = actocc.ktile_presence(a, kw.ks)
-    sched = kw.schedule
-    mask = np.asarray(actocc.work_mask(sched.plane_bits, sched.ktile_ids,
-                                       pres))
-    base = np.asarray(sched.plane_bits)          # the weight-only walk
-    passes = np.bitwise_count(mask)
-    # intersected work ⊆ weight-only work, slot by slot and plane by plane
-    assert ((mask & ~base) == 0).all()
-    assert passes.sum() <= np.bitwise_count(base).sum() == sched.total_work
-    assert int(actocc.mask_passes(jnp.asarray(mask))) == passes.sum()
-    # the packed-word view of the same intersection counts the same work
-    inter = actocc.intersect_packed_presence(kw.occupancy, pres)
-    assert int(np.asarray(popcount(inter)).sum()) == int(passes.sum())
-    # per N-tile too, not just in aggregate
-    per_tile = np.asarray(popcount(inter)).sum(axis=(0, 1))
-    np.testing.assert_array_equal(per_tile, passes.sum(axis=1))
-    return pres, mask
-
-
-@given(seed=st.integers(0, 200),
-       sparsity=st.sampled_from([0.0, 0.7]),
-       dead_frac=st.sampled_from([0.0, 0.5, 1.0]))
-def test_act_intersection_subset(seed, sparsity, dead_frac):
-    """PROPERTY: for random weights and gappy activations, the intersected
-    work list is a subset of the weight-only one and its size equals the
-    popcount of the AND-ed packed presence words."""
-    kw = knead(_sparse_w(seed, 512, 256, sparsity), bits=8)
-    a = _gappy_activation(seed + 1, 2, 512, 256, dead_frac)
-    _check_intersection_invariants(kw, a)
-
-
-def test_act_intersection_subset_smoke():
-    """Non-hypothesis fallback of the subset property: fixed cases covering
-    no gaps, half gaps, and all-dead activations."""
-    for seed, dead in ((0, 0.0), (1, 0.5), (2, 1.0)):
-        kw = knead(_sparse_w(seed, 1024, 256, 0.6), bits=8, ks=256)
-        a = _gappy_activation(seed + 9, 2, 1024, 256, dead)
-        _check_intersection_invariants(kw, a)
-
-
-@given(seed=st.integers(0, 100),
-       sparsity=st.sampled_from([0.0, 0.8]),
-       dead_frac=st.sampled_from([0.25, 0.5, 0.75]))
-def test_act_skip_work_conservation(seed, sparsity, dead_frac):
-    """PROPERTY (work conservation): the items the intersection drops
-    contribute exactly 0 — the replay oracle over the intersected order is
-    bit-identical to the full weight-only replay."""
-    kw = knead(_sparse_w(seed, 1024, 128, sparsity), bits=8)
-    a = _gappy_activation(seed + 3, 2, 1024, 256, dead_frac)
-    pres, mask = _check_intersection_invariants(kw, a)
-    full = replay_schedule(a, kw)
-    skipped = replay_schedule(a, kw, act_presence=pres)
-    np.testing.assert_array_equal(np.asarray(skipped), np.asarray(full))
-
-
-def test_act_skip_work_conservation_smoke():
-    """Non-hypothesis fallback of the conservation property: one case where
-    the intersection provably drops work, replays bit-identical."""
-    kw = knead(_sparse_w(11, 1024, 128, 0.5), bits=8)
-    a = _gappy_activation(17, 1, 1024, 256, 0.5)
-    pres, mask = _check_intersection_invariants(kw, a)
-    assert np.bitwise_count(mask).sum() < kw.schedule.total_work  # dropped
-    full = replay_schedule(a, kw)
-    skipped = replay_schedule(a, kw, act_presence=pres)
-    np.testing.assert_array_equal(np.asarray(skipped), np.asarray(full))
-
-
-@pytest.mark.parametrize("case", ["all_zero", "all_dense", "single_hot"])
-def test_act_skip_activation_extremes(case):
-    """Activation extremes: an all-zero activation drops EVERY pass (output
-    exactly zero), a fully-dense one drops none (mask == weight-only mask),
-    and a single-hot one keeps exactly the one K-tile's passes — all
-    bit-exact against the unskipped kernel and the planes oracle."""
-    kw = knead(_sparse_w(31, 1024, 256, 0.5), bits=8)
-    sched = kw.schedule
-    rng = np.random.default_rng(32)
-    a = np.zeros((2, 1024), np.float32)
-    if case == "all_dense":
-        a = rng.normal(size=(2, 1024)).astype(np.float32)
-    elif case == "single_hot":
-        a[:, 256:512] = rng.normal(size=(2, 256)).astype(np.float32)
-    a = jnp.asarray(a)
-    pres = actocc.ktile_presence(a, kw.ks)
-    mask = np.asarray(actocc.work_mask(sched.plane_bits, sched.ktile_ids,
-                                       pres))
-    counts = np.asarray(sched.counts)
-    kids = np.asarray(sched.ktile_ids)
-    pbits = np.asarray(sched.plane_bits)
-    if case == "all_zero":
-        assert mask.sum() == 0
-    elif case == "all_dense":
-        np.testing.assert_array_equal(mask, pbits)
-    else:
-        expect = sum(int(np.bitwise_count(pbits[j, :counts[j]][
-            kids[j, :counts[j]] == 1]).sum()) for j in range(sched.n_tiles))
-        assert np.bitwise_count(mask).sum() == expect > 0
-    on = sac_matmul_pallas(a, kw, bm=8, skip_activations=True)
-    off = sac_matmul_pallas(a, kw, bm=8)
-    ref = sac_matmul(a, kw, impl="planes")
-    np.testing.assert_array_equal(np.asarray(on), np.asarray(off))
-    np.testing.assert_array_equal(np.asarray(on[:, :kw.logical_n]),
-                                  np.asarray(ref))
-    if case == "all_zero":
-        np.testing.assert_array_equal(np.asarray(on),
-                                      np.zeros_like(np.asarray(on)))
-
-
-@given(seed=st.integers(0, 100),
-       sparsity=st.sampled_from([0.0, 0.7, 0.95]),
-       dead_frac=st.sampled_from([0.0, 0.5]),
-       m=st.sampled_from([1, 2, 8]))
-def test_act_skip_parity_bit_exact(seed, sparsity, dead_frac, m):
-    """PROPERTY: masked pallas == unmasked pallas == planes, bitwise, across
-    random weight sparsities, activation gap fractions, and GEMV row
-    counts."""
-    kw = knead(_sparse_w(seed, 512, 128, sparsity), bits=8)
-    a = _gappy_activation(seed + 5, m, 512, 256, dead_frac)
-    on = sac_matmul(a, kw, impl="pallas", skip_activations=True)
-    off = sac_matmul(a, kw, impl="pallas")
-    ref = sac_matmul(a, kw, impl="planes")
-    np.testing.assert_array_equal(np.asarray(on), np.asarray(off))
-    np.testing.assert_array_equal(np.asarray(on), np.asarray(ref))
-
-
-def test_act_skip_parity_smoke():
-    """Non-hypothesis fallback of the skip-parity property, with the skip
-    accounting checked: fewer executed than scheduled tile-dots, same
-    bits."""
-    kw = knead(_sparse_w(41, 1024, 128, 0.5), bits=8)
-    a = _gappy_activation(43, 2, 1024, 256, 0.5)
-    actocc.reset_skip_stats()
-    on = sac_matmul(a, kw, impl="pallas", skip_activations=True)
-    jax.block_until_ready(on)
-    stats = actocc.skip_stats()
-    assert stats["weight_tile_dots"] == kw.schedule.total_work
-    assert stats["executed_tile_dots"] < stats["weight_tile_dots"]
-    assert 0.0 < stats["act_skip_frac"] <= 1.0
-    off = sac_matmul(a, kw, impl="pallas")
-    ref = sac_matmul(a, kw, impl="planes")
-    np.testing.assert_array_equal(np.asarray(on), np.asarray(off))
-    np.testing.assert_array_equal(np.asarray(on), np.asarray(ref))
-
-
-def test_act_skip_gemv_gate():
-    """The sac_matmul switch is decode-GEMV-only: a prefill-shaped call
-    (M > 8) must fall back to the static weight-only walk and record no
-    skip traffic."""
-    kw = knead(_sparse_w(51, 512, 128, 0.5), bits=8)
-    a = _gappy_activation(53, 24, 512, 256, 0.5)
-    actocc.reset_skip_stats()
-    on = sac_matmul(a, kw, impl="pallas", skip_activations=True)
-    jax.block_until_ready(on)
-    assert actocc.skip_stats()["weight_tile_dots"] == 0    # gate held
-    off = sac_matmul(a, kw, impl="pallas")
-    np.testing.assert_array_equal(np.asarray(on), np.asarray(off))
-
-
-@pytest.mark.parametrize("partition", ["contiguous", "balanced"])
-def test_act_skip_sharded_bit_exact(partition):
-    """Sharded execution with skip: the mask is computed once from the
-    replicated activations and sliced per shard — serial shard walk stays
-    bit-exact vs the skip-off walk and the unsharded kernel, under both
-    partitions (the balanced tile_slot gather is untouched by masking)."""
-    kw = knead(_sparse_w(61, 512, 512, 0.6), bits=8)
-    a = _gappy_activation(63, 2, 512, 256, 0.5)
-    skw = shard_schedule(kw, 2, partition=partition)
-    on = sac_matmul_pallas_sharded(a, skw, None, bm=8, skip_activations=True)
-    off = sac_matmul_pallas_sharded(a, skw, None, bm=8)
-    ref = sac_matmul_pallas(a, kw, bm=8)
-    np.testing.assert_array_equal(np.asarray(on), np.asarray(off))
-    np.testing.assert_array_equal(np.asarray(on), np.asarray(ref))
 
 
 # -------------------------------------------------- logical-K direct calls

@@ -231,14 +231,13 @@ _ENGINE_RUN = textwrap.dedent("""
 
     shards = int(sys.argv[2])
     partition = sys.argv[3]
-    act_skip = len(sys.argv) > 4 and sys.argv[4] == "1"
     cfg = get_config("smollm-360m", smoke=True)
     params = LanguageModel(cfg).init(jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0,
                               cfg.vocab_size)
     eng = ServingEngine(cfg, params, ServingConfig(
         max_len=48, impl="pallas", knead_min_dim=8, shards=shards,
-        shard_partition=partition, activation_skip=act_skip))
+        shard_partition=partition))
     with eng._mesh_ctx():
         logits, _ = eng._prefill(eng.params, {"tokens": toks})
     gen = eng.generate({"tokens": toks}, 32)
@@ -255,14 +254,12 @@ _ENGINE_RUN = textwrap.dedent("""
 """)
 
 
-def _run(code, out_prefix, shards, extra_env, partition="contiguous",
-         activation_skip=False):
+def _run(code, out_prefix, shards, extra_env, partition="contiguous"):
     env = {"PYTHONPATH": "src", "PATH": os.environ.get("PATH",
                                                        "/usr/bin:/bin")}
     env.update(extra_env)
     res = subprocess.run([sys.executable, "-c", code, out_prefix,
-                          str(shards), partition,
-                          "1" if activation_skip else "0"],
+                          str(shards), partition],
                          capture_output=True, text=True, env=env,
                          cwd=".", timeout=1200)
     assert res.returncode == 0, res.stderr[-2000:]
@@ -313,30 +310,3 @@ def test_sharded_lm_engine_bit_exact_vs_single_device_oracle(
     assert all(wk == 0 for wk in sharded_meta["wq_shard_work"][1:])
     assert sharded_meta["wq_max_layer_imbalance"] == pytest.approx(
         float(shards))
-
-
-@pytest.mark.parametrize("shards,partition",
-                         [(2, "contiguous"), (4, "balanced")])
-def test_sharded_lm_engine_activation_skip_bit_exact(
-        shards, partition, tmp_path, oracle_run):
-    """Activation-skip x sharding (docs/DESIGN.md §12): the sharded engine
-    with ``activation_skip=True`` must stay bit-identical to the clean
-    single-device *skip-off* oracle — presence is computed once from the
-    full decode row (shard-invariant under N-sharding), the survival mask
-    is sliced per shard, and surviving tile-dots keep their ascending K order, so
-    neither the mask intersection nor the balanced permutation epilogue may
-    move a single bit of the prefill logits or the 32-token generation."""
-    oracle_prefix, oracle_meta = oracle_run
-    n_force = int(os.environ.get("REPRO_SHARD_TEST_DEVICES", "4"))
-    sharded_meta = _run(
-        _ENGINE_RUN, str(tmp_path / "skip"), shards,
-        {"XLA_FLAGS": f"--xla_force_host_platform_device_count={n_force}",
-         "JAX_PLATFORMS": "cpu"}, partition=partition, activation_skip=True)
-    assert sharded_meta["devices"] == n_force
-    assert oracle_meta["devices"] == 1
-    np.testing.assert_array_equal(
-        np.load(tmp_path / "skip_logits.npy"),
-        np.load(oracle_prefix + "_logits.npy"))
-    np.testing.assert_array_equal(
-        np.load(tmp_path / "skip_gen.npy"),
-        np.load(oracle_prefix + "_gen.npy"))

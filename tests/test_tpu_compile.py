@@ -60,14 +60,14 @@ def _compile_sac(one_chip, m, k, n, bm, a_dtype):
     nk, n_tiles = k // KS, n // N_BLOCK
     num_work = nk
 
-    def run(a, planes, signs, scale, counts, kids, pbits, mask):
+    def run(a, planes, signs, scale, counts, kids, pbits):
         sched = KneadedSchedule(counts=counts, ktile_ids=kids,
                                 plane_bits=pbits, num_work=num_work,
                                 total_work=(BITS - 1) * nk * n_tiles,
                                 nk=nk, n_tiles=n_tiles)
         return sac_matmul_pallas_call(a, planes, signs, scale, sched,
                                       bits=BITS, bm=bm, bn=N_BLOCK, bk=KS,
-                                      interpret=False, mask=mask)
+                                      interpret=False)
 
     sched_shape = (n_tiles, num_work)
     args = (_sds((m, k), a_dtype, one_chip),
@@ -75,7 +75,6 @@ def _compile_sac(one_chip, m, k, n, bm, a_dtype):
             _sds((k // WORD, n), jnp.uint32, one_chip),
             _sds((1, n), jnp.float32, one_chip),
             _sds((n_tiles,), jnp.int32, one_chip),
-            _sds(sched_shape, jnp.int32, one_chip),
             _sds(sched_shape, jnp.int32, one_chip),
             _sds(sched_shape, jnp.int32, one_chip))
     return jax.jit(run).lower(*args).compile()
@@ -147,12 +146,12 @@ def test_kernels_keep_their_names_inside_jitted_wrappers(one_chip):
     nk, n_tiles = k // KS, n // N_BLOCK
     num_work = nk
 
-    def sac_step(a, planes, signs, scale, counts, kids, pbits, mask):
+    def sac_step(a, planes, signs, scale, counts, kids, pbits):
         sched = KneadedSchedule(counts=counts, ktile_ids=kids,
                                 plane_bits=pbits, num_work=num_work,
                                 total_work=(BITS - 1) * nk * n_tiles,
                                 nk=nk, n_tiles=n_tiles)
-        return jnp.tanh(sac_ops._run(a, planes, signs, scale, sched, mask,
+        return jnp.tanh(sac_ops._run(a, planes, signs, scale, sched,
                                      bits=BITS, ks=KS, n_block=N_BLOCK, bm=8,
                                      interpret=False))
 
@@ -163,7 +162,7 @@ def test_kernels_keep_their_names_inside_jitted_wrappers(one_chip):
         _sds((k // WORD, n), jnp.uint32, one_chip),
         _sds((1, n), jnp.float32, one_chip),
         _sds((n_tiles,), jnp.int32, one_chip),
-        *[_sds(sched_shape, jnp.int32, one_chip)] * 3).compile().as_text()
+        *[_sds(sched_shape, jnp.int32, one_chip)] * 2).compile().as_text()
     gemm = jax.jit(lambda a, q, s: jnp.tanh(gemm_ops._run(
         a, q, s, packed4=False, bm=8, bn=256, bk=512, interpret=False))
     ).lower(_sds((m, k), jnp.bfloat16, one_chip),
